@@ -23,6 +23,7 @@ from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import jax
+import ml_dtypes
 import numpy as np
 from jax.sharding import Mesh
 
@@ -38,12 +39,8 @@ def _pow2ceil(n: int) -> int:
     return 1 << (max(1, n) - 1).bit_length()
 
 
-try:  # the narrow wire dtype the admission scan must treat as float
-    import ml_dtypes
-
-    _WIRE_BF16 = np.dtype(ml_dtypes.bfloat16)
-except ImportError:  # pragma: no cover
-    _WIRE_BF16 = np.dtype(np.void)   # matches no real leaf
+# the narrow wire dtype the admission scan must treat as float
+_WIRE_BF16 = np.dtype(ml_dtypes.bfloat16)
 
 
 class TrajectoryBuffer:
@@ -149,14 +146,11 @@ class TrajectoryBuffer:
         if self._wire_plan:   # a narrow config's plan IS the bf16 plan
             alt_plan = self._wire_plan
         else:
-            try:
-                alt_plan = rollout_cast_plan(
-                    {n: np.dtype(a.dtype) for n, a in flat_tmpl.items()},
-                    "bfloat16",
-                    int_bounds,
-                )
-            except ValueError:   # ml_dtypes unavailable: full-width only
-                alt_plan = {}
+            alt_plan = rollout_cast_plan(
+                {n: np.dtype(a.dtype) for n, a in flat_tmpl.items()},
+                "bfloat16",
+                int_bounds,
+            )
         for n, a in flat_tmpl.items():
             widths = {np.dtype(a.dtype)}
             if n in alt_plan:

@@ -10,6 +10,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional
 
@@ -31,11 +32,20 @@ def build(force: bool = False, out: Optional[str] = None) -> str:
             and os.path.getmtime(out) >= os.path.getmtime(_SRC)
         ):
             return out
+        # compile beside the target and rename into place: the library is
+        # not in git, so on a fresh checkout a learner and its actors all
+        # build at once and none may dlopen a half-written file
+        tmp = f"{out}.build.{os.getpid()}"
         cmd = [
             "g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-            "-o", out, _SRC,
+            "-o", tmp, _SRC,
         ]
-        subprocess.run(cmd, check=True, capture_output=True)
+        try:
+            subprocess.run(cmd, check=True, capture_output=True)
+            os.replace(tmp, out)
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
         return out
 
 
@@ -66,7 +76,9 @@ class EncodeTensor(ctypes.Structure):
 
 
 def load_library(auto_build: bool = True) -> Optional[ctypes.CDLL]:
-    """Load (building if needed) the native library; None if unavailable."""
+    """Load (building if needed) the native library; None if unavailable —
+    the callers then use the Python codec, and the reason (the compiler's
+    own error for a failed build) is printed once."""
     global _lib, _load_failed
     if _lib is not None or _load_failed:
         return _lib
@@ -109,13 +121,19 @@ def load_library(auto_build: bool = True) -> Optional[ctypes.CDLL]:
             ]
         _lib = lib
     except (
-        OSError,
+        OSError,   # no g++ (FileNotFoundError) or an unloadable library
         subprocess.CalledProcessError,
-        FileNotFoundError,
         AttributeError,  # unbuildable stale library missing a symbol
-    ):
-        _load_failed = True
+    ) as e:
+        _load_failed = True   # latched: the report below prints once
         _lib = None
+        stderr = getattr(e, "stderr", None) or b""
+        print(
+            f"dotaclient_tpu.native: C++ rollout codec unavailable, using "
+            f"the Python codec ({type(e).__name__}: {e})\n"
+            f"{stderr.decode(errors='replace').rstrip()}",
+            file=sys.stderr, flush=True,
+        )
     return _lib
 
 

@@ -1,15 +1,11 @@
 """Pallas (Mosaic) TPU kernels."""
 
 from dotaclient_tpu.ops.pallas.lstm import (
-    HAVE_PALLAS,
-    lstm_sequence,
     lstm_sequence_pallas,
     lstm_sequence_reference,
 )
 
 __all__ = [
-    "HAVE_PALLAS",
-    "lstm_sequence",
     "lstm_sequence_pallas",
     "lstm_sequence_reference",
 ]

@@ -1,16 +1,21 @@
 """Pallas fused LSTM-sequence kernel (the one custom-kernel candidate,
 SURVEY.md §2.2 row 1 / §7 hard-part 1).
 
-Measured context (BASELINE.md "Pallas decision"): at production shapes the
-`nn.scan` LSTM is ~15 µs of a ~55 µs sequence forward and a single-digit
-percent of the 388 µs train step, so the DEFAULT core stays `nn.scan` —
-XLA already fuses the per-step matmul+elementwise well at H=128. This
-kernel exists as the measured alternative for *wider* cores, where keeping
-the weights pinned in VMEM across all T steps pays: one `pallas_call` runs
-the whole sequence, double-reading nothing from HBM.
+The DEFAULT core stays `nn.scan` (BASELINE.md "Pallas decision"); no path in
+the package calls this kernel. It exists as the alternative for *wider*
+cores, where keeping the weights pinned in VMEM across all T steps should
+pay: one `pallas_call` runs the whole sequence, double-reading nothing from
+HBM. Its speed against the scan: not measured on chip in this round.
+
+On the chip (TPU v5e, jax 0.9.0, libtpu 0.0.34; chip_smoke.py phase e, PR
+21): Mosaic accepts the kernel as written — no grid, no `BlockSpec`, the
+1-D reset row `r_ref[t]` reshaped to 2-D, `gates` sliced along lanes at
+multiples of H — and it matches the reference at B=32, T=16 for H=128 and
+H=512 (max abs error 3e-4 at default matmul precision, 5e-6 at "highest").
+H=512 was at first refused for VMEM; see `_vmem_limit`.
 
 Cell math (gate order i, f, g, o — pinned by `lstm_sequence_reference`,
-which is both the spec and the fallback):
+the spec the kernel is tested against):
 
     gates = x_t @ Wx + h @ Wh + b
     c' = σ(f)·c + σ(i)·tanh(g);  h' = σ(o)·tanh(c')
@@ -19,7 +24,13 @@ which is both the spec and the fallback):
 Gradients: `custom_vjp` with a recompute backward — the forward runs the
 kernel, the backward re-runs the reference under `jax.vjp` (rematerialized
 BPTT; residuals are just the inputs). Numerics parity is tested in
-interpreter mode on CPU and compiled on TPU.
+interpreter mode on CPU (tests/test_pallas.py) and compiled on the TPU
+(chip_smoke.py, phase e).
+
+There is no dispatcher: the caller names `lstm_sequence_pallas` (with
+`interpret=` explicit — `True` only where there is no TPU to compile for) or
+`lstm_sequence_reference`. Nothing here looks at the backend, so a run can
+never quietly get the scan when it asked for the kernel.
 """
 
 from __future__ import annotations
@@ -30,13 +41,13 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # pallas ships with jax; guard anyway for exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-    HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    HAVE_PALLAS = False
+# Mosaic's default scoped-VMEM limit on the v5e, and the most this kernel
+# will ask for (the chip has 128 MiB; leave room for XLA's own fusions).
+_VMEM_DEFAULT = 16 << 20
+_VMEM_CEILING = 100 << 20
 
 
 def _cell(x_t, h, c, wx, wh, b, reset_t):
@@ -109,6 +120,25 @@ def _kernel(x_ref, h0_ref, c0_ref, wx_ref, wh_ref, b_ref, r_ref,
     cT_ref[:] = c
 
 
+def _vmem_limit(wx, wh) -> int:
+    """Scoped-VMEM request, sized from the two weight matrices.
+
+    Measured on the v5e (PR 21): whatever B and T are, Mosaic allocates
+    twice the weights plus under 1 MiB — the VMEM buffers and the values
+    the kernel hoists out of its loop — 16.30M at D=H=512 ("Scoped
+    allocation with size 16.30M and limit 16.00M exceeded scoped vmem
+    limit by 308.0K" at the default) and 64.55M at D=H=1024. Asking for
+    exactly that sits on an edge: XLA also places some of the call's
+    operands in VMEM, and at H=512 a 24.5 MiB limit failed (26.28M) where
+    24 MiB and 32 MiB passed. So ask for four times the weights: 32 MiB at
+    H=512, where every B (32-256), T (16, 32) and matmul precision tried
+    compiled. H=1024 hits the ceiling; there B=32 at "highest" precision
+    still fails (103.86M) and needs a grid over the gate columns, which
+    this kernel does not have."""
+    weights = (wx.size + wh.size) * wx.dtype.itemsize
+    return max(_VMEM_DEFAULT, min(4 * weights, _VMEM_CEILING))
+
+
 def _pallas_forward(x, h0, c0, wx, wh, b, resets, interpret):
     B, T, D = x.shape
     H = h0.shape[-1]
@@ -120,6 +150,9 @@ def _pallas_forward(x, h0, c0, wx, wh, b, resets, interpret):
             jax.ShapeDtypeStruct((T, B, H), jnp.float32),
             jax.ShapeDtypeStruct((B, H), jnp.float32),
             jax.ShapeDtypeStruct((B, H), jnp.float32),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(wx, wh)
         ),
         interpret=interpret,
     )(x_tm, h0, c0, wx, wh, b, r_tm)
@@ -152,17 +185,3 @@ def _bwd(interpret, residuals, cotangents):
 
 
 lstm_sequence_pallas.defvjp(_fwd, _bwd)
-
-
-def lstm_sequence(
-    x, h0, c0, wx, wh, b, resets,
-    use_pallas: bool = True,
-    interpret_ok: bool = False,
-) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray]]:
-    """Dispatch: fused kernel on TPU; off-TPU the reference scan — the
-    interpreter-mode kernel (Python-emulated, very slow) only when
-    explicitly requested via ``interpret_ok`` (numerics tests)."""
-    on_tpu = jax.default_backend() == "tpu"
-    if not (use_pallas and HAVE_PALLAS) or (not on_tpu and not interpret_ok):
-        return lstm_sequence_reference(x, h0, c0, wx, wh, b, resets)
-    return lstm_sequence_pallas(x, h0, c0, wx, wh, b, resets, not on_tpu)

@@ -28,19 +28,6 @@ from typing import Optional
 import jax
 
 
-def _is_initialized() -> bool:
-    # jax.distributed.is_initialized is absent before jax 0.4.x-late; fall
-    # back to the client handle the initialize() call populates.
-    checker = getattr(jax.distributed, "is_initialized", None)
-    if checker is not None:
-        return bool(checker())
-    try:
-        from jax._src.distributed import global_state
-    except ImportError:  # pragma: no cover - very old/new private layout
-        return False
-    return global_state.client is not None
-
-
 def initialize_runtime(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,
@@ -52,7 +39,7 @@ def initialize_runtime(
     environment. Explicit args serve CPU fleets and tests. Idempotent —
     calling twice (e.g. test re-entry) is a no-op rather than an error.
     """
-    if _is_initialized():
+    if jax.distributed.is_initialized():
         return
     jax.distributed.initialize(
         coordinator_address=coordinator_address,

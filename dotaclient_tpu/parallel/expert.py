@@ -26,9 +26,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from dotaclient_tpu.parallel._compat import shard_map
 
 AXIS = "data"
 

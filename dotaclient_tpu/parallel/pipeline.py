@@ -21,8 +21,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from dotaclient_tpu.parallel._compat import pcast_varying, shard_map
 
 StageFn = Callable[[Any, jnp.ndarray], jnp.ndarray]
 
@@ -57,9 +57,10 @@ def make_pipeline(
         perm_fwd = [(i, (i + 1) % S) for i in range(S)]
         # zero-constants are axis-invariant; the loop makes them varying —
         # pcast the initializers so the fori_loop carry types match
-        # (identity on jax versions without varying types — _compat shim)
-        out0 = pcast_varying(jnp.zeros_like(xm), (axis,))
-        recv0 = pcast_varying(jnp.zeros(mb_shape, x.dtype), (axis,))
+        out0 = jax.lax.pcast(jnp.zeros_like(xm), (axis,), to="varying")
+        recv0 = jax.lax.pcast(
+            jnp.zeros(mb_shape, x.dtype), (axis,), to="varying"
+        )
 
         def tick(t, carry):
             recv, out = carry

@@ -25,8 +25,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from dotaclient_tpu.parallel._compat import pcast_varying, shard_map
 
 AXIS = "data"  # default mesh axis to shard the sequence over
 
@@ -117,8 +117,7 @@ def ring_attention_shard(
     def varying(x):
         # constants are axis-invariant; the loop outputs are axis-varying —
         # mark the init carries varying so the fori_loop types match
-        # (identity on jax versions without varying types — _compat shim)
-        return pcast_varying(x, (axis_name,))
+        return jax.lax.pcast(x, (axis_name,), to="varying")
 
     init = (
         varying(jnp.zeros((B, Tl, h, d), jnp.float32)),

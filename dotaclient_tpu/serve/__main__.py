@@ -76,9 +76,10 @@ def main(argv=None) -> int:
         load_inference_params,
         make_inference_policy,
     )
-    from dotaclient_tpu.utils import telemetry
+    from dotaclient_tpu.utils import compile_cache, telemetry
     from dotaclient_tpu.utils.overrides import parse_dataclass_overrides
 
+    compile_cache.enable()
     config, params, version = load_inference_params(args.checkpoint)
     if args.serve:
         from dotaclient_tpu.config import ServeConfig
@@ -101,11 +102,14 @@ def main(argv=None) -> int:
     engine = ServeEngine(config, policy, params, version=version)
     host, port = args.serve_listen.rsplit(":", 1)
     server = PolicyServer(engine, config, host=host, port=int(port))
+    # the device is read off the committed arrays: one server is one
+    # single-device replica, however many chips the host holds
     print(
         f"serve: listening on {server.address} "
         f"(window {config.serve.batch_window_ms} ms, "
         f"max_batch {config.serve.max_batch}, "
-        f"{config.serve.max_slots} carry slots, weights v{version})",
+        f"{config.serve.max_slots} carry slots, weights v{version}, "
+        f"params on {', '.join(sorted(map(str, engine.param_devices)))})",
         flush=True,
     )
     # machine-readable address line: the chaos harness and fleet tooling

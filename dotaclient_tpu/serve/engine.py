@@ -123,6 +123,13 @@ class ServeEngine:
             jnp.asarray, policy.initial_state(S + 1)
         )
         self._params = jax.device_put(params)
+        # Where the params landed, read off the arrays: ``device_put`` with
+        # no sharding commits to the process's first device (weight swaps
+        # land there too), so one engine is one single-device replica
+        # however many chips the host holds.
+        self.param_devices: Set[Any] = set().union(
+            *(leaf.devices() for leaf in jax.tree.leaves(self._params))
+        )
         self._version = version
         self._rng0 = jax.random.PRNGKey(scfg.seed)
         self._dispatch_idx = 0

@@ -20,28 +20,23 @@ import threading
 import zlib
 from typing import Any, Dict, List, Mapping, Tuple
 
+import ml_dtypes
 import numpy as np
 
-try:  # bfloat16 arrays cross the wire when actors run bf16 inference
-    import ml_dtypes
-
-    _BFLOAT16 = np.dtype(ml_dtypes.bfloat16)
-except ImportError:  # pragma: no cover
-    _BFLOAT16 = None
-
 from dotaclient_tpu.protos import dota_pb2 as pb
+
+# bfloat16 arrays cross the wire when actors run bf16 inference
+_BFLOAT16 = np.dtype(ml_dtypes.bfloat16)
 
 
 def _np_dtype(name: str) -> np.dtype:
     if name == "bfloat16":
-        if _BFLOAT16 is None:
-            raise ValueError("bfloat16 payload but ml_dtypes unavailable")
         return _BFLOAT16
     return np.dtype(name)
 
 
 def _dtype_name(dtype: np.dtype) -> str:
-    if _BFLOAT16 is not None and dtype == _BFLOAT16:
+    if dtype == _BFLOAT16:
         return "bfloat16"
     return dtype.name
 
@@ -278,10 +273,6 @@ def rollout_cast_plan(
         )
     if wire_dtype == "float32":
         return {}
-    if _BFLOAT16 is None:
-        raise ValueError(
-            "rollout_wire_dtype=bfloat16 but ml_dtypes unavailable"
-        )
     plan: Dict[str, np.dtype] = {}
     for name, dtype in specs.items():
         dtype = np.dtype(dtype)
@@ -955,8 +946,6 @@ def encode_weights(
         raise ValueError(f"unknown wire_dtype {wire_dtype!r}")
     cast = None
     if wire_dtype == "bfloat16":
-        if _BFLOAT16 is None:
-            raise ValueError("wire_dtype=bfloat16 but ml_dtypes unavailable")
         cast = _BFLOAT16
     msg = pb.ModelWeights(version=version)
     cast_names = []
@@ -1008,7 +997,6 @@ def decode_weights(msg: pb.ModelWeights, upcast: bool = True) -> Tuple[int, Any]
         if (
             upcast
             and name in cast_names
-            and _BFLOAT16 is not None
             and arr.dtype == _BFLOAT16
         ):
             arr = arr.astype(np.float32)

@@ -290,7 +290,6 @@ ALERT_WAIVERS: Dict[str, str] = {
         "needs a traced run and trace_report's critical path; no single "
         "registry level encodes it"
     ),
-    "rb:tpu-preflight": "startup tool (run_multichip.py), not a live signal",
     "rb:fused-lane-divisibility": (
         "construction-time ValueError before any compile; the process "
         "never reaches a runtime level to watch"

@@ -241,7 +241,9 @@ class FleetPublisher:
         self._reg = (
             registry if registry is not None else telemetry.get_registry()
         )
-        self._last = 0.0
+        # -inf, not 0: time.monotonic() counts from boot, so on a machine up
+        # for less than interval_s a 0 start would swallow the first publish
+        self._last = float("-inf")
         self._seq = 0
 
     def maybe_publish(self, transport: Any, force: bool = False) -> bool:

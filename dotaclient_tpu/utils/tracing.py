@@ -512,25 +512,18 @@ def update_memory_gauges(
 ) -> float:
     """Refresh ``mem/hbm_peak_bytes`` from the local devices' allocator
     stats (max peak across devices). Host-only metadata reads — safe at
-    log-boundary cadence. CPU backends report no stats → gauge stays at
-    its eager-created 0 (graceful degrade, pinned by test)."""
+    log-boundary cadence. ``memory_stats()`` is a dict on TPU and ``None``
+    on CPU, where the gauge stays at its eager-created 0 (pinned by
+    test)."""
+    import jax  # deferred: jax-free tools import this module for its records
+
     reg = registry if registry is not None else telemetry.get_registry()
     peak = 0.0
-    try:
-        import jax
-
-        for dev in jax.local_devices():
-            stats = None
-            try:
-                stats = dev.memory_stats()
-            except Exception:  # noqa: BLE001 - backend without stats
-                stats = None
-            if stats:
-                peak = max(peak, float(stats.get("peak_bytes_in_use", 0)))
-    except Exception:  # noqa: BLE001 - no backend at all (import-light use)
-        peak = 0.0
+    for dev in jax.local_devices():
+        stats = dev.memory_stats()
+        if stats:
+            peak = max(peak, float(stats.get("peak_bytes_in_use", 0)))
+    gauge = reg.gauge("mem/hbm_peak_bytes")
     if peak:
-        reg.gauge("mem/hbm_peak_bytes").set(peak)
-    else:
-        reg.gauge("mem/hbm_peak_bytes")
+        gauge.set(peak)
     return peak

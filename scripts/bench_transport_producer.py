@@ -2,10 +2,10 @@
 
 Runs as a separate OS process (the real split-topology shape: an actor
 process feeding the learner's transport) and ships ``--frames`` rollout
-frames of ``--bytes`` wire bytes each through the requested lane. Imports
-no JAX — the process is up in milliseconds, so the parent's timing window
-(which starts at first frame arrival) measures transport, not interpreter
-startup.
+frames of ``--bytes`` wire bytes each through the requested lane. Does no
+device work (bench.py starts it with ``JAX_PLATFORMS=cpu``: its parent
+holds the chip), and the parent's timing window starts at first frame
+arrival, so it measures transport, not interpreter startup.
 
 Usage (spawned by bench.py, but runnable by hand):
     python scripts/bench_transport_producer.py --lane socket \

@@ -1,23 +1,14 @@
-"""Multi-chip preflight + probe: make the flagship scaling path runnable.
+"""Multi-chip dry run + probes on this process's (or forced host) devices.
 
-Three modes, one JSON result line each (the driver-record shape of
-``MULTICHIP_r01.json``):
+One JSON result line per mode; the exit code is 0 only when the line says
+``"ok": true``. There is no mode that reports a backend it could not
+initialise as anything but a failure: the accelerator preflight is
+``python chip_smoke.py`` (repo root), which fails without a TPU.
 
-* **preflight** (default): FIRST initialize the real accelerator backend
-  in a fresh subprocess with NO platform pin (``jax.devices()`` — the op
-  that actually trips a broken env; ``dryrun_multichip`` itself pins CPU
-  before any device op, so it alone would validate the CPU path and call
-  a broken TPU healthy), THEN run ``__graft_entry__.dryrun_multichip``
-  for the sharded-path validation. A broken TPU environment — the libtpu
-  client/terminal version mismatch that failed ``MULTICHIP_r01.json``
-  with a 40-frame traceback, a missing PJRT plugin, a busy chip, an init
-  that HANGS (bounded by a timeout and classified like any other
-  breakage) — is reported as a clear, actionable SKIP with a remediation
-  line, never a traceback dump.
-* **--force-host N**: the requested fallback — run the same dry run on N
-  forced host devices (``XLA_FLAGS=--xla_force_host_platform_device_count``
-  + ``JAX_PLATFORMS=cpu``), the zero-TPU path tests/conftest.py and the
-  driver use.
+* **--force-host N**: run ``__graft_entry__.dryrun_multichip`` on N forced
+  host devices (``XLA_FLAGS=--xla_force_host_platform_device_count`` +
+  ``JAX_PLATFORMS=cpu``) in a fresh subprocess — the sharded-path validation
+  tests/conftest.py and the driver use.
 * **--probe**: measurement mode for ``bench.py``'s multichip stage. Runs
   the learner's fused epoch step (``train/ppo.make_epoch_step`` — the
   production multi-update program) on THIS process's visible devices:
@@ -28,7 +19,6 @@ Three modes, one JSON result line each (the driver-record shape of
   parity headline. The caller pins the device count via env BEFORE the
   probe process initializes its backend; ``--devices`` only *asserts*
   the count.
-
 * **--fused**: same measurement contract for the ONE-dispatch fused
   program (``train/fused.make_fused_step``, ``actor="fused"``): the whole
   rollout+update iteration runs lane-sharded over this process's devices,
@@ -45,9 +35,12 @@ Three modes, one JSON result line each (the driver-record shape of
   mesh — the multi-host spelling, exercisable single-host because forced
   host devices reshape the same way.
 
+One process per chip: the parent of ``--force-host`` and ``--fused-parity``
+never touches JAX, and every child it starts is pinned to the CPU through
+its environment.
+
 Usage:
-    python scripts/run_multichip.py                  # real-backend dry run
-    python scripts/run_multichip.py --force-host 8   # zero-TPU fallback
+    python scripts/run_multichip.py --force-host 8       # sharded dry run
     python scripts/run_multichip.py --probe --steps 10   # bench probe
     python scripts/run_multichip.py --fused-parity 8     # fused verdict
 """
@@ -63,190 +56,66 @@ from typing import List, Optional, Tuple
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# Known backend-initialization failure shapes → (reason, remediation).
-# Matched against the combined stdout+stderr of the probe subprocess; the
-# first hit wins. Kept data-driven so the next broken-env shape is one
-# tuple, not another try/except ladder.
-FAILURE_SIGNATURES: Tuple[Tuple[str, str, str], ...] = (
-    (
-        "libtpu version mismatch",
-        "libtpu client/terminal version mismatch — the AOT client and the "
-        "TPU terminal are at different libtpu builds",
-        "align the libtpu builds (update the client runtime to the "
-        "terminal's build, or vice versa — usually a monorepo sync or a "
-        "rolling libtpu upgrade mid-flight), or rerun with "
-        "--force-host N to validate the sharded path on CPU",
-    ),
-    (
-        "FAILED_PRECONDITION",
-        "TPU backend failed a runtime precondition at init",
-        "check the PJRT plugin / driver state (another process may hold "
-        "the chip — this TPU supports one process at a time), or rerun "
-        "with --force-host N",
-    ),
-    (
-        "Unable to initialize backend",
-        "no usable accelerator backend in this environment",
-        "run on a TPU host, or rerun with --force-host N for the "
-        "forced-host-device CPU path",
-    ),
-    (
-        # emitted by _run_subprocess on subprocess.TimeoutExpired — a
-        # wedged backend init (chip held by another process) must classify
-        # into the same skip+remediation shape, not escape as a traceback
-        "MULTICHIP_PREFLIGHT_TIMEOUT",
-        "backend init / dry run did not complete within the timeout "
-        "(another process holding the chip? wedged PJRT plugin?)",
-        "free the TPU (this chip supports one process at a time), check "
-        "for stuck processes holding /dev/accel*, or rerun with "
-        "--force-host N",
-    ),
-)
-
-
-def classify_backend_error(text: str) -> Optional[Tuple[str, str]]:
-    """Map a probe subprocess's output to (reason, remediation), or None
-    when no known signature matches (the caller then reports the tail
-    verbatim — unknown breakage must stay visible, just bounded)."""
-    for needle, reason, remediation in FAILURE_SIGNATURES:
-        if needle in text:
-            return reason, remediation
-    return None
-
 
 def _result(payload: dict) -> int:
     print(json.dumps(payload, sort_keys=True))
-    return 0 if payload.get("ok") or payload.get("skipped") else 1
+    return 0 if payload.get("ok") else 1
 
 
-def _run_subprocess(
-    code: str, env: Optional[dict] = None, timeout: float = 900.0
-) -> Tuple[int, str]:
-    """Run ``python -c code`` fresh; a hang becomes a classifiable
-    MULTICHIP_PREFLIGHT_TIMEOUT marker instead of an uncaught
-    TimeoutExpired traceback (the no-traceback contract covers hangs —
-    a chip held by another process commonly BLOCKS init rather than
-    erroring)."""
+def _forced_host_env(n: int) -> dict:
+    """The child environment that pins ``n`` forced host devices — set
+    before the child initializes its backend (a cached backend makes any
+    later pin inert)."""
+    return {
+        **os.environ,
+        "XLA_FLAGS": (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={n}"
+        ).strip(),
+        "JAX_PLATFORMS": "cpu",
+    }
+
+
+def _run_forced_host(argv: List[str], n: int) -> Tuple[int, str]:
+    """Run ``python *argv`` fresh on ``n`` forced host devices; a child
+    that outlives the timeout is a failure (rc -1) like any other."""
     try:
         proc = subprocess.run(
-            [sys.executable, "-c", code],
+            [sys.executable, *argv],
             cwd=REPO,
-            env={**os.environ, **(env or {})},
+            env=_forced_host_env(n),
             capture_output=True,
             text=True,
-            timeout=timeout,
+            timeout=900.0,
         )
     except subprocess.TimeoutExpired as e:
         partial = "".join(
             p.decode(errors="replace") if isinstance(p, bytes) else (p or "")
             for p in (e.stdout, e.stderr)
         )
-        return -1, (
-            f"MULTICHIP_PREFLIGHT_TIMEOUT after {timeout:.0f}s\n{partial}"
-        )
+        return -1, f"timed out after 900s\n{partial}"
     return proc.returncode, proc.stdout + proc.stderr
 
 
-def _dryrun_subprocess(
-    n_devices: int, env: Optional[dict] = None
-) -> Tuple[int, str]:
-    """Run dryrun_multichip(n) in a fresh process (a cached backend makes
-    any platform pin inert — __graft_entry__ docstring)."""
-    return _run_subprocess(
-        f"from __graft_entry__ import dryrun_multichip; "
-        f"dryrun_multichip({n_devices})",
-        env=env,
+def force_host_dryrun(n_devices: int) -> int:
+    """Dry-run the sharded train path on ``n_devices`` forced host devices."""
+    rc, out = _run_forced_host(
+        [
+            "-c",
+            f"from __graft_entry__ import dryrun_multichip; "
+            f"dryrun_multichip({n_devices})",
+        ],
+        n_devices,
     )
-
-
-def _backend_init_subprocess() -> Tuple[int, str]:
-    """Initialize the REAL backend — no platform pin, no forced host
-    devices: ``jax.devices()`` is the op that actually trips a broken
-    libtpu env. ``dryrun_multichip`` pins JAX_PLATFORMS=cpu before any
-    device op (by design — it is the zero-TPU validation), so WITHOUT
-    this step the preflight would validate the CPU path and report a
-    broken TPU as healthy."""
-    return _run_subprocess(
-        "import jax; print('BACKEND', [d.device_kind for d in jax.devices()])",
-        timeout=300.0,
+    return _result(
+        {
+            "n_devices": n_devices,
+            "mode": "forced-host",
+            "rc": rc,
+            "ok": rc == 0,
+            "tail": "\n".join(out.splitlines()[-8:]),
+        }
     )
-
-
-def preflight(n_devices: int, force_host: Optional[int]) -> int:
-    """Init the real backend, then dry-run the sharded train path;
-    classify env breakage as a SKIP."""
-    if force_host is not None:
-        n_devices = force_host
-        rc, out = _dryrun_subprocess(
-            n_devices,
-            env={
-                "XLA_FLAGS": (
-                    os.environ.get("XLA_FLAGS", "")
-                    + f" --xla_force_host_platform_device_count={n_devices}"
-                ).strip(),
-                "JAX_PLATFORMS": "cpu",
-            },
-        )
-        tail = "\n".join(out.splitlines()[-8:])
-        return _result(
-            {
-                "n_devices": n_devices,
-                "mode": "forced-host",
-                "rc": rc,
-                "ok": rc == 0,
-                "skipped": False,
-                "tail": tail,
-            }
-        )
-    # Step 1: REAL backend init (no pins) — the op that trips a broken
-    # env; classify breakage into the actionable skip.
-    init_rc, init_out = _backend_init_subprocess()
-    if init_rc != 0:
-        classified = classify_backend_error(init_out)
-        if classified is not None:
-            reason, remediation = classified
-            # the actionable skip (ISSUE 10): one reason line + one
-            # remediation line, never the 40-frame traceback
-            print(f"MULTICHIP SKIP: {reason}", file=sys.stderr)
-            print(f"  remediation: {remediation}", file=sys.stderr)
-            return _result(
-                {
-                    "n_devices": n_devices,
-                    "mode": "accelerator",
-                    "rc": init_rc,
-                    "ok": False,
-                    "skipped": True,
-                    "reason": reason,
-                    "remediation": remediation,
-                }
-            )
-        return _result(
-            {
-                "n_devices": n_devices,
-                "mode": "accelerator",
-                "rc": init_rc,
-                "ok": False,
-                "skipped": False,
-                "tail": "\n".join(init_out.splitlines()[-12:]),
-            }
-        )
-    backend = next(
-        (ln for ln in init_out.splitlines() if ln.startswith("BACKEND ")),
-        "",
-    ).removeprefix("BACKEND ")
-    # Step 2: the sharded-path dry run (pins CPU internally by design —
-    # the backend's health was established above).
-    rc, out = _dryrun_subprocess(n_devices)
-    payload = {
-        "n_devices": n_devices,
-        "mode": "accelerator",
-        "backend": backend,
-        "rc": rc,
-        "ok": rc == 0,
-        "skipped": False,
-        "tail": "\n".join(out.splitlines()[-4 if rc == 0 else -12:]),
-    }
-    return _result(payload)
 
 
 def _probe_config(dcn_slices: int):
@@ -284,13 +153,15 @@ def probe(
     from dotaclient_tpu.parallel import make_mesh
     from dotaclient_tpu.train import example_batch, init_train_state
     from dotaclient_tpu.train.ppo import make_epoch_step, train_state_sharding
+    from dotaclient_tpu.utils import compile_cache
+
+    compile_cache.enable()
 
     n_devices = len(jax.devices())
     if expect_devices is not None and n_devices != expect_devices:
         return _result(
             {
                 "ok": False,
-                "skipped": False,
                 "n_devices": n_devices,
                 "error": (
                     f"probe expected {expect_devices} devices but the "
@@ -364,7 +235,6 @@ def probe(
     return _result(
         {
             "ok": True,
-            "skipped": False,
             "n_devices": n_devices,
             "mesh": {
                 "data": int(mesh.shape[config.mesh.data_axis]),
@@ -414,13 +284,14 @@ def fused_probe(
     from dotaclient_tpu.train import init_train_state
     from dotaclient_tpu.train.fused import make_fused_step
     from dotaclient_tpu.train.ppo import train_state_sharding
+    from dotaclient_tpu.utils import compile_cache
 
+    compile_cache.enable()
     n_devices = len(jax.devices())
     if expect_devices is not None and n_devices != expect_devices:
         return _result(
             {
                 "ok": False,
-                "skipped": False,
                 "n_devices": n_devices,
                 "error": (
                     f"probe expected {expect_devices} devices but the "
@@ -502,7 +373,6 @@ def fused_probe(
     return _result(
         {
             "ok": True,
-            "skipped": False,
             "mode": "fused",
             "n_devices": n_devices,
             "mesh": {str(k): int(v) for k, v in mesh.shape.items()},
@@ -524,36 +394,16 @@ def _fused_probe_subprocess(
     n: int, n_steps: int, parity_steps: int, rollout_len: int
 ) -> Tuple[int, str]:
     """Spawn one fused probe on ``n`` FORCED HOST devices in a fresh
-    process — the device count must be pinned via env before the child
-    initializes its backend (a cached backend makes any later pin inert)."""
-    env = {
-        "XLA_FLAGS": (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={n}"
-        ).strip(),
-        "JAX_PLATFORMS": "cpu",
-    }
-    try:
-        proc = subprocess.run(
-            [
-                sys.executable, os.path.abspath(__file__), "--fused",
-                "--devices", str(n), "--steps", str(n_steps),
-                "--parity-steps", str(parity_steps),
-                "--rollout-len", str(rollout_len),
-            ],
-            cwd=REPO,
-            env={**os.environ, **env},
-            capture_output=True,
-            text=True,
-            timeout=900.0,
-        )
-    except subprocess.TimeoutExpired as e:
-        partial = "".join(
-            p.decode(errors="replace") if isinstance(p, bytes) else (p or "")
-            for p in (e.stdout, e.stderr)
-        )
-        return -1, f"MULTICHIP_PREFLIGHT_TIMEOUT after 900s\n{partial}"
-    return proc.returncode, proc.stdout + proc.stderr
+    process."""
+    return _run_forced_host(
+        [
+            os.path.abspath(__file__), "--fused",
+            "--devices", str(n), "--steps", str(n_steps),
+            "--parity-steps", str(parity_steps),
+            "--rollout-len", str(rollout_len),
+        ],
+        n,
+    )
 
 
 def _last_json_line(out: str) -> Optional[dict]:
@@ -603,25 +453,10 @@ def fused_parity(
                                           rollout_len)
         payload = _last_json_line(out)
         if rc != 0 or not payload or not payload.get("ok"):
-            classified = classify_backend_error(out)
-            if classified is not None:
-                reason, remediation = classified
-                print(f"MULTICHIP SKIP: {reason}", file=sys.stderr)
-                print(f"  remediation: {remediation}", file=sys.stderr)
-                return _result(
-                    {
-                        "mode": "fused-parity",
-                        "ok": False,
-                        "skipped": True,
-                        "reason": reason,
-                        "remediation": remediation,
-                    }
-                )
             return _result(
                 {
                     "mode": "fused-parity",
                     "ok": False,
-                    "skipped": False,
                     "failed_probe_devices": n,
                     "rc": rc,
                     "tail": "\n".join(out.splitlines()[-12:]),
@@ -650,7 +485,6 @@ def fused_parity(
         {
             "mode": "fused-parity",
             "ok": rollout_ok and losses_ok and checksum_ok and lane_sharded,
-            "skipped": False,
             "devices": [1, n_high],
             "parity": {
                 "rollout_l1_ok": rollout_ok,
@@ -668,32 +502,33 @@ def fused_parity(
 
 def main(argv: Optional[List[str]] = None) -> int:
     p = argparse.ArgumentParser(description=__doc__)
-    p.add_argument(
-        "--devices", type=int, default=8,
-        help="device count to dry-run (preflight) or assert (--probe)",
-    )
-    p.add_argument(
+    mode = p.add_mutually_exclusive_group(required=True)
+    mode.add_argument(
         "--force-host", type=int, default=None, metavar="N",
-        help="skip the accelerator and run the dry run on N forced host "
-        "devices (XLA_FLAGS=--xla_force_host_platform_device_count=N + "
-        "JAX_PLATFORMS=cpu) — the zero-TPU validation path",
+        help="dry-run the sharded train path on N forced host devices "
+        "(XLA_FLAGS=--xla_force_host_platform_device_count=N + "
+        "JAX_PLATFORMS=cpu), fresh subprocess",
     )
-    p.add_argument(
+    mode.add_argument(
         "--probe", action="store_true",
         help="measurement mode (bench.py's multichip stage): fused epoch "
         "step throughput + parity digest on this process's devices",
     )
-    p.add_argument(
+    mode.add_argument(
         "--fused", action="store_true",
         help="measurement mode for the ONE-dispatch fused program "
         "(rollout + update, actor='fused'): lane-sharded throughput + "
         "parity digest + compiled lane-sharding proof",
     )
-    p.add_argument(
+    mode.add_argument(
         "--fused-parity", type=int, default=None, metavar="N",
         help="one-command verdict: spawn the fused probe at 1 and N forced "
         "host devices (fresh subprocess each), compare digests at "
         "reassociation tolerance, require the lane-sharding proof at N",
+    )
+    p.add_argument(
+        "--devices", type=int, default=8,
+        help="probe modes: the device count to assert",
     )
     p.add_argument(
         "--dcn-slices", type=int, default=1,
@@ -724,7 +559,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         return probe(
             args.devices, args.steps, args.parity_steps, args.dcn_slices
         )
-    return preflight(args.devices, args.force_host)
+    return force_host_dryrun(args.force_host)
 
 
 if __name__ == "__main__":

@@ -120,7 +120,9 @@ def main() -> None:
     )
     from dotaclient_tpu.league import evaluate
     from dotaclient_tpu.train.learner import Learner
+    from dotaclient_tpu.utils import compile_cache
 
+    compile_cache.enable()
     if args.hero_pool is not None:
         try:
             hero_pool = tuple(int(h) for h in args.hero_pool.split(","))

@@ -426,7 +426,7 @@ class TestShardedSnapshots:
                 learner._snap_engine.stop()
 
 
-class TestPreflightAndSchema:
+class TestSchema:
     def _load_script(self, name):
         import importlib.util
 
@@ -436,48 +436,6 @@ class TestPreflightAndSchema:
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
         return mod
-
-    def test_preflight_classifies_libtpu_mismatch(self):
-        """The exact failure shape that produced MULTICHIP_r01.json's
-        40-frame traceback must classify into a one-line reason + a
-        remediation line (the actionable-skip contract)."""
-        mod = self._load_script("run_multichip")
-        tail = (
-            'jax.errors.JaxRuntimeError: FAILED_PRECONDITION: libtpu '
-            'version mismatch: terminal has "TFRT TPU v5 lite ... '
-            'cl/831091709", client AOT libtpu has "... cl/854318611". '
-            'Client and terminal must use the same libtpu build'
-        )
-        got = mod.classify_backend_error(tail)
-        assert got is not None
-        reason, remediation = got
-        assert "libtpu" in reason
-        assert "--force-host" in remediation
-        # generic FAILED_PRECONDITION still classifies (second signature)
-        assert mod.classify_backend_error(
-            "FAILED_PRECONDITION: something else"
-        ) is not None
-        # a hung backend init surfaces as the timeout marker and must
-        # classify too (a held chip usually BLOCKS init, not errors)
-        timeout_reason, timeout_fix = mod.classify_backend_error(
-            "MULTICHIP_PREFLIGHT_TIMEOUT after 300s\n"
-        )
-        assert "timeout" in timeout_reason
-        assert "--force-host" in timeout_fix
-        # unknown breakage stays unclassified → caller reports the tail
-        assert mod.classify_backend_error("ValueError: nope") is None
-
-    def test_preflight_timeout_becomes_marker_not_traceback(self):
-        """A subprocess that outlives its timeout returns the classifiable
-        marker (rc -1) instead of raising TimeoutExpired out of the
-        preflight — the no-traceback contract covers hangs."""
-        mod = self._load_script("run_multichip")
-        rc, out = mod._run_subprocess(
-            "import time; time.sleep(60)", timeout=1.0
-        )
-        assert rc == -1
-        assert "MULTICHIP_PREFLIGHT_TIMEOUT" in out
-        assert mod.classify_backend_error(out) is not None
 
     def test_require_multichip_tier(self):
         """--require-multichip pins exactly the eager-created mesh keys."""
