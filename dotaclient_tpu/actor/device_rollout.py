@@ -395,94 +395,105 @@ class DeviceActor:
             # per-GAME key triple [N, 3, 2]: carry / learner lanes / opp
             # lanes — each game's stream is independent, so the whole split
             # is shard-local under the lane sharding
-            ks = jax.vmap(lambda k: jax.random.split(k, 3))(key)
-            key2, k_act, k_opp = ks[:, 0], ks[:, 1], ks[:, 2]
+            with jax.named_scope("rollout_sample"):
+                ks = jax.vmap(lambda k: jax.random.split(k, 3))(key)
+                key2, k_act, k_opp = ks[:, 0], ks[:, 1], ks[:, 2]
 
-            obs = feat.featurize(sim)
+            # Each stage of a rollout step carries a scope (metadata only;
+            # the policy's two forward passes keep the policy's own), so a
+            # profiler trace times the stages by name.
+            with jax.named_scope("rollout_featurize"):
+                obs = feat.featurize(sim)
             logits, _, lstm2 = self.policy.apply(
                 params, obs, lstm, method="step"
             )
-            acts, logp = sample_per_game(k_act, logits, obs, spec.n_games)
-            packed = jnp.stack(
-                [acts[h] for h in D.HEADS], axis=1
-            ).astype(jnp.int32)
-            sim_acts = feat.actions_to_sim(packed)
+            with jax.named_scope("rollout_sample"):
+                acts, logp = sample_per_game(k_act, logits, obs, spec.n_games)
+                packed = jnp.stack(
+                    [acts[h] for h in D.HEADS], axis=1
+                ).astype(jnp.int32)
+                sim_acts = feat.actions_to_sim(packed)
 
             if self._opp_feat is not None:
-                oobs = self._opp_feat.featurize(sim)
+                with jax.named_scope("rollout_featurize"):
+                    oobs = self._opp_feat.featurize(sim)
                 ologits, _, opp_lstm2 = self.policy.apply(
                     opp_params, oobs, opp_lstm, method="step"
                 )
-                oacts, _ = sample_per_game(
-                    k_opp, ologits, oobs, spec.n_games
-                )
-                opacked = jnp.stack(
-                    [oacts[h] for h in D.HEADS], axis=1
-                ).astype(jnp.int32)
-                osim = self._opp_feat.actions_to_sim(opacked)
-                opp_mask = jnp.zeros((spec.n_players,), bool).at[
-                    jnp.asarray(self.opponent_players)
-                ].set(True)
-                sim_acts = {
-                    k: jnp.where(opp_mask[None, :], osim[k], sim_acts[k])
-                    for k in sim_acts
-                }
+                with jax.named_scope("rollout_sample"):
+                    oacts, _ = sample_per_game(
+                        k_opp, ologits, oobs, spec.n_games
+                    )
+                    opacked = jnp.stack(
+                        [oacts[h] for h in D.HEADS], axis=1
+                    ).astype(jnp.int32)
+                    osim = self._opp_feat.actions_to_sim(opacked)
+                    opp_mask = jnp.zeros((spec.n_players,), bool).at[
+                        jnp.asarray(self.opponent_players)
+                    ].set(True)
+                    sim_acts = {
+                        k: jnp.where(opp_mask[None, :], osim[k], sim_acts[k])
+                        for k in sim_acts
+                    }
             else:
                 opp_lstm2 = opp_lstm
 
-            sim2 = sim_mod.step(
-                spec, sim, sim_acts,
-                scripted_possible=(
-                    self.config.env.opponent not in ("selfplay", "league")
-                    or self.n_anchor_games > 0
-                ),
-            )
-            r_terms = shaped_reward_terms(
-                spec, self.learner_players, sim, sim2,
-                weights=cfg.reward.as_dict(),
-            )
-            # the single-sourced table-order fold: bit-identical to the
-            # historical shaped_rewards sum (features.reward.fold_terms)
-            r = fold_terms(r_terms)
-            done_g = sim2.done
-            win_g = done_g & (sim2.winning_team == owner_team)
-            ep_ret = ep_ret + r
-            # outcome plane: this step closed the episode at length
-            # ep_steps+1 for done games; the counter resets in-scan
-            ep_steps2 = ep_steps + 1
-            ep_len_g = jnp.where(done_g, ep_steps2, 0)
-            ep_steps3 = jnp.where(done_g, 0, ep_steps2)
-
-            sim3 = sim_mod.reset_where(spec, sim2, done_g)
-            done_lane = jnp.repeat(done_g, A)
-            lstm3 = mask_carry(lstm2, 1.0 - done_lane.astype(jnp.float32))
-            if self._opp_feat is not None:
-                opp_done = jnp.repeat(done_g, len(self.opponent_players))
-                opp_lstm3 = mask_carry(
-                    opp_lstm2, 1.0 - opp_done.astype(jnp.float32)
+            with jax.named_scope("rollout_sim_step"):
+                sim2 = sim_mod.step(
+                    spec, sim, sim_acts,
+                    scripted_possible=(
+                        self.config.env.opponent not in ("selfplay", "league")
+                        or self.n_anchor_games > 0
+                    ),
                 )
-            else:
-                opp_lstm3 = opp_lstm2
+            with jax.named_scope("rollout_reward"):
+                r_terms = shaped_reward_terms(
+                    spec, self.learner_players, sim, sim2,
+                    weights=cfg.reward.as_dict(),
+                )
+                # the single-sourced table-order fold: bit-identical to the
+                # historical shaped_rewards sum (features.reward.fold_terms)
+                r = fold_terms(r_terms)
+            with jax.named_scope("rollout_reset"):
+                done_g = sim2.done
+                win_g = done_g & (sim2.winning_team == owner_team)
+                ep_ret = ep_ret + r
+                # outcome plane: this step closed the episode at length
+                # ep_steps+1 for done games; the counter resets in-scan
+                ep_steps2 = ep_steps + 1
+                ep_len_g = jnp.where(done_g, ep_steps2, 0)
+                ep_steps3 = jnp.where(done_g, 0, ep_steps2)
 
-            # completed-episode returns leave through stats; the accumulator
-            # resets on done (owner lane per game, matching the pools)
-            owner_ret = ep_ret.reshape(-1, A)[:, 0]
-            out = {
-                "obs": obs,
-                "packed": packed,
-                "logp": logp,
-                "reward": r,
-                "done_lane": done_lane.astype(jnp.float32),
-                "ep_done": done_g,
-                "win": win_g,
-                "ep_len": ep_len_g,
-                "ep_return": jnp.where(done_g, owner_ret, 0.0),
-                # per-term rewards kept PER-LANE [L]: the post-scan sums
-                # reduce only the step axis, so the accumulators stay
-                # shard-local partials under the lane sharding
-                "rew_terms": r_terms,
-            }
-            ep_ret = jnp.where(done_lane, 0.0, ep_ret)
+                sim3 = sim_mod.reset_where(spec, sim2, done_g)
+                done_lane = jnp.repeat(done_g, A)
+                lstm3 = mask_carry(lstm2, 1.0 - done_lane.astype(jnp.float32))
+                if self._opp_feat is not None:
+                    opp_done = jnp.repeat(done_g, len(self.opponent_players))
+                    opp_lstm3 = mask_carry(
+                        opp_lstm2, 1.0 - opp_done.astype(jnp.float32)
+                    )
+                else:
+                    opp_lstm3 = opp_lstm2
+
+                # completed-episode returns leave through stats; the accumulator
+                # resets on done (owner lane per game, matching the pools)
+                owner_ret = ep_ret.reshape(-1, A)[:, 0]
+                out = {
+                    "obs": obs,
+                    "packed": packed,
+                    "logp": logp,
+                    "reward": r,
+                    "done_lane": done_lane.astype(jnp.float32),
+                    "ep_done": done_g,
+                    "win": win_g,
+                    "ep_len": ep_len_g,
+                    "ep_return": jnp.where(done_g, owner_ret, 0.0),
+                    # per-term rewards kept PER-LANE [L]: the post-scan sums
+                    # reduce only the step axis, so the accumulators stay
+                    # shard-local partials under the lane sharding
+                    "rew_terms": r_terms,
+                }
+                ep_ret = jnp.where(done_lane, 0.0, ep_ret)
             return (sim3, lstm3, opp_lstm3, key2, ep_ret, ep_steps3), out
 
         (sim_f, lstm_f, opp_f, key_f, ep_ret_f, ep_steps_f), outs = jax.lax.scan(
@@ -495,61 +506,63 @@ class DeviceActor:
             length=T,
         )
 
-        bootstrap = feat.featurize(sim_f)                        # [L, ...]
+        with jax.named_scope("rollout_featurize"):
+            bootstrap = feat.featurize(sim_f)                    # [L, ...]
 
-        def to_chunk_obs(seq, boot):
-            # [T, L, ...] -> [L, T+1, ...]
-            seq = jnp.moveaxis(seq, 0, 1)
-            return jnp.concatenate([seq, boot[:, None]], axis=1)
+        with jax.named_scope("rollout_assemble"):
+            def to_chunk_obs(seq, boot):
+                # [T, L, ...] -> [L, T+1, ...]
+                seq = jnp.moveaxis(seq, 0, 1)
+                return jnp.concatenate([seq, boot[:, None]], axis=1)
 
-        obs_seq = jax.tree.map(to_chunk_obs, outs["obs"], bootstrap)
-        packed = jnp.moveaxis(outs["packed"], 0, 1)              # [L, T, 5]
-        chunk = {
-            "obs": obs_seq,
-            "actions": {
-                h: packed[:, :, j] for j, h in enumerate(D.HEADS)
-            },
-            "behavior_logp": jnp.moveaxis(outs["logp"], 0, 1),
-            "rewards": jnp.moveaxis(outs["reward"], 0, 1),
-            "dones": jnp.moveaxis(outs["done_lane"], 0, 1),
-            "valid": jnp.ones((self.n_lanes, T), jnp.float32),
-            "carry0": carry0,
-        }
-        lg = self._league_game_mask[None, :]     # [1, N] non-anchor games
-        # Stats are PER-GAME/PER-LANE partials (ISSUE 18): only the step
-        # axis reduces here, the game/lane axis survives — under the lane
-        # sharding every accumulation is shard-local and the rollout half
-        # of the fused program emits NO collective. The host folds the
-        # surviving axis at drain time (reduce_device_stats).
-        stats = {
-            "episodes": outs["ep_done"].sum(0).astype(jnp.float32),
-            "wins": outs["win"].sum(0).astype(jnp.float32),
-            "reward_sum": outs["reward"].sum(0),
-            "ep_return_sum": outs["ep_return"].sum(0),
-            # snapshot-attributable outcomes only (anchor games excluded)
-            "league_episodes": (outs["ep_done"] & lg).sum(0).astype(jnp.float32),
-            "league_wins": (outs["win"] & lg).sum(0).astype(jnp.float32),
-        }
-        # outcome plane (ISSUE 15): done-masked per-bucket reductions +
-        # episode-length histogram + the per-term reward decomposition —
-        # all accumulated on device, drained with the existing stats sync
-        stats.update(
-            outcome_ingraph.chunk_outcome_partials(
-                outs["ep_done"], outs["win"], outs["ep_len"],
-                self._outcome_masks,
+            obs_seq = jax.tree.map(to_chunk_obs, outs["obs"], bootstrap)
+            packed = jnp.moveaxis(outs["packed"], 0, 1)              # [L, T, 5]
+            chunk = {
+                "obs": obs_seq,
+                "actions": {
+                    h: packed[:, :, j] for j, h in enumerate(D.HEADS)
+                },
+                "behavior_logp": jnp.moveaxis(outs["logp"], 0, 1),
+                "rewards": jnp.moveaxis(outs["reward"], 0, 1),
+                "dones": jnp.moveaxis(outs["done_lane"], 0, 1),
+                "valid": jnp.ones((self.n_lanes, T), jnp.float32),
+                "carry0": carry0,
+            }
+            lg = self._league_game_mask[None, :]     # [1, N] non-anchor games
+            # Stats are PER-GAME/PER-LANE partials (ISSUE 18): only the step
+            # axis reduces here, the game/lane axis survives — under the lane
+            # sharding every accumulation is shard-local and the rollout half
+            # of the fused program emits NO collective. The host folds the
+            # surviving axis at drain time (reduce_device_stats).
+            stats = {
+                "episodes": outs["ep_done"].sum(0).astype(jnp.float32),
+                "wins": outs["win"].sum(0).astype(jnp.float32),
+                "reward_sum": outs["reward"].sum(0),
+                "ep_return_sum": outs["ep_return"].sum(0),
+                # snapshot-attributable outcomes only (anchor games excluded)
+                "league_episodes": (outs["ep_done"] & lg).sum(0).astype(jnp.float32),
+                "league_wins": (outs["win"] & lg).sum(0).astype(jnp.float32),
+            }
+            # outcome plane (ISSUE 15): done-masked per-bucket reductions +
+            # episode-length histogram + the per-term reward decomposition —
+            # all accumulated on device, drained with the existing stats sync
+            stats.update(
+                outcome_ingraph.chunk_outcome_partials(
+                    outs["ep_done"], outs["win"], outs["ep_len"],
+                    self._outcome_masks,
+                )
             )
-        )
-        stats["out_reward_terms"] = {
-            term: outs["rew_terms"][term].sum(0)
-            for term in outcome_records.REWARD_TERMS
-        }
-        cum_stats = jax.tree.map(
-            lambda a, b: a + b, state.stats, stats
-        )
-        new_state = DeviceActorState(
-            sim=sim_f, carry=lstm_f, opp_carry=opp_f, key=key_f,
-            ep_return=ep_ret_f, ep_steps=ep_steps_f, stats=cum_stats,
-        )
+            stats["out_reward_terms"] = {
+                term: outs["rew_terms"][term].sum(0)
+                for term in outcome_records.REWARD_TERMS
+            }
+            cum_stats = jax.tree.map(
+                lambda a, b: a + b, state.stats, stats
+            )
+            new_state = DeviceActorState(
+                sim=sim_f, carry=lstm_f, opp_carry=opp_f, key=key_f,
+                ep_return=ep_ret_f, ep_steps=ep_steps_f, stats=cum_stats,
+            )
         return new_state, chunk, stats
 
     # -- host surface ------------------------------------------------------

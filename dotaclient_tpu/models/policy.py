@@ -205,7 +205,8 @@ class Policy(nn.Module):
         episode — exactly matching the actor-side reset — so step t+1 starts
         its new episode from a fresh carry. Without ``dones`` the behavior is
         unchanged (scalar-pool chunks never span episodes)."""
-        x, unit_emb = self._trunk(obs)                            # [B, T, H]
+        with jax.named_scope("policy_trunk"):
+            x, unit_emb = self._trunk(obs)                        # [B, T, H]
         T = x.shape[1]
         if dones is None:
             resets = jnp.zeros((x.shape[0], T), x.dtype)

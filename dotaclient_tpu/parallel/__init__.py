@@ -19,7 +19,6 @@ from dotaclient_tpu.parallel.expert import make_expert_dispatch
 from dotaclient_tpu.parallel.mesh import (
     batch_axes,
     batch_shard_count,
-    collective_probe_ms,
     data_sharding,
     make_mesh,
     replicated,
@@ -35,7 +34,6 @@ from dotaclient_tpu.parallel.sharding import param_spec, state_shardings
 __all__ = [
     "batch_axes",
     "batch_shard_count",
-    "collective_probe_ms",
     "data_sharding",
     "initialize_runtime",
     "make_expert_dispatch",

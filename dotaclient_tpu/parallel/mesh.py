@@ -116,31 +116,3 @@ def row_sharding(mesh: Mesh, config: MeshConfig, n_rows: int) -> NamedSharding:
     if n_rows > 0 and n_rows % n == 0:
         return data_sharding(mesh, config)
     return replicated(mesh)
-
-
-def collective_probe_ms(mesh: Mesh, config: MeshConfig) -> float:
-    """Measure one cross-mesh all-reduce round trip (dispatch → replicated
-    result on the host), in milliseconds.
-
-    A one-time STARTUP probe (the ``learner/psum_ms`` gauge): the train
-    path itself never blocks on its gradient psum — XLA fuses it into the
-    dispatched step — so the per-step collective cost is not separably
-    observable without a profiler. This measures the same collective shape
-    (one scalar per batch shard, summed to a replicated scalar) cold-path,
-    which bounds the mesh's reduce latency floor. On a 1-device mesh it
-    degenerates to dispatch+fetch latency. Deliberately blocking — call it
-    at construction, never from the train loop.
-    """
-    import time
-
-    import jax.numpy as jnp
-
-    n = batch_shard_count(mesh, config)
-    xs = jax.device_put(
-        np.ones((n,), np.float32), data_sharding(mesh, config)
-    )
-    fn = jax.jit(lambda x: jnp.sum(x), out_shardings=replicated(mesh))
-    fn(xs).block_until_ready()   # compile outside the measurement
-    t0 = time.perf_counter()
-    fn(xs).block_until_ready()   # host-sync-ok: one-time startup probe
-    return (time.perf_counter() - t0) * 1e3

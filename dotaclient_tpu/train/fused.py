@@ -198,16 +198,21 @@ def make_fused_step(
         return new_state, fold_scan_metrics(metric_seq)
 
     def one_iter(state, actor_state, opp_params):
-        actor_state, chunk, stats = actor._rollout_impl(
-            state.params, actor_state, opp_params
-        )
-        # no-op assertion: the chunk is BORN data-sharded (its lanes
-        # inherit the actor state's lane sharding); this pin turns a
-        # layout regression into a visible reshard instead of silence
-        chunk = jax.tree.map(
-            lambda x: jax.lax.with_sharding_constraint(x, ds), chunk
-        )
-        new_state, metrics = update_on_chunk(state, chunk)
+        # The two phases carry a scope each (metadata only): a profiler
+        # trace splits the program's device time by them, and what is
+        # under neither is the benchmark's `unscoped_device_share`.
+        with jax.named_scope("phase_rollout"):
+            actor_state, chunk, stats = actor._rollout_impl(
+                state.params, actor_state, opp_params
+            )
+            # no-op assertion: the chunk is BORN data-sharded (its lanes
+            # inherit the actor state's lane sharding); this pin turns a
+            # layout regression into a visible reshard instead of silence
+            chunk = jax.tree.map(
+                lambda x: jax.lax.with_sharding_constraint(x, ds), chunk
+            )
+        with jax.named_scope("phase_update"):
+            new_state, metrics = update_on_chunk(state, chunk)
         return new_state, actor_state, metrics, stats
 
     if n_iters == 1:

@@ -172,19 +172,14 @@ class Learner:
         self.actor_mode = mode
         self.config = config
         self.mesh = make_mesh(config.mesh)
-        # Multi-chip telemetry (ISSUE 10): mesh geometry gauges plus a
-        # ONE-TIME startup probe of the mesh's all-reduce round trip
-        # (`learner/psum_ms`) — the per-step gradient psum is fused into
-        # the dispatched program and never separably observable, so the
-        # probe is the documented stand-in. All eager-created here so any
-        # learner run's JSONL validates
+        # Multi-chip telemetry (ISSUE 10): mesh geometry gauges, all
+        # eager-created here so any learner run's JSONL validates
         # `check_telemetry_schema.py --require-multichip`
         # deterministically (`buffer/shard_bytes` stays 0 for bufferless
-        # fused runs; the ring overwrites it when it allocates).
-        from dotaclient_tpu.parallel.mesh import (
-            batch_shard_count,
-            collective_probe_ms,
-        )
+        # fused runs; the ring overwrites it when it allocates). The
+        # gradient all-reduce itself is timed from a profiler trace (the
+        # benchmark's `collective_share`, `collective_exposed_share`).
+        from dotaclient_tpu.parallel.mesh import batch_shard_count
 
         reg = telemetry.get_registry()
         # Pipeline tracing + device hooks (ISSUE 12): the tracer is
@@ -199,9 +194,6 @@ class Learner:
             float(batch_shard_count(self.mesh, config.mesh))
         )
         reg.gauge("buffer/shard_bytes")
-        reg.gauge("learner/psum_ms").set(
-            collective_probe_ms(self.mesh, config.mesh)
-        )
         # Lane-sharded actor geometry (ISSUE 18): eager-created so any
         # learner JSONL validates --require-multichip; they stay 0 for
         # modes without a device-resident actor and are set to the real
@@ -1410,6 +1402,7 @@ class Learner:
         if not self._league_pending:
             return
         pending, self._league_pending = self._league_pending, []
+        self.telemetry.counter("league/report_fetches_total").inc()
         fetched = jax.device_get([st for _, st in pending])  # one sync
         for (idx, _), st in zip(pending, fetched):
             # anchor games (scripted-bot opponents) are excluded from the
@@ -1611,6 +1604,8 @@ class Learner:
             self.transport, InProcTransport
         )
 
+        boundaries = self.telemetry.counter("learner/boundaries_total")
+
         def after_step(m, frames: Optional[int] = None) -> int:
             """Boundary side effects for one loop iteration. Returns the
             number of optimizer steps a divergence rollback rewound (0 on
@@ -1623,129 +1618,143 @@ class Learner:
                 else cfg.ppo.batch_rollouts * cfg.ppo.rollout_len
             )
             step = self._host_step
-            if step % cfg.log_every < stride or (
-                self.ckpt and step % cfg.checkpoint_every < stride
-            ):
-                # ship pending health verdicts ahead of this boundary's
-                # jobs (one batched fetch on the snapshot thread); the
-                # publish branch flushes inside _publish_weights itself
-                self._flush_health()
-            if step % cfg.log_every < stride:
-                t0 = time.perf_counter()
-                # a best-model save the async metrics continuation deferred
-                # here: self.state must never be read from the snapshot
-                # thread — in-flight dispatches donate its buffers
-                self._apply_pending_best()
-                host_extra: Dict[str, float] = {}
-                if self.league is not None:
-                    self._flush_league_reports()
-                    wrs = self.league.win_rates()
-                    host_extra["league_snapshots"] = float(len(wrs))   # host-sync-ok: host ints
-                    if wrs:
-                        host_extra["league_winrate_mean"] = float(np.mean(wrs))   # host-sync-ok: host floats
-                if self.buffer is not None:
-                    host_extra.update(self.buffer.metrics())
-                elapsed = time.time() - t_start
-                host_extra["frames_per_sec"] = frames_trained / max(elapsed, 1e-9)
-                self._publish_pipeline_gauges()
-                if self._snap_engine is not None:
-                    # async (default): the device values leave through the
-                    # snapshot thread's batched fetches; this thread only
-                    # dispatches the tiny stats copy and keeps training.
-                    # The stat drain rides the never-coalesced backlog (its
-                    # accumulators were just reset — dropping it would lose
-                    # the window); the log job itself is latest-wins.
-                    stats_source = None
-                    if self.device_actor is not None:
-                        s_dev, s_fin = self.device_actor.begin_drain()
-                        self._snap_engine.submit_stats(s_dev, s_fin)
-                        stats_source = self.device_actor.stats
-                    elif self.pool is not None:
-                        # host pools: windowed stats are host floats already
-                        host_extra.update(self.pool.drain_stats())
-                    self._snap_engine.submit_metrics(
-                        {"m": m},
-                        self._make_metrics_finish(
-                            step, host_extra, stats_source
-                        ),
-                    )
-                else:
-                    # sync-snapshots mode: ONE transfer for the whole
-                    # metrics dict — the only host↔device sync this loop
-                    # performs (spans and gauges above are host values).
-                    with self.telemetry.span("learner/metrics_fetch"):
-                        scalars = {
-                            k: float(v) for k, v in jax.device_get(m).items()   # host-sync-ok: log_every boundary (sync-snapshots mode)
-                        }
-                        if self.device_actor is not None:
-                            scalars.update(self.device_actor.drain_stats())
-                        elif self.pool is not None:
-                            scalars.update(self.pool.drain_stats())
-                    # the fetch blocked on the dispatched step — overlap
-                    # window for prefetch accounting closes here
-                    self._dispatch_inflight = False
-                    if self._health is not None:
-                        # sync-mode health verdicts fold from the boundary
-                        # scalars just fetched — zero extra transfers,
-                        # detection at log cadence
-                        self._health.fold_host(
-                            step, self._host_version, scalars
-                        )
-                    scalars.update(host_extra)
-                    self._maybe_save_best(scalars)
-                    if self._best_dir is not None:
-                        scalars["best_win_rate"] = self._best_win
-                    # outcome curves (ISSUE 15): tick after the drain
-                    # above folded this window's episodes — same-line
-                    # consistency as the async continuation
-                    if not self._fleet_started:
-                        self.outcome.tick()
-                    self._last_metrics = self.metrics.log(step, scalars)
-                self._stall_s += time.perf_counter() - t0
-                self.telemetry.gauge("learner/stall_fraction").set(
-                    self._stall_s / max(elapsed, 1e-9)
-                )
+            tel = self.telemetry
+            at_log = step % cfg.log_every < stride
             # `< stride` (not `== 0`): the counter advances in strides of
             # epochs_per_batch × steps_per_dispatch, which may step over
             # exact multiples.
-            if self.ckpt and step % cfg.checkpoint_every < stride:
+            at_ckpt = bool(self.ckpt) and step % cfg.checkpoint_every < stride
+            if at_log:
+                # every host stretch of the log boundary is a span, so a
+                # profiler trace says which of them held the device back
+                with tel.span("learner/boundary", step=step):
+                    boundaries.inc()
+                    # ship pending health verdicts ahead of this boundary's
+                    # jobs (one batched fetch on the snapshot thread); the
+                    # publish branch flushes inside _publish_weights itself
+                    with tel.span("learner/boundary/flush_health"):
+                        self._flush_health()
+                    t0 = time.perf_counter()
+                    # a best-model save the async metrics continuation deferred
+                    # here: self.state must never be read from the snapshot
+                    # thread — in-flight dispatches donate its buffers
+                    self._apply_pending_best()
+                    host_extra: Dict[str, float] = {}
+                    if self.league is not None:
+                        with tel.span("learner/boundary/league_fetch"):
+                            self._flush_league_reports()
+                        wrs = self.league.win_rates()
+                        host_extra["league_snapshots"] = float(len(wrs))   # host-sync-ok: host ints
+                        if wrs:
+                            host_extra["league_winrate_mean"] = float(np.mean(wrs))   # host-sync-ok: host floats
+                    if self.buffer is not None:
+                        host_extra.update(self.buffer.metrics())
+                    elapsed = time.time() - t_start
+                    host_extra["frames_per_sec"] = frames_trained / max(elapsed, 1e-9)
+                    with tel.span("learner/boundary/gauges"):
+                        self._publish_pipeline_gauges()
+                    if self._snap_engine is not None:
+                        # async (default): the device values leave through the
+                        # snapshot thread's batched fetches; this thread only
+                        # dispatches the tiny stats copy and keeps training.
+                        # The stat drain rides the never-coalesced backlog (its
+                        # accumulators were just reset — dropping it would lose
+                        # the window); the log job itself is latest-wins.
+                        stats_source = None
+                        if self.device_actor is not None:
+                            with tel.span("learner/boundary/stats_drain"):
+                                s_dev, s_fin = self.device_actor.begin_drain()
+                                self._snap_engine.submit_stats(s_dev, s_fin)
+                            stats_source = self.device_actor.stats
+                        elif self.pool is not None:
+                            # host pools: windowed stats are host floats already
+                            host_extra.update(self.pool.drain_stats())
+                        with tel.span("learner/boundary/submit_metrics"):
+                            self._snap_engine.submit_metrics(
+                                {"m": m},
+                                self._make_metrics_finish(
+                                    step, host_extra, stats_source
+                                ),
+                            )
+                    else:
+                        # sync-snapshots mode: ONE transfer for the whole
+                        # metrics dict — the only host↔device sync this loop
+                        # performs (spans and gauges above are host values).
+                        with tel.span("learner/metrics_fetch"):
+                            scalars = {
+                                k: float(v) for k, v in jax.device_get(m).items()   # host-sync-ok: log_every boundary (sync-snapshots mode)
+                            }
+                            if self.device_actor is not None:
+                                scalars.update(self.device_actor.drain_stats())
+                            elif self.pool is not None:
+                                scalars.update(self.pool.drain_stats())
+                        # the fetch blocked on the dispatched step — overlap
+                        # window for prefetch accounting closes here
+                        self._dispatch_inflight = False
+                        if self._health is not None:
+                            # sync-mode health verdicts fold from the boundary
+                            # scalars just fetched — zero extra transfers,
+                            # detection at log cadence
+                            self._health.fold_host(
+                                step, self._host_version, scalars
+                            )
+                        scalars.update(host_extra)
+                        self._maybe_save_best(scalars)
+                        if self._best_dir is not None:
+                            scalars["best_win_rate"] = self._best_win
+                        # outcome curves (ISSUE 15): tick after the drain
+                        # above folded this window's episodes — same-line
+                        # consistency as the async continuation
+                        if not self._fleet_started:
+                            self.outcome.tick()
+                        with tel.span("learner/boundary/log"):
+                            self._last_metrics = self.metrics.log(step, scalars)
+                    self._stall_s += time.perf_counter() - t0
+                    tel.gauge("learner/stall_fraction").set(
+                        self._stall_s / max(elapsed, 1e-9)
+                    )
+            if at_ckpt:
                 # periodic saves are weights-only: the pipeline extras cost a
                 # full buffer+actor device fetch (tens of MB, a train-loop
                 # stall); the forced end-of-run save below captures the
                 # complete pipeline
-                t0 = time.perf_counter()
-                if self._snap_engine is not None:
-                    # one cheap on-device copy of the WHOLE TrainState; the
-                    # snapshot thread fetches it (one transfer), health-
-                    # gates it (verdicts ≤ this step land first — flushed
-                    # above), and writes
-                    self._snap_engine.submit_checkpoint(
-                        self._snap_copy(self.state), cfg
-                    )
-                else:
-                    # sync mode: log-boundary folds may not cover THIS
-                    # step (checkpoint_every and log_every need not align)
-                    # — fold the latest verdict before gating, or a
-                    # poisoned state could earn the last_good mark
-                    self._sync_fold_latest()
-                    if (
-                        self._health is not None
-                        and self._health.unhealthy is not None
-                    ):
-                        # contain (sync mode): a poisoned state never
-                        # enters the rolling retention
-                        self.telemetry.counter(
-                            "health/checkpoints_blocked_total"
-                        ).inc()
-                    else:
-                        self.ckpt.save(
-                            self.state, cfg,
-                            mark_good=self._health is not None,
+                with tel.span("learner/checkpoint_submit", step=step):
+                    if not at_log:
+                        # the log boundary above has flushed them already
+                        self._flush_health()
+                    t0 = time.perf_counter()
+                    if self._snap_engine is not None:
+                        # one cheap on-device copy of the WHOLE TrainState; the
+                        # snapshot thread fetches it (one transfer), health-
+                        # gates it (verdicts ≤ this step land first — flushed
+                        # above), and writes
+                        self._snap_engine.submit_checkpoint(
+                            self._snap_copy(self.state), cfg
                         )
-                ckpt_dt = time.perf_counter() - t0
-                self._stall_s += ckpt_dt
-                if self._util is not None:
-                    self._util.phase("checkpoint_stall", ckpt_dt)
+                    else:
+                        # sync mode: log-boundary folds may not cover THIS
+                        # step (checkpoint_every and log_every need not align)
+                        # — fold the latest verdict before gating, or a
+                        # poisoned state could earn the last_good mark
+                        self._sync_fold_latest()
+                        if (
+                            self._health is not None
+                            and self._health.unhealthy is not None
+                        ):
+                            # contain (sync mode): a poisoned state never
+                            # enters the rolling retention
+                            tel.counter(
+                                "health/checkpoints_blocked_total"
+                            ).inc()
+                        else:
+                            self.ckpt.save(
+                                self.state, cfg,
+                                mark_good=self._health is not None,
+                            )
+                    ckpt_dt = time.perf_counter() - t0
+                    self._stall_s += ckpt_dt
+                    if self._util is not None:
+                        self._util.phase("checkpoint_stall", ckpt_dt)
             if (
                 publish_midrun
                 and refresh_every
@@ -1769,30 +1778,43 @@ class Learner:
                 da = self.device_actor
                 k_iters = cfg.steps_per_dispatch
                 frames_per = da.n_lanes * cfg.ppo.rollout_len * k_iters
+                # The loop's host stretches, named for the profiler
+                # (telemetry.Registry.span): one `learner/iteration` per
+                # dispatch, its self time the loop work no child names.
+                tel = self.telemetry
+                dispatches = tel.counter("learner/dispatches_total")
+                frozen = tel.counter("league/frozen_dispatches_total")
                 while steps_done < num_steps and not self._stop_requested:
-                    opp_params, opp_idx = self._league_opponent()
-                    if opp_params is None:       # self-play / scripted: one
-                        opp_params = self.state.params   # signature for all modes
-                    t0 = time.perf_counter()
-                    self.state, da.state, m, chunk_stats = self.fused_step(
-                        self.state, da.state, opp_params
-                    )
-                    if self._util is not None:
-                        self._util.phase(
-                            "dispatch_inflight", time.perf_counter() - t0
-                        )
-                    self._report_league(opp_idx, chunk_stats)
-                    # the program ran `stride` optimizer steps over K chunks —
-                    # keep the host mirrors in lockstep with the device counters
-                    self._host_step += stride
-                    self._host_version += stride
-                    da.env_steps += frames_per
-                    da.rollouts_shipped += da.n_lanes * k_iters
-                    self._submit_health(m)
-                    if self._tracer is not None:
-                        self._emit_dispatch_traces()
-                    steps_done += stride
-                    steps_done -= after_step(m, frames=frames_per)
+                    with tel.span("learner/iteration", step=self._host_step):
+                        with tel.span("learner/league_draw"):
+                            opp_params, opp_idx = self._league_opponent()
+                        if opp_params is None:       # self-play / scripted: one
+                            opp_params = self.state.params   # signature for all modes
+                        t0 = time.perf_counter()
+                        with tel.span("learner/dispatch"):
+                            self.state, da.state, m, chunk_stats = self.fused_step(
+                                self.state, da.state, opp_params
+                            )
+                        if self._util is not None:
+                            self._util.phase(
+                                "dispatch_inflight", time.perf_counter() - t0
+                            )
+                        dispatches.inc()
+                        if opp_idx != league_pool.LIVE:
+                            frozen.inc()
+                        with tel.span("learner/league_report"):
+                            self._report_league(opp_idx, chunk_stats)
+                        # the program ran `stride` optimizer steps over K chunks —
+                        # keep the host mirrors in lockstep with the device counters
+                        self._host_step += stride
+                        self._host_version += stride
+                        da.env_steps += frames_per
+                        da.rollouts_shipped += da.n_lanes * k_iters
+                        self._submit_health(m)
+                        if self._tracer is not None:
+                            self._emit_dispatch_traces()
+                        steps_done += stride
+                        steps_done -= after_step(m, frames=frames_per)
             elif self.device_actor is not None:
                 # On-device rollout mode: collect→ingest→train is all dispatch
                 # (the device serializes rollout and train programs back-to-back,

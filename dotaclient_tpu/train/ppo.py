@@ -175,27 +175,29 @@ def ppo_loss(
         adv = batch["advantages"].astype(jnp.float32)
         returns = batch["returns"].astype(jnp.float32)
     elif cfg.advantage == "gae":
-        adv, returns = gae(
-            batch["rewards"],
-            jax.lax.stop_gradient(values),
-            batch["dones"],
-            cfg.gamma,
-            cfg.gae_lambda,
-        )
+        with jax.named_scope("update_gae"):
+            adv, returns = gae(
+                batch["rewards"],
+                jax.lax.stop_gradient(values),
+                batch["dones"],
+                cfg.gamma,
+                cfg.gae_lambda,
+            )
     elif cfg.advantage == "vtrace":
         # Importance weights are constants to the optimizer (stop-grad on
         # the target logp): the surrogate's gradient flows through the
         # ratio below, not through the advantage estimate.
-        adv, returns = vtrace(
-            batch["rewards"],
-            jax.lax.stop_gradient(values),
-            batch["dones"],
-            batch["behavior_logp"],
-            jax.lax.stop_gradient(logp),
-            cfg.gamma,
-            cfg.vtrace_rho_clip,
-            cfg.vtrace_c_clip,
-        )
+        with jax.named_scope("update_gae"):
+            adv, returns = vtrace(
+                batch["rewards"],
+                jax.lax.stop_gradient(values),
+                batch["dones"],
+                batch["behavior_logp"],
+                jax.lax.stop_gradient(logp),
+                cfg.gamma,
+                cfg.vtrace_rho_clip,
+                cfg.vtrace_c_clip,
+            )
     else:
         raise ValueError(
             f"unknown advantage {cfg.advantage!r} (one of {ADVANTAGE_MODES})"
@@ -301,125 +303,131 @@ def _train_step(
         ),
         has_aux=True,
     )
-    (_, metrics), grads = grad_fn(state.params)
-    if cfg.value_warmup_steps:
-        # Critic-only warmup: zero every gradient outside the value head so
-        # the behavior policy is EXACTLY frozen (value-loss gradients still
-        # flow through the shared trunk otherwise). The head itself keeps
-        # its full gradient and recalibrates to this config's returns.
-        policy_on = (state.step >= cfg.value_warmup_steps).astype(jnp.float32)
-
-        def _mask(path, g):
-            in_value_head = any(
-                getattr(k, "key", None) == "head_value" for k in path
-            )
-            # astype(g.dtype): a float32 scalar would silently promote
-            # bfloat16 grads (and with them Adam's moments) to float32,
-            # retracing the donated step and skewing checkpoint templates.
-            return g if in_value_head else g * policy_on.astype(g.dtype)
-
-        grads = jax.tree_util.tree_map_with_path(_mask, grads)
-    opt = make_optimizer(cfg)
-    opt_state_in = state.opt_state
-    if cfg.value_warmup_steps:
-        # At the warmup boundary, re-init the optimizer state: the frozen
-        # params sat out the warmup with zero moments while Adam's shared
-        # step count advanced, so their bias correction is desynchronized —
-        # the first post-warmup update would be ~(1-b1)/sqrt(1-b2) ≈ 3×
-        # oversized across every policy param at once, exactly the
-        # destroy-the-transferred-policy kick this feature exists to
-        # prevent. A fresh opt_state makes the first live step behave like
-        # a fresh optimizer's first step. (The value head's moments reset
-        # too — harmless, it has converged toward this config's returns by
-        # then.) jnp.where keeps the opt_state structure unchanged, so
-        # checkpoints stay layout-compatible.
-        at_boundary = state.step == cfg.value_warmup_steps
-        fresh = opt.init(state.params)
-        opt_state_in = jax.tree.map(
-            lambda f, cur: jnp.where(at_boundary, f, cur),
-            fresh, opt_state_in,
-        )
-    updates, opt_state = opt.update(grads, opt_state_in, state.params)
-    params = optax.apply_updates(state.params, updates)
-    if cfg.kl_target > 0:
-        # KL-adaptive lr: measure the POST-update policy shift on this
-        # batch's taken actions (k3 estimator, E_old[r − 1 − log r] ≥ 0)
-        # and rescale the lr carried in the optimizer state for the NEXT
-        # step. All in-graph: no host sync, fused-mode compatible.
-        logp_pre = metrics.pop("_logp")
-
-        def _measure_kl(operand):
-            params_new, lp_pre = operand
-            T = batch["rewards"].shape[1]
-            obs = batch["obs"]
-            if "advantages" in batch:
-                # one-pass batches train on a T-step forward (the
-                # bootstrap slot only fed the estimator) — measure the
-                # post-update KL over the same window
-                obs = {k: v[:, :T] for k, v in obs.items()}
-            (logits_post, _, _), _ = policy.apply(
-                params_new, obs, batch["carry0"], batch["dones"],
-                method="sequence", mutable=["losses"],
-            )
-            logits_t = {k: v[:, :T] for k, v in logits_post.items()}
-            obs_t = {k: v[:, :T] for k, v in obs.items()}
-            logp_post = D.log_prob(logits_t, obs_t, batch["actions"])
-            d = logp_post - lp_pre
-            valid = batch["valid"].astype(jnp.float32)
-            n_valid = jnp.maximum(valid.sum(), 1.0)
-            return (((jnp.exp(d) - 1.0) - d) * valid).sum() / n_valid
-
+    # The step's stages carry a scope each (metadata only; a backward
+    # operation keeps the scope its forward was written in), so a profiler
+    # trace times loss, optimizer and probes by name.
+    with jax.named_scope("update_loss"):
+        (_, metrics), grads = grad_fn(state.params)
+    with jax.named_scope("update_optimizer"):
         if cfg.value_warmup_steps:
-            # The frozen-policy window has post-KL ≡ 0 by construction;
-            # skip the measurement forward (~a full policy pass) there.
-            post_kl = jax.lax.cond(
-                state.step >= cfg.value_warmup_steps,
-                _measure_kl,
-                lambda _: jnp.zeros(()),
-                (params, logp_pre),
-            )
-        else:
-            post_kl = _measure_kl((params, logp_pre))
+            # Critic-only warmup: zero every gradient outside the value head so
+            # the behavior policy is EXACTLY frozen (value-loss gradients still
+            # flow through the shared trunk otherwise). The head itself keeps
+            # its full gradient and recalibrates to this config's returns.
+            policy_on = (state.step >= cfg.value_warmup_steps).astype(jnp.float32)
 
-        inj = opt_state[1]
-        lr = inj.hyperparams["learning_rate"]
-        t = cfg.kl_target
-        factor = jnp.where(
-            post_kl > 2.0 * t,
-            cfg.kl_lr_down,
-            jnp.where(post_kl < 0.5 * t, cfg.kl_lr_up, 1.0),
-        )
+            def _mask(path, g):
+                in_value_head = any(
+                    getattr(k, "key", None) == "head_value" for k in path
+                )
+                # astype(g.dtype): a float32 scalar would silently promote
+                # bfloat16 grads (and with them Adam's moments) to float32,
+                # retracing the donated step and skewing checkpoint templates.
+                return g if in_value_head else g * policy_on.astype(g.dtype)
+
+            grads = jax.tree_util.tree_map_with_path(_mask, grads)
+        opt = make_optimizer(cfg)
+        opt_state_in = state.opt_state
         if cfg.value_warmup_steps:
-            # The frozen-policy window measures KL ≡ 0; don't let the
-            # controller ratchet the lr up against a flat signal (the
-            # boundary reset would restore it anyway, but the value head
-            # trains through the warmup at whatever lr this leaves).
+            # At the warmup boundary, re-init the optimizer state: the frozen
+            # params sat out the warmup with zero moments while Adam's shared
+            # step count advanced, so their bias correction is desynchronized —
+            # the first post-warmup update would be ~(1-b1)/sqrt(1-b2) ≈ 3×
+            # oversized across every policy param at once, exactly the
+            # destroy-the-transferred-policy kick this feature exists to
+            # prevent. A fresh opt_state makes the first live step behave like
+            # a fresh optimizer's first step. (The value head's moments reset
+            # too — harmless, it has converged toward this config's returns by
+            # then.) jnp.where keeps the opt_state structure unchanged, so
+            # checkpoints stay layout-compatible.
+            at_boundary = state.step == cfg.value_warmup_steps
+            fresh = opt.init(state.params)
+            opt_state_in = jax.tree.map(
+                lambda f, cur: jnp.where(at_boundary, f, cur),
+                fresh, opt_state_in,
+            )
+        updates, opt_state = opt.update(grads, opt_state_in, state.params)
+        params = optax.apply_updates(state.params, updates)
+    with jax.named_scope("update_probe"):
+        if cfg.kl_target > 0:
+            # KL-adaptive lr: measure the POST-update policy shift on this
+            # batch's taken actions (k3 estimator, E_old[r − 1 − log r] ≥ 0)
+            # and rescale the lr carried in the optimizer state for the NEXT
+            # step. All in-graph: no host sync, fused-mode compatible.
+            logp_pre = metrics.pop("_logp")
+
+            def _measure_kl(operand):
+                params_new, lp_pre = operand
+                T = batch["rewards"].shape[1]
+                obs = batch["obs"]
+                if "advantages" in batch:
+                    # one-pass batches train on a T-step forward (the
+                    # bootstrap slot only fed the estimator) — measure the
+                    # post-update KL over the same window
+                    obs = {k: v[:, :T] for k, v in obs.items()}
+                (logits_post, _, _), _ = policy.apply(
+                    params_new, obs, batch["carry0"], batch["dones"],
+                    method="sequence", mutable=["losses"],
+                )
+                logits_t = {k: v[:, :T] for k, v in logits_post.items()}
+                obs_t = {k: v[:, :T] for k, v in obs.items()}
+                logp_post = D.log_prob(logits_t, obs_t, batch["actions"])
+                d = logp_post - lp_pre
+                valid = batch["valid"].astype(jnp.float32)
+                n_valid = jnp.maximum(valid.sum(), 1.0)
+                return (((jnp.exp(d) - 1.0) - d) * valid).sum() / n_valid
+
+            if cfg.value_warmup_steps:
+                # The frozen-policy window has post-KL ≡ 0 by construction;
+                # skip the measurement forward (~a full policy pass) there.
+                post_kl = jax.lax.cond(
+                    state.step >= cfg.value_warmup_steps,
+                    _measure_kl,
+                    lambda _: jnp.zeros(()),
+                    (params, logp_pre),
+                )
+            else:
+                post_kl = _measure_kl((params, logp_pre))
+
+            inj = opt_state[1]
+            lr = inj.hyperparams["learning_rate"]
+            t = cfg.kl_target
             factor = jnp.where(
-                state.step < cfg.value_warmup_steps, 1.0, factor
+                post_kl > 2.0 * t,
+                cfg.kl_lr_down,
+                jnp.where(post_kl < 0.5 * t, cfg.kl_lr_up, 1.0),
             )
-        new_lr = jnp.clip(
-            lr * factor,
-            cfg.learning_rate * cfg.kl_lr_min_scale,
-            cfg.learning_rate * cfg.kl_lr_max_scale,
-        )
-        hp = dict(inj.hyperparams)
-        hp["learning_rate"] = new_lr
-        opt_state = (opt_state[0], inj._replace(hyperparams=hp))
-        metrics["post_kl"] = post_kl
-        metrics["lr"] = lr
-    metrics["grad_norm"] = optax.global_norm(grads)
-    if probe:
-        # Training-health probe (ISSUE 6, train/health.py): one scalar AND
-        # over the two values every step already computes. loss covers
-        # NaN/Inf anywhere in the forward/returns path (non-finite params
-        # from a previous step included); the PRE-clip gradient global
-        # norm covers a backward pass that NaN'd after a finite loss.
-        # Scanned multi-update programs AND-fold this flag
-        # (fold_scan_metrics), so one poisoned update taints the whole
-        # program's verdict.
-        metrics["health_ok"] = (
-            jnp.isfinite(metrics["loss"]) & jnp.isfinite(metrics["grad_norm"])
-        ).astype(jnp.float32)
+            if cfg.value_warmup_steps:
+                # The frozen-policy window measures KL ≡ 0; don't let the
+                # controller ratchet the lr up against a flat signal (the
+                # boundary reset would restore it anyway, but the value head
+                # trains through the warmup at whatever lr this leaves).
+                factor = jnp.where(
+                    state.step < cfg.value_warmup_steps, 1.0, factor
+                )
+            new_lr = jnp.clip(
+                lr * factor,
+                cfg.learning_rate * cfg.kl_lr_min_scale,
+                cfg.learning_rate * cfg.kl_lr_max_scale,
+            )
+            hp = dict(inj.hyperparams)
+            hp["learning_rate"] = new_lr
+            opt_state = (opt_state[0], inj._replace(hyperparams=hp))
+            metrics["post_kl"] = post_kl
+            metrics["lr"] = lr
+        metrics["grad_norm"] = optax.global_norm(grads)
+        if probe:
+            # Training-health probe (ISSUE 6, train/health.py): one scalar AND
+            # over the two values every step already computes. loss covers
+            # NaN/Inf anywhere in the forward/returns path (non-finite params
+            # from a previous step included); the PRE-clip gradient global
+            # norm covers a backward pass that NaN'd after a finite loss.
+            # Scanned multi-update programs AND-fold this flag
+            # (fold_scan_metrics), so one poisoned update taints the whole
+            # program's verdict.
+            metrics["health_ok"] = (
+                jnp.isfinite(metrics["loss"]) & jnp.isfinite(metrics["grad_norm"])
+            ).astype(jnp.float32)
     new_state = dataclasses.replace(
         state,
         step=state.step + 1,

@@ -51,7 +51,9 @@ TrainState, freed as soon as the fetch completes.
 Telemetry: ``snapshot/pending`` (job slots occupied), ``snapshot/d2h_ms``
 (last batched device→host fetch), ``span/transport/publish_weights`` and
 ``span/learner/metrics_fetch`` keep their documented keys — they are simply
-recorded from this thread now.
+recorded from this thread now — and ``span/snapshot/stats_fetch`` times each
+stats job's fetch, so a profiler trace shows what this thread held while the
+train thread's enqueue waited.
 """
 
 from __future__ import annotations
@@ -240,7 +242,9 @@ class SnapshotEngine:
                 # weights at fanout latency), then the slower orbax write
                 for dev, finish in stats_batch:
                     try:
-                        finish(jax.device_get(dev))  # host-sync-ok: snapshot thread, tiny stat scalars
+                        with self._tel.span("snapshot/stats_fetch"):
+                            host = jax.device_get(dev)  # host-sync-ok: snapshot thread, tiny stat scalars
+                        finish(host)
                     except Exception as e:  # noqa: BLE001 - engine must outlive any job
                         self._tel.counter("snapshot/errors_total").inc()
                         logger.warning(

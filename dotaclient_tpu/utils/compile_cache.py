@@ -13,6 +13,13 @@ inside the checkout (``<repo>/.jax_cache``, git-ignored). The path is never
 built from a pid, a temp name or the clock: a directory that moves between
 runs never hits.
 
+The key of an entry includes the program's metadata (scope names, source
+lines): JAX leaves them out by default, and a process would then load a
+program compiled before a ``jax.named_scope`` was added or renamed and its
+profiler trace would name every operation as the old code did. A restart of
+the same code hits as before; a changed file on the traced path compiles
+again, once.
+
 The CPU backend is left exactly as JAX configured it. Programs at the sizes
 the CPU runs (tests, rehearsals, actors) compile in seconds, XLA:CPU logs an
 error line for every entry it loads back, and a test run must not write
@@ -50,4 +57,6 @@ def enable() -> Optional[str]:
         # the serve dispatch, the snapshot copies and the gathers are
         # quicker than that and would be rebuilt by every process
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        # a trace must name operations as THIS code scopes them (see above)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return jax.config.jax_compilation_cache_dir

@@ -10,7 +10,11 @@ scalars only; the rebuild's observability is two complementary layers:
   ``MetricsLogger``. That layer answers *which stage* is slow or starved.
 * **This module**: jax.profiler device traces viewable in TensorBoard
   (tensorboard-plugin-profile) and `checkify`-instrumented train steps for
-  NaN/Inf hunting. This layer answers *why* a device stage is slow.
+  NaN/Inf hunting. This layer answers *why* a device stage is slow. The
+  two meet in the trace: every telemetry span is a ``TraceAnnotation`` on
+  the trace's host plane, and the fused step's phases are named scopes
+  (``phase_rollout``, ``phase_update``, ``rollout_*``, ``update_*``,
+  ``policy_*``) on its device plane.
 
 A third layer joined in ISSUE 12: the pipeline TRACING plane
 (``utils/tracing.py``, ``--trace-jsonl``) follows individual chunks and
@@ -40,11 +44,18 @@ import jax
 @contextlib.contextmanager
 def trace(logdir: Optional[str]) -> Iterator[None]:
     """jax.profiler device trace over the enclosed block (no-op when
-    ``logdir`` is None). View: tensorboard --logdir <logdir>."""
+    ``logdir`` is None). View: tensorboard --logdir <logdir>.
+
+    The Python tracer is off, as in the benchmark's traced runs: every
+    ``telemetry.Registry.span`` is an event of the trace's host plane, so
+    the spans name the host's stretches, and Python frames make a trace of
+    a real run too large to open."""
     if logdir is None:
         yield
         return
-    jax.profiler.start_trace(logdir)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=options)
     try:
         yield
     finally:

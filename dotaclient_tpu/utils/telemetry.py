@@ -18,11 +18,16 @@ Primitives
   approximate power-of-two-bucket histogram (``p95_s``).
 * ``Registry.span("stage")`` — context manager timing a pipeline stage into
   the timer ``span/<stage>``; spans NEST via a per-thread stack
-  (``span("a")`` inside ``span("b")`` records ``span/b/a``).
+  (``span("a")`` inside ``span("b")`` records ``span/b/a``). Where JAX is
+  loaded the span is also a ``jax.profiler.TraceAnnotation`` under its full
+  name: with a profiler session on (``--profile-dir``, the benchmark's
+  ``--trace 1``) every span of every thread is an event of ``/host:CPU`` on
+  the device trace's clock; with none the annotation is a flag test.
 
 Everything here is host-side wall clock — recording a span never touches the
 device, so the learner's "no host↔device sync except at ``log_every``"
-discipline is preserved by construction.
+discipline is preserved by construction. This module never imports JAX:
+jax-free tools keep the timers and get no annotation.
 
 Snapshot key schema (the JSONL contract; see docs/ARCHITECTURE.md
 "Observability" and scripts/check_telemetry_schema.py):
@@ -39,7 +44,12 @@ reused ingest lanes), ``buffer/insert``, ``buffer/sample``,
 ``learner/consume``, ``learner/assemble``, ``learner/dispatch``,
 ``learner/metrics_fetch``, ``learner/prefetch`` (batch N+1's
 drain+stage+scatter+gather, issued behind batch N's in-flight dispatch),
-``league/evaluate``. The pipelined data path also reports two gauges:
+``league/evaluate``; the fused loop's own (ISSUE 24): ``learner/iteration``
+(one pass of the loop, ``step=``), ``learner/league_draw``,
+``learner/league_report``, ``learner/boundary`` and its children
+``learner/boundary/<child>`` (the log boundary's host work),
+``learner/checkpoint_submit``, and the snapshot thread's
+``snapshot/stats_fetch``. The pipelined data path also reports two gauges:
 ``learner/prefetch_hit_rate`` (batches served from the prefetch lane /
 batches served) and ``learner/overlap_fraction`` (prefetch host time spent
 while a dispatch was in flight / all prefetch host time) — see
@@ -79,9 +89,10 @@ import contextlib
 import json
 import math
 import os
+import sys
 import threading
 import time
-from typing import Dict, Iterator, List, Optional, TextIO, Tuple
+from typing import Any, Dict, Iterator, List, Optional, TextIO, Tuple
 
 __all__ = [
     "Counter",
@@ -218,6 +229,13 @@ class Timer:
         }
 
 
+def _trace_annotation() -> Any:
+    """``jax.profiler.TraceAnnotation`` from a JAX that is already loaded,
+    ``None`` where none is: a span must never be what imports JAX."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return getattr(profiler, "TraceAnnotation", None)
+
+
 class Registry:
     """Named counters/gauges/timers plus the nesting ``span`` timer.
 
@@ -256,14 +274,19 @@ class Registry:
             return t
 
     @contextlib.contextmanager
-    def span(self, stage: str) -> Iterator[None]:
-        """Time one pipeline stage into ``span/<stage>``.
+    def span(self, stage: str, **attrs: Any) -> Iterator[None]:
+        """Time one pipeline stage into ``span/<stage>`` and, where JAX is
+        loaded, name it in the profiler's trace.
 
         A *bare* name (no "/") nests under the enclosing span via a
         per-thread stack — ``span("b")`` inside ``span("a")`` records
         ``span/a/b``. A name containing "/" is absolute: the documented
         pipeline stages ("buffer/insert", "learner/dispatch", ...) keep
         stable keys no matter which outer span the caller holds.
+
+        ``attrs`` go to the trace event only (``step=`` ties the spans of
+        one dispatch together; an event's parent is the event that
+        encloses it on the same thread). The timer's key never holds them.
         """
         stack: List[str] = getattr(self._span_stack, "names", None) or []
         if "/" in stage or not stack:
@@ -272,9 +295,14 @@ class Registry:
             # stack entries are already full names — extend the innermost
             full = f"{stack[-1]}/{stage}"
         self._span_stack.names = stack + [full]
+        annotation = _trace_annotation()
         t0 = time.perf_counter()
         try:
-            yield
+            with (
+                annotation(full, **attrs) if annotation is not None
+                else contextlib.nullcontext()
+            ):
+                yield
         finally:
             self.timer(f"span/{full}").observe(time.perf_counter() - t0)
             self._span_stack.names = stack

@@ -166,9 +166,8 @@ HEALTH_KEYS = (
 
 # Multi-chip learner (ISSUE 10; lane-sharding gauges PR 18). Validated
 # with --require-multichip against ANY learner run's JSONL: the Learner
-# eager-creates every key here at construction (mesh geometry, the
-# lane-sharding layout — 0s outside device/fused modes — and the
-# one-time startup all-reduce probe;
+# eager-creates every key here at construction (mesh geometry and the
+# lane-sharding layout — 0s outside device/fused modes;
 # buffer/shard_bytes stays 0 for bufferless fused runs and carries the
 # per-device resident ring bytes otherwise), so presence is deterministic
 # at every device count — a 1-device mesh is the degenerate case of the
@@ -179,7 +178,6 @@ MULTICHIP_KEYS = (
     "mesh/lane_shards",      # fused actor-state lane shard count (PR 18)
     "fused/lanes_per_shard", # local lanes per shard (0 in non-device modes)
     "buffer/shard_bytes",    # per-device resident bytes of the HBM ring
-    "learner/psum_ms",       # startup probe: one mesh all-reduce round trip
 )
 
 # Policy-serving plane (ISSUE 11). Validated with --require-serve against
@@ -553,8 +551,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         "--require-multichip", action="store_true",
         help="also require the multi-chip learner keys (ISSUE 10); valid "
         "against ANY learner run's JSONL at any device count — the "
-        "Learner eager-creates mesh geometry, the startup all-reduce "
-        "probe, and the ring's per-shard byte gauge at construction",
+        "Learner eager-creates mesh geometry and the ring's per-shard "
+        "byte gauge at construction",
     )
     args = p.parse_args(argv)
     extra: tuple = ()

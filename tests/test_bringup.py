@@ -28,10 +28,11 @@ def _load_root_script(name):
 
 @pytest.fixture
 def jax_cache_config():
-    """Hand the two jax settings the helper touches back as found."""
+    """Hand the jax settings the helper touches back as found."""
     keys = (
         "jax_compilation_cache_dir",
         "jax_persistent_cache_min_compile_time_secs",
+        "jax_compilation_cache_include_metadata_in_key",
     )
     before = {k: getattr(jax.config, k) for k in keys}
     yield
@@ -51,6 +52,9 @@ class TestCompileCache:
         assert compile_cache.enable() == "as-jax-had-it"
         assert jax.config.jax_compilation_cache_dir == "as-jax-had-it"
         assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        # scope names are part of an entry's key: a trace never names
+        # operations as a program compiled before a scope was added did
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
 
     def test_unset_is_one_fixed_in_checkout_path(
         self, monkeypatch, jax_cache_config

@@ -6,7 +6,9 @@ from identical initial state. Pinned by running both from copies of the
 same params/actor-state and comparing losses and updated parameters.
 """
 
+import collections
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -365,3 +367,66 @@ class TestFusedStep:
         out = learner.train(3)
         assert np.isfinite(out["loss"])
         assert len(learner.league.snapshots) >= 1
+
+
+# -- the program's scopes (ISSUE 24) ---------------------------------------
+# What a profiler trace splits the fused step by. The benchmark's
+# `*_device_share` readers match these names as whole path segments
+# (benchmark/readers/_scopes.py); `policy_*` are the four the older readers
+# match by substring, and must stay as they are.
+PHASE_SCOPES = ("phase_rollout", "phase_update")
+STAGE_SCOPES = (
+    "rollout_featurize", "rollout_sample", "rollout_sim_step",
+    "rollout_reward", "rollout_reset", "rollout_assemble",
+    "update_loss", "update_gae", "update_optimizer", "update_probe",
+)
+POLICY_SCOPES = ("policy_trunk", "policy_core", "policy_core_scan", "policy_heads")
+
+
+class TestFusedScopes:
+    def test_lowered_step_carries_every_scope_and_little_unscoped_work(self):
+        """The guard for `unscoped_device_share` that needs no chip: every
+        scope is in the lowered step's metadata, and nearly every operation
+        of the lowered module was written under one of the two phases."""
+        from benchmark.readers import _scopes
+        from dotaclient_tpu.actor.device_rollout import DeviceActor
+        from dotaclient_tpu.models import init_params, make_policy
+        from dotaclient_tpu.parallel import make_mesh
+        from dotaclient_tpu.train.fused import make_fused_step
+        from dotaclient_tpu.train.ppo import init_train_state
+
+        cfg = tiny_cfg(n_envs=2)
+        mesh = make_mesh(cfg.mesh, devices=jax.devices()[:1])
+        policy = make_policy(cfg.model, cfg.obs, cfg.actions)
+        # shapes only: nothing here runs
+        params = jax.eval_shape(
+            lambda: init_params(policy, jax.random.PRNGKey(0))
+        )
+        state = jax.eval_shape(lambda p: init_train_state(p, cfg.ppo), params)
+        actor = DeviceActor(cfg, policy, seed=3)
+        lowered = make_fused_step(policy, cfg, mesh, actor).lower(
+            state, actor.state, params
+        )
+        text = lowered.as_text(debug_info=True)
+        for scope in PHASE_SCOPES + STAGE_SCOPES + POLICY_SCOPES:
+            assert re.search(rf'[/"(]{scope}[/")]', text), scope
+
+        # the optimised module: only there are calls inlined and every
+        # instruction's `op_name` the whole path from the program's root
+        hlo = lowered.compile(compiler_options={
+            "xla_backend_optimization_level": 0,     # the names, not the code
+            "xla_llvm_disable_expensive_passes": True,
+        }).as_text()
+        names = re.findall(r'op_name="([^"]*)"', hlo)
+        assert len(names) > 5000
+        rest = collections.Counter(
+            n for n in names
+            if not set(PHASE_SCOPES) & set(_scopes.segments(n))
+        )
+        share = 1.0 - sum(rest.values()) / len(names)
+        # found: 0.952. The rest are the entry's parameters (named after
+        # the arguments) and the bodies of reductions, which XLA:CPU names
+        # by their kind alone; neither is an operation of a TPU trace.
+        assert share > 0.94, (share, rest.most_common(40))
+        for phase in PHASE_SCOPES:
+            assert sum(phase in _scopes.segments(n) for n in names) > 500

@@ -286,3 +286,84 @@ class TestCheckpoint:
         )
         resumed.train(1)
         assert int(resumed.state.step) == 3
+
+
+class TestFusedLoopSpans:
+    """ISSUE 24: the fused loop names its host stretches (timers
+    ``span/learner/*``, events of a profiler trace) and counts its
+    dispatches, its log boundaries and the dispatches whose opponent was a
+    frozen snapshot."""
+
+    BOUNDARY_CHILDREN = (
+        "flush_health", "league_fetch", "gauges", "stats_drain",
+        "submit_metrics",
+    )
+
+    @pytest.fixture(scope="class")
+    def run(self):
+        """One fused ``train(4)`` over two log boundaries against league
+        opponents → (what the registry gained, the draws, train's result).
+        The registry is the process's, so everything is a delta."""
+        from dotaclient_tpu.utils import telemetry
+
+        cfg = tiny_config()
+        cfg = dataclasses.replace(
+            cfg,
+            env=dataclasses.replace(cfg.env, opponent="league"),
+            ppo=dataclasses.replace(cfg.ppo, rollout_len=4),
+            league=dataclasses.replace(
+                cfg.league, enabled=True, snapshot_every=1, pool_size=2,
+                selfplay_prob=0.0,        # a frozen draw whenever one exists
+            ),
+            log_every=2,
+        )
+        learner = Learner(cfg, actor="fused", seed=2)
+        draws = []
+        draw = learner._league_opponent
+
+        def recorded_draw():
+            params, uid = draw()
+            draws.append(uid)
+            return params, uid
+
+        learner._league_opponent = recorded_draw
+        reg = telemetry.get_registry()
+        before = reg.snapshot()
+        out = learner.train(4)
+        after = reg.snapshot()
+        gained = {k: v - before.get(k, 0.0) for k, v in after.items()}
+        return gained, draws, out
+
+    def test_dispatches_and_boundaries_are_counted(self, run):
+        gained, draws, out = run
+        assert out["optimizer_steps"] == 4.0 and len(draws) == 4
+        assert gained["learner/dispatches_total"] == 4
+        assert gained["learner/boundaries_total"] == 2       # steps 2 and 4
+        assert gained["span/learner/boundary/count"] == 2
+        for stage in ("iteration", "league_draw", "dispatch", "league_report"):
+            assert gained[f"span/learner/{stage}/count"] == 4, stage
+        for child in self.BOUNDARY_CHILDREN:
+            assert gained[f"span/learner/boundary/{child}/count"] == 2, child
+        # the snapshot thread's own fetches of the boundaries' stats
+        assert gained["span/snapshot/stats_fetch/count"] >= 2
+
+    def test_frozen_dispatches_follow_the_draw(self, run):
+        from dotaclient_tpu.league import pool as league_pool
+
+        gained, draws, _ = run
+        frozen = sum(uid != league_pool.LIVE for uid in draws)
+        assert frozen >= 1
+        assert gained["league/frozen_dispatches_total"] == frozen
+        # their outcomes were fetched, at a boundary or as the call ended
+        assert gained["league/report_fetches_total"] >= 1
+
+    def test_a_child_span_lies_inside_its_parent(self, run):
+        gained, _, _ = run
+        assert sum(
+            gained[f"span/learner/boundary/{c}/total_s"]
+            for c in self.BOUNDARY_CHILDREN
+        ) <= gained["span/learner/boundary/total_s"]
+        assert sum(
+            gained[f"span/learner/{c}/total_s"]
+            for c in ("boundary", "dispatch", "league_draw", "league_report")
+        ) <= gained["span/learner/iteration/total_s"]

@@ -420,7 +420,6 @@ class TestShardedSnapshots:
             assert snap["mesh/n_devices"] == 8.0
             assert snap["mesh/data_shards"] == 8.0
             assert snap["buffer/shard_bytes"] > 0
-            assert snap["learner/psum_ms"] >= 0
         finally:
             if learner._snap_engine is not None:
                 learner._snap_engine.stop()

@@ -5,7 +5,6 @@ import os
 
 import jax
 import jax.numpy as jnp
-import pytest
 
 from dotaclient_tpu.utils.profiling import trace
 
@@ -16,7 +15,6 @@ class TestTrace:
             x = jax.jit(lambda a: a * 2)(jnp.ones((4,)))
         assert float(x.sum()) == 8.0
 
-    @pytest.mark.slow   # tier-1 duration audit (ISSUE 6): ~59s on the reference container
     def test_writes_profile_artifacts(self, tmp_path):
         logdir = str(tmp_path / "prof")
         with trace(logdir):
@@ -29,6 +27,31 @@ class TestTrace:
         # the TensorBoard profile plugin layout: plugins/profile/<run>/...
         assert found, "trace() produced no files"
         assert any("plugins" in p and "profile" in p for p in found)
+
+    def test_trace_holds_the_programs_spans_and_no_python_frames(self, tmp_path):
+        """ISSUE 24: a `--profile-dir` trace names the host's stretches by
+        the telemetry spans; the Python tracer is off (its frames made a
+        trace of a real run too large to open, and this test a minute
+        long)."""
+        import glob
+
+        from dotaclient_tpu.utils import telemetry
+
+        logdir = str(tmp_path / "prof")
+        with trace(logdir):
+            with telemetry.Registry().span("learner/iteration", step=9):
+                jax.block_until_ready(jax.jit(lambda a: a + 1)(jnp.ones((4,))))
+        (path,) = glob.glob(
+            os.path.join(logdir, "plugins", "profile", "*", "*.xplane.pb")
+        )
+        data = jax.profiler.ProfileData.from_file(path)
+        (host,) = [p for p in data.planes if p.name == "/host:CPU"]
+        names = [ev.name for line in host.lines for ev in line.events]
+        assert names.count("learner/iteration") == 1
+        # a Python frame's event is named "<file>:<line> <function>"
+        assert not any(".py:" in n for n in names), [
+            n for n in names if ".py:" in n
+        ][:5]
 
     def test_trace_closes_on_exception(self, tmp_path):
         logdir = str(tmp_path / "prof2")

@@ -119,6 +119,86 @@ class TestRegistry:
         assert "span/after/count" in snap          # not nested under "boom"
         assert "span/boom/after/count" not in snap
 
+    def test_span_is_a_profiler_event_under_a_session(self, tmp_path):
+        """With a profiler session on, every span is an event of the host
+        plane under its FULL name, with the attributes it was given, inside
+        its parent's interval on the same thread; the timer's key never
+        holds the attributes."""
+        import glob
+        import threading
+
+        r = telemetry.Registry()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with r.span("learner/boundary", step=7):
+                with r.span("gauges"):
+                    time.sleep(0.002)
+            def fetch():
+                with r.span("snapshot/stats_fetch"):
+                    pass
+
+            other = threading.Thread(target=fetch)
+            other.start()
+            other.join()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(
+            str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")
+        )
+        data = jax.profiler.ProfileData.from_file(path)
+        (host,) = [p for p in data.planes if p.name == "/host:CPU"]
+        found = {}
+        for line_no, line in enumerate(host.lines):
+            for ev in line.events:
+                if ev.name.startswith(("learner/", "snapshot/")):
+                    found[ev.name] = (
+                        line_no, ev.start_ns, ev.start_ns + ev.duration_ns,
+                        dict(ev.stats),
+                    )
+        assert set(found) == {
+            "learner/boundary", "learner/boundary/gauges",
+            "snapshot/stats_fetch",
+        }
+        p_line, p0, p1, p_stats = found["learner/boundary"]
+        c_line, c0, c1, c_stats = found["learner/boundary/gauges"]
+        assert p_stats == {"step": 7} and c_stats == {}
+        assert c_line == p_line and p0 <= c0 < c1 <= p1
+        assert c1 - c0 >= 2e6                       # the sleep, in ns
+        assert found["snapshot/stats_fetch"][0] != p_line   # its own thread
+        snap = r.snapshot()
+        assert snap["span/learner/boundary/count"] == 1
+        assert snap["span/learner/boundary/gauges/count"] == 1
+        assert not any("step" in k for k in snap)
+
+    def test_span_with_attributes_records_its_timer_with_no_session(self):
+        r = telemetry.Registry()
+        with r.span("learner/iteration", step=3):
+            with r.span("learner/dispatch"):
+                time.sleep(0.002)
+        snap = r.snapshot()
+        assert snap["span/learner/iteration/count"] == 1
+        assert snap["span/learner/dispatch/total_s"] >= 0.002
+        assert snap["span/learner/iteration/total_s"] >= snap["span/learner/dispatch/total_s"]
+
+    def test_importing_telemetry_imports_no_jax(self):
+        """jax-free tools load the module by path, keep the timers and get
+        no annotation: a span must never be what imports JAX."""
+        import subprocess
+
+        code = (
+            "import importlib.util, sys\n"
+            f"spec = importlib.util.spec_from_file_location('t', {telemetry.__file__!r})\n"
+            "t = importlib.util.module_from_spec(spec); spec.loader.exec_module(t)\n"
+            "r = t.Registry()\n"
+            "with r.span('a/b', step=1): pass\n"
+            "assert r.snapshot()['span/a/b/count'] == 1\n"
+            "assert t._trace_annotation() is None\n"
+            "assert not any(m == 'jax' or m.startswith('jax.') for m in sys.modules)\n"
+        )
+        subprocess.run([sys.executable, "-c", code], check=True, timeout=60)
+
     def test_clear(self):
         r = telemetry.Registry()
         r.counter("c").inc()
