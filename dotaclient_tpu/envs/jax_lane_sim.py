@@ -113,6 +113,39 @@ def _armor_mult(armor: jnp.ndarray) -> jnp.ndarray:
     return 1.0 - (0.06 * armor) / (1.0 + 0.06 * armor)
 
 
+def _slot_mask(idx: jnp.ndarray, n_slots: int) -> jnp.ndarray:
+    """bool [..., n_slots]: True at the one slot each index of ``idx`` names."""
+    return idx[..., None] == jnp.arange(n_slots, dtype=idx.dtype)
+
+
+def _at_slot(x: jnp.ndarray, mask: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """The value of ``x`` at the one position ``mask`` marks along ``axis``:
+    ``x[n, idx[n, k]]`` as compare-select-reduce, never an XLA ``gather``.
+
+    ``x`` broadcasts against ``mask``: per-unit values ``[N, S]`` go in as
+    ``x[:, None, :]`` against a ``_slot_mask`` of ``[N, K, S]``, a row per
+    looker (``dist[:, :P, :]``) as it is. A data-dependent gather runs close
+    to one element at a time on the TPU (10 ns a value: 1.4 ms for one
+    ``[4096, 32]`` lookup, PERF.md section 6, PR 25) and, because it names
+    the game axis, is not shard-local to the partitioner; the select fuses
+    into the elementwise work around it and is local to a game by
+    construction.
+
+    Exact for every dtype, ``-0.0``, ``inf`` and ``nan`` included: floats
+    are reduced as their bit patterns (an integer sum with one non-zero
+    term), and the select comes before the reduce, so nothing in a slot
+    that is not selected reaches the result. Deliberately NOT a one-hot
+    ``einsum``: at default precision the MXU rounds float32 inputs through
+    bfloat16, which would move a looked-up health of 550.0 and with it a
+    kill threshold.
+    """
+    if x.dtype == jnp.bool_:
+        return (mask & x).any(axis=axis)
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)   # f32 or i32 alike
+    picked = jnp.where(mask, bits, 0).sum(axis=axis)
+    return jax.lax.bitcast_convert_type(picked, x.dtype)
+
+
 def _hero_stats_table() -> np.ndarray:
     """Dense hero_id → stats lookup (row 0.. = generic fallback)."""
     n = max(HERO_STATS) + 1
@@ -155,8 +188,11 @@ def init_state(
     pslots = jnp.arange(P)
     team_row = jnp.where(pslots < spec.team_size, TEAM_RADIANT, TEAM_DIRE)
     side = jnp.where(team_row == TEAM_RADIANT, -1.0, 1.0)
-    table = jnp.asarray(_hero_stats_table())
-    stats = table[jnp.clip(state.hero_ids, 0, table.shape[0] - 1)]  # [N, P, 6]
+    table = jnp.asarray(_hero_stats_table())                    # [rows, 6]
+    row = _slot_mask(
+        jnp.clip(state.hero_ids, 0, table.shape[0] - 1), table.shape[0]
+    )                                                           # [N, P, rows]
+    stats = _at_slot(table.T, row[:, :, None, :])               # [N, P, 6]
 
     def set_cols(arr, vals):
         return arr.at[:, :P].set(vals)
@@ -286,7 +322,7 @@ def step(
     cannot prune the scripted-bot subgraph on its own — callers that know no
     player is scripted (self-play, league) pass False and skip it entirely.
     """
-    N, S, P = spec.n_games, spec.max_units, spec.n_players
+    S, P = spec.max_units, spec.n_players
     live = ~state.done
     dt = spec.ticks_per_obs / TICKS_PER_SECOND
     dist = _pairwise_dist(state)
@@ -307,7 +343,6 @@ def step(
         ability = jnp.where(scripted, sa["ability"], ability)
 
     hero_alive = state.alive[:, :P] & live[:, None]
-    n_idx = jnp.arange(N)[:, None]
 
     # 1. movement
     half = (spec.move_bins - 1) / 2.0
@@ -325,12 +360,13 @@ def step(
     )
 
     # 2. hero attacks / casts (phase A)
-    tgt_dist = dist[n_idx, jnp.arange(P)[None, :], target]
-    t_alive = state.alive[n_idx, target]
-    t_team = state.team[n_idx, target]
-    t_type = state.unit_type[n_idx, target]
-    t_hp = state.health[n_idx, target]
-    t_hpmax = state.health_max[n_idx, target]
+    at_target = _slot_mask(target, S)                           # [N, P, S]
+    tgt_dist = _at_slot(dist[:, :P, :], at_target)
+    t_alive = _at_slot(state.alive[:, None, :], at_target)
+    t_team = _at_slot(state.team[:, None, :], at_target)
+    t_type = _at_slot(state.unit_type[:, None, :], at_target)
+    t_hp = _at_slot(state.health[:, None, :], at_target)
+    t_hpmax = _at_slot(state.health_max[:, None, :], at_target)
     my_team = state.team[:, :P]
 
     is_deny = (t_team == my_team) & (t_type == pb.UNIT_LANE_CREEP) & (
@@ -371,7 +407,7 @@ def step(
         0.0,
     )
     hit = attack_ok | cast_ok
-    t_mult = _armor_mult(state.armor[n_idx, target])
+    t_mult = _armor_mult(_at_slot(state.armor[:, None, :], at_target))
     # one-hot matmul, NOT scatter-add: XLA scatter combines duplicate
     # indices in unspecified order (f32 non-associativity then flips kill
     # thresholds run-to-run); a reduction has a fixed order and maps to the
@@ -401,7 +437,6 @@ def _resolve_deaths(
     hero_deny=None,
 ) -> SimState:
     N, S, P = spec.n_games, spec.max_units, spec.n_players
-    n_idx = jnp.arange(N)[:, None]
     pre_alive = state.alive
     health = jnp.where(pre_alive, state.health - dmg, state.health)
     died = pre_alive & (health <= 0.0)
@@ -424,7 +459,11 @@ def _resolve_deaths(
         )                                                       # [N, P, S]
         by_hero = died & credit.any(axis=1)                     # [N, S]
         first_p = jnp.argmax(credit, axis=1)                    # [N, S]
-        deny_credit = jnp.take_along_axis(hero_deny, first_p, axis=1)  # [N, S]
+        deny_credit = _at_slot(
+            hero_deny[:, :, None],
+            first_p[:, None, :] == jnp.arange(P)[None, :, None],
+            axis=1,
+        )                                                       # [N, S]
 
         cred_creep = by_hero & is_creep
         denied_creep = cred_creep & deny_credit
@@ -517,7 +556,7 @@ def _grant_xp(spec: VecSimSpec, state: SimState, xp_gain: jnp.ndarray) -> SimSta
 def _step_ai(
     spec: VecSimSpec, state: SimState, dist: jnp.ndarray, dt: float, live: jnp.ndarray
 ) -> SimState:
-    N, S = spec.n_games, spec.max_units
+    S = spec.max_units
     alive = state.alive & live[:, None]
     enemy = (
         alive[:, :, None]
@@ -530,7 +569,7 @@ def _step_ai(
     is_tower = (state.unit_type == pb.UNIT_TOWER) & alive
 
     nearest = d_masked.argmin(axis=2)
-    nearest_d = jnp.take_along_axis(d_masked, nearest[:, :, None], 2)[:, :, 0]
+    nearest_d = d_masked.min(axis=2)    # the value at the argmin IS the min
     can_attack = is_creep & (nearest_d <= state.attack_range + 20.0)
     attacking = can_attack & (state.attack_cd <= 0.0)
 
@@ -551,8 +590,7 @@ def _step_ai(
     state = state._replace(
         attack_cd=jnp.where(atk, 1.0 / ATTACKS_PER_SECOND, state.attack_cd)
     )
-    n_idx = jnp.arange(N)[:, None]
-    t_mult = _armor_mult(state.armor[n_idx, tgt])
+    t_mult = _armor_mult(_at_slot(state.armor[:, None, :], _slot_mask(tgt, S)))
     # deterministic one-hot reduction (see phase-A dmg comment)
     onehot_t = jax.nn.one_hot(tgt, S, dtype=jnp.float32)        # [N, S, S]
     dmg = jnp.einsum(
@@ -772,9 +810,9 @@ def _scripted_actions(
     # march toward nearest enemy (or mid)
     nearest_any = d_enemy.argmin(axis=2)
     has_enemy = d_enemy.min(axis=2) < _BIG
-    n_idx = jnp.arange(N)[:, None]
-    gx = jnp.where(has_enemy, state.x[n_idx, nearest_any], 0.0)
-    gy = jnp.where(has_enemy, state.y[n_idx, nearest_any], 0.0)
+    at_nearest = _slot_mask(nearest_any, S)
+    gx = jnp.where(has_enemy, _at_slot(state.x[:, None, :], at_nearest), 0.0)
+    gy = jnp.where(has_enemy, _at_slot(state.y[:, None, :], at_nearest), 0.0)
     out_type, out_mx, out_my = move_toward(todo, gx, gy, (out_type, out_mx, out_my))
 
     return {

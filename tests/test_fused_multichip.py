@@ -9,9 +9,13 @@ The tentpole's contract, pinned from four sides:
 * the lane-sharded rollout is BITWISE the 1-device rollout in-process
   (per-game keys partition random-bit generation with the games; stat
   partials reduce only the step axis — the rollout has no collective to
-  reassociate; the cross-process ``--fused-parity`` digest allows 1e-7
-  relative for backend tiling differences) and fused losses track
-  within Adam-amplified reassociation tolerance;
+  reassociate, which ``test_rollout_phase_compiles_to_no_collective`` pins
+  on the compiled program since PR 25: until then the simulation's
+  gathers over the game axis cost six all-gathers and an all-reduce in
+  every rollout step on the v5e, results unchanged; the cross-process
+  ``--fused-parity`` digest allows 1e-7 relative for backend tiling
+  differences) and fused losses track within Adam-amplified
+  reassociation tolerance;
 * the shard-local minibatch permutation (``lane_minibatches``) is
   deterministic in (seed, step), partitions the lane set exactly, and
   never moves a lane across shards;
@@ -101,6 +105,50 @@ class TestLaneShardedCompile:
         # the bulk of the state must be partitioned, not a token leaf
         assert len(sharded) >= len(jax.tree.leaves(actor_sh)) // 2
 
+    def test_rollout_phase_compiles_to_no_collective(self):
+        """No operation written under ``phase_rollout`` may compile to a
+        collective: every lane's rollout is local to its shard. Until PR 25
+        this did not hold. ``envs/jax_lane_sim.py`` looked units up with
+        ``state.x[arange(N)[:, None], idx]``, a gather that names the
+        sharded game axis; the partitioner cannot prove that index local,
+        so it all-gathered the indices and all-reduced the results inside
+        the rollout's loop body (v5e 2x2: six all-gathers and one all-reduce
+        a simulation step, 2.35% of device time in flight; forced host
+        devices: eight and three, all named ``rollout_sim_step/gather``).
+        The lookups are compare-select-reduce along the unit axis now and
+        name no game axis. The only collectives left belong to the update
+        (gradient and loss reductions)."""
+        import re
+
+        from dotaclient_tpu.parallel import make_mesh
+        from dotaclient_tpu.train.fused import make_fused_step
+
+        # self-play, as the benchmark's cells compile the simulation
+        # (scripted_possible=False); a narrow model: only the layout counts
+        cfg = tiny_cfg(opponent="selfplay", small_model=True)
+        mesh = make_mesh(cfg.mesh)   # conftest's 8 forced host devices
+        policy, actor, state = _build(cfg, mesh)
+        assert actor.lane_shards == 8
+        hlo = make_fused_step(policy, cfg, mesh, actor).lower(
+            state, actor.state, state.params
+        ).compile().as_text()
+        collectives = [
+            (kind, op_name)
+            for kind, op_name in re.findall(
+                r"= \S+ (all-gather|all-reduce|all-to-all|reduce-scatter|"
+                r"collective-permute|collective-broadcast)(?:-start)?\("
+                r".*?op_name=\"([^\"]*)\"",
+                hlo,
+            )
+        ]
+        # the program IS partitioned and its scopes reach the HLO: the
+        # update's reductions are there, the rollout's operations are named
+        assert any("phase_update" in name for _, name in collectives)
+        assert "phase_rollout/while/body" in hlo
+        in_rollout = [c for c in collectives if "phase_rollout" in c[1]]
+        assert not in_rollout, in_rollout
+        assert not [c for c in collectives if c[0] == "all-gather"], collectives
+
     def test_degenerate_games_fall_back_to_replicated(self):
         """4 games on an 8-way mesh cannot lane-shard: the layout must
         degrade to replicated (lane_shards == 1) instead of failing."""
@@ -119,7 +167,8 @@ class TestShardCountParity:
     @pytest.mark.slow   # two mesh sizes × (rollout + fused) compiles, ~1 min
     def test_rollout_bitwise_and_losses_close_8_vs_1(self):
         """Same seeds, 8-way lane-sharded vs 1-device: the rollout chunk
-        must be BYTE-IDENTICAL (no collective in the rollout), and fused
+        must be BYTE-IDENTICAL (no collective in the rollout:
+        ``test_rollout_phase_compiles_to_no_collective``), and fused
         losses over 3 dispatches must agree within the Adam-amplified
         reassociation tolerance (the gradient psum reorders sums;
         ``1/(sqrt(v)+eps)`` amplifies ~1e-7 deltas on near-zero-gradient
