@@ -28,6 +28,7 @@ import numpy as np
 
 from dotaclient_tpu.config import RunConfig
 from dotaclient_tpu.envs import jax_lane_sim as sim_mod
+from dotaclient_tpu.envs.lane_sim import TICKS_PER_SECOND
 from dotaclient_tpu.envs.vec_lane_sim import VecSimSpec, draft_games
 from dotaclient_tpu.features.jax_featurizer import (
     JaxFeaturizer,
@@ -35,7 +36,7 @@ from dotaclient_tpu.features.jax_featurizer import (
 )
 from dotaclient_tpu.features.reward import fold_terms
 from dotaclient_tpu.models import distributions as D
-from dotaclient_tpu.models.policy import Policy, mask_carry
+from dotaclient_tpu.models.policy import Policy, require_episode_fits
 from dotaclient_tpu.outcome import ingraph as outcome_ingraph
 from dotaclient_tpu.outcome import records as outcome_records
 from dotaclient_tpu.protos import dota_pb2 as pb
@@ -46,8 +47,8 @@ class DeviceActorState(NamedTuple):
     """Everything the rollout loop carries across chunks, device-resident."""
 
     sim: sim_mod.SimState
-    carry: Tuple[jnp.ndarray, jnp.ndarray]       # learner lanes' LSTM state
-    opp_carry: Tuple[jnp.ndarray, jnp.ndarray]   # opponent lanes' (or dummy)
+    carry: Any       # learner lanes' recurrent carry (the core's pytree)
+    opp_carry: Any   # opponent lanes' (or one dummy lane's)
     # f32/u32 [N, 2] per-GAME PRNG keys: each game's lanes sample from that
     # game's key, so action sampling is shard-local when the game axis is
     # partitioned over the mesh (and bitwise independent of the shard count)
@@ -99,6 +100,23 @@ def actor_state_sharding(state: DeviceActorState, mesh, mesh_config):
         ep_steps=rows(state.ep_steps),
         stats=jax.tree.map(rows, state.stats),
     )
+
+
+def own_buffers(tree: Any) -> Any:
+    """``tree`` with every leaf a buffer of its own: a leaf that shares its
+    device buffer with an earlier one (``sim.init_state`` builds many fields
+    from one zeros array) is copied. A program that donates the actor state
+    would otherwise donate that buffer twice."""
+    seen: set = set()
+
+    def own(x):
+        buffers = {s.data.unsafe_buffer_pointer() for s in x.addressable_shards}
+        if seen & buffers:
+            return jnp.copy(x)
+        seen.update(buffers)
+        return x
+
+    return jax.tree.map(own, tree)
 
 
 def reduce_device_stats(stats: Dict[str, Any]) -> Dict[str, Any]:
@@ -199,6 +217,15 @@ class DeviceActor:
             else None
         )
         self.n_lanes = self.feat.n_lanes
+        # an episode ends at the first observation at or past max_dota_time
+        require_episode_fits(
+            config.model,
+            int(np.ceil(
+                config.env.max_dota_time * TICKS_PER_SECOND
+                / config.env.ticks_per_observation
+            )) + 1,
+            config.ppo.rollout_len,
+        )
 
         N, P = self.spec.n_games, self.spec.n_players
         hero_ids, control = draft_games(
@@ -222,6 +249,7 @@ class DeviceActor:
         key, k_init = jax.random.split(key)
         sim0 = sim_mod.init_state(self.spec, hero_ids, control, k_init)
         opp_lanes = max(len(opponent_players) * N, 1)
+        self._own = lambda tree: tree     # until donate_state()
         self.state = DeviceActorState(
             sim=sim0,
             carry=policy.initial_state(self.n_lanes),
@@ -310,9 +338,9 @@ class DeviceActor:
                 )
             return new_state, chunk, stats
 
-        # No donation: the state is small (the big arrays are the chunk
-        # OUTPUTS), and zero-initialized carries can alias the same cached
-        # constant buffer, which donation would flag as a double-donate.
+        # No donation here: the buffered mode's state is small (the big
+        # arrays are the chunk OUTPUTS), and zero-initialized leaves share
+        # buffers. A program that does donate it says so (donate_state).
         self._rollout = jax.jit(_collect_impl)
         # host-side counters, updated from fetched stats at log boundaries
         self.env_steps = 0
@@ -323,6 +351,14 @@ class DeviceActor:
         self._ep_count_window = 0.0
         self._tel = registry if registry is not None else telemetry.get_registry()
         outcome_records.ensure_actor_metrics(self._tel)
+
+    def donate_state(self) -> None:
+        """Whoever builds a program that DONATES ``self.state`` says so here
+        (``train/fused.py``, where the states are most of the chip): from
+        now on every leaf of the state, and of the fresh stat accumulators a
+        drain puts in, is a buffer of its own."""
+        self._own = own_buffers
+        self.state = own_buffers(self.state)
 
     def reset_recurrent(self) -> None:
         """Zero every lane's recurrent carry (learner + opponent sides).
@@ -384,10 +420,6 @@ class DeviceActor:
             sim_mod.TEAM_RADIANT
             if self.learner_players[0] < spec.team_size
             else sim_mod.TEAM_DIRE
-        )
-
-        carry0 = jax.tree.map(
-            lambda t: t.astype(jnp.float32), state.carry
         )
 
         def body(c, _):
@@ -466,10 +498,14 @@ class DeviceActor:
 
                 sim3 = sim_mod.reset_where(spec, sim2, done_g)
                 done_lane = jnp.repeat(done_g, A)
-                lstm3 = mask_carry(lstm2, 1.0 - done_lane.astype(jnp.float32))
+                # the reset is the core's own (Policy.reset_carry): the
+                # LSTM's row is zeroed, a cache is never rewritten
+                lstm3 = self.policy.reset_carry(
+                    lstm2, 1.0 - done_lane.astype(jnp.float32)
+                )
                 if self._opp_feat is not None:
                     opp_done = jnp.repeat(done_g, len(self.opponent_players))
-                    opp_lstm3 = mask_carry(
+                    opp_lstm3 = self.policy.reset_carry(
                         opp_lstm2, 1.0 - opp_done.astype(jnp.float32)
                     )
                 else:
@@ -526,7 +562,10 @@ class DeviceActor:
                 "rewards": jnp.moveaxis(outs["reward"], 0, 1),
                 "dones": jnp.moveaxis(outs["done_lane"], 0, 1),
                 "valid": jnp.ones((self.n_lanes, T), jnp.float32),
-                "carry0": carry0,
+                # the chunk-start carry as the core hands it to a learner:
+                # float32 rows for the LSTM, and for a core with caches the
+                # start's counters beside the rings as the chunk left them
+                "carry0": self.policy.chunk_start_carry(state.carry, lstm_f),
             }
             lg = self._league_game_mask[None, :]     # [1, N] non-anchor games
             # Stats are PER-GAME/PER-LANE partials (ISSUE 18): only the step
@@ -609,7 +648,7 @@ class DeviceActor:
                 lambda t: jax.tree.map(jnp.copy, t)
             )
         dev = self._stats_copy(self.state.stats)
-        fresh = self._zero_stats()
+        fresh = self._own(self._zero_stats())
         if self.mesh is not None:
             # commit the zeroed accumulators back to the lane sharding —
             # uncommitted host zeros would change the collect program's

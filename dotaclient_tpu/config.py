@@ -52,7 +52,7 @@ class ModelConfig:
     hidden_dim: int = 128        # LSTM hidden size — parity with reference
     n_hero_ids: int = 32         # hero-embedding vocabulary (multi-hero pools)
     hero_embed_dim: int = 16
-    core: str = "lstm"           # "lstm" | "transformer"
+    core: str = "lstm"           # "lstm" | "transformer" | "afmoe"
     # Transformer-core options (scale-out path, SURVEY.md §7 step 8).
     n_layers: int = 2
     n_heads: int = 4
@@ -62,6 +62,29 @@ class ModelConfig:
     moe_capacity_factor: float = 2.0
     dtype: str = "bfloat16"      # compute dtype; params stay float32
     param_dtype: str = "float32"
+    # "afmoe" core (models/afmoe.py): window and full grouped-query
+    # attention over per-lane ring caches, sigmoid top-k routing with a
+    # shared expert. It reads hidden_dim (stream width), n_layers, n_heads,
+    # context_window (the sliding window) and moe_experts (the router's
+    # width) from the fields above, and these:
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    global_attn_every: int = 4   # expert layer l attends fully iff (l+1+offset) % this == 0
+    global_attn_offset: int = 0  # published index of layer l minus l (a cut that keeps a later period)
+    full_context: int = 3072     # ring of a full layer: >= episode steps + rollout_chunk
+    rollout_chunk: int = 16      # a window ring holds context_window + this many steps
+    n_dense_layers: int = 1      # leading layers with a dense FFN
+    dense_ffn_dim: int = 6144
+    expert_ffn_dim: int = 1024
+    experts_per_token: int = 8
+    n_shared_experts: int = 1
+    held_experts: int = 0        # routed experts this chip holds (0 = all)
+    expert_offset: int = 0       # index of the first held expert
+    route_norm: bool = True
+    route_scale: float = 2.826
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    mup_enabled: bool = True     # stream input scaled by sqrt(hidden_dim)
 
 
 # Valid PPOConfig.adv_norm values — the single source of truth for the
@@ -91,6 +114,9 @@ class PPOConfig:
     minibatches: int = 1         # shuffled minibatch splits per epoch
     max_staleness: int = 4       # drop rollouts older than this many BATCHES
     moe_aux_coef: float = 0.01   # Switch load-balancing loss weight (MoE core)
+    # afmoe core: step of the selection bias's balancing update per optimizer
+    # step (train/ppo._balance_select_bias); 0 leaves the bias where it is
+    select_bias_rate: float = 0.001
     # Advantage normalization. "batch" (the standard per-batch whitening) is
     # right for training from scratch, but it amplifies GAE noise to unit
     # scale when the true advantage signal is ~zero — measured to destroy a

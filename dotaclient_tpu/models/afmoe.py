@@ -1,0 +1,482 @@
+"""The "afmoe" block (Arcee Trinity's architecture) as a recurrent policy core.
+
+``ModelConfig.core = "afmoe"``. A stack of pre-norm blocks on a stream of
+width ``hidden_dim``: grouped-query attention of two kinds in one stack
+(sliding-window layers with RoPE, every ``global_attn_every``-th layer full
+attention with no positional encoding, never a leading dense layer;
+``global_attn_offset`` shifts the count where a cut keeps a later period of
+the published stack), then a SwiGLU feed-forward that is
+dense in the leading ``n_dense_layers`` and a routed mixture with a shared
+expert in the rest. One layer, on the stream ``h``, the query at position
+``p`` counted from its episode's start:
+
+  a = RMSNorm(h); q = RMSNorm_head(a Wq); k = RMSNorm_head(a Wk); v = a Wv
+  window layers: q, k rotated by RoPE(theta) at p
+  scores = q . k / sqrt(head_dim), ``n_heads / n_kv_heads`` query heads a KV
+  head, over the keys of the SAME episode with p_k <= p_q, and in a window
+  layer p_k > p_q - context_window; float32 softmax
+  attn = ((softmax v) * sigmoid(a Wgate)) Wo;   h = h + RMSNorm(attn)
+  m = RMSNorm(h)
+  dense:   f = (silu(m Wg) * (m Wu)) Wd
+  experts: s = sigmoid(m Wr) over ``moe_experts``; the ``experts_per_token``
+           largest of s + select_bias (the bias has no gradient: its
+           balancing update follows the load, see below);
+           w = route_scale * s_top / sum(s_top);
+           f = SwiGLU_shared(m) + sum over the chosen e of w_e SwiGLU_e(m)
+  h = h + RMSNorm(f);   after the last layer y = RMSNorm(h)
+
+The stream enters scaled by sqrt(hidden_dim) (``mup_enabled``).
+
+**One function for a step and for a chunk.** ``AfmoeCore(carry, x, resets)``
+takes ``x [B, T, H]``: the learner's pass is ONE pass over the chunk (T
+queries against the carried keys and the chunk's own), and the actor's step
+is the same function at T = 1, so step/sequence parity is structural.
+``resets [B, T]`` (1 where an episode starts AT step t) cut attention by
+episode inside the chunk and restart ``p``.
+
+**The carry** is ``{"pos": [B] i32, "cursor": [B] i32, "kv": per layer (K, V)
+[B, R, n_kv_heads * head_dim]}`` in the compute type (one position is one
+contiguous row: on the v5e a step's read of that layout runs at 646 GB/s and
+its scatter costs 4% more, where ``[B, n_kv_heads, R, head_dim]`` reads at
+743 GB/s and pays 47% for the scatter: my chip run, PR 26). ``pos`` is the position
+of the lane's next query in its episode, which is also how many of the
+ring's newest keys belong to that episode. ``cursor`` is the slot the next
+key is written to (mod R, kept mod the rings' common multiple); it never
+resets, so a chunk's T keys always land in T consecutive slots. The slot
+``s`` holds the key written ``age = (cursor - 1 - s) mod R`` steps before
+the newest, and is visible iff ``age < pos`` (same episode) and, in a window
+layer, inside the window: the mask is arithmetic on the two counters, and
+``reset`` only zeroes ``pos``: no cache leaf is touched. A write is a
+scatter of T rows at ``cursor``, never a copy of the cache.
+
+R is ``context_window + rollout_chunk`` for a window layer and
+``full_context`` for a full layer. The slack is what lets the learner be
+handed a chunk's START without a copy: the T keys the rollout wrote lie
+further back than a window layer's first query can see, and in a full layer
+further back than the episode is long (``require_episode_fits``), so
+``chunk_start_view`` is the START's two counters beside the END's rings.
+
+**Experts held here.** ``held_experts`` and ``expert_offset`` say which of
+the ``moe_experts`` routed experts this chip holds (guide: one chip's share
+of a layer divided over several). The router keeps its whole width and its
+experts per token; the layer adds the terms of the experts it holds and the
+shared expert. Every token-expert pair is sorted by held expert (pairs of
+absent experts last) and multiplied in groups by ``jax.lax.ragged_dot``; the
+buffer has a row for every pair, so none can be dropped, and the layer
+counts that from the buffer (``moe_dropped``). A step therefore costs what
+the router chose: a grouped product takes as long as the held experts it
+touches (1.0 / 4.7 / 8.4 / 15.5 microseconds a decode product for 0 / 1 / 2 /
+3 experts, 4.2 MB of weights each: my chip runs, PR 26), and early in
+training lanes that watch like game states choose like experts, so a chip's
+share of the pairs is a draw of the weights (PERF.md section 6). With
+``held_experts`` 0 or ``moe_experts`` the layer is the whole layer.
+
+**The selection bias** is the one parameter no gradient reaches. The layer
+sows each expert's tokens minus the mean (``select_bias_err``), and the
+optimizer step moves the bias against it (``train/ppo._balance_select_bias``,
+``ppo.select_bias_rate``): the published balancing rule.
+
+Scopes inside ``policy_core``: ``core_attn_window``, ``core_attn_full``,
+``core_cache_write``, ``core_router``, ``core_experts_routed``,
+``core_expert_shared``, ``core_dense_ffn``.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from dotaclient_tpu.config import ModelConfig
+
+_NEG = -1e30
+
+
+def _dtype(name: str):
+    return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[name]
+
+
+# -- the static plan of a configuration --------------------------------------
+
+
+def layer_is_full(cfg: ModelConfig, layer: int) -> bool:
+    return (
+        layer >= cfg.n_dense_layers
+        and (layer + 1 + cfg.global_attn_offset) % cfg.global_attn_every == 0
+    )
+
+
+def layer_is_dense(cfg: ModelConfig, layer: int) -> bool:
+    return layer < cfg.n_dense_layers
+
+
+def ring_len(cfg: ModelConfig, layer: int) -> int:
+    if layer_is_full(cfg, layer):
+        return cfg.full_context
+    return cfg.context_window + cfg.rollout_chunk
+
+
+def held_experts(cfg: ModelConfig) -> Tuple[int, int]:
+    """(how many routed experts live here, index of the first)."""
+    return (cfg.held_experts or cfg.moe_experts), cfg.expert_offset
+
+
+def _cursor_modulus(cfg: ModelConfig) -> int:
+    return math.lcm(*(ring_len(cfg, l) for l in range(cfg.n_layers)))
+
+
+def carry_bytes_per_lane(cfg: ModelConfig) -> int:
+    width = 2 * cfg.n_kv_heads * cfg.head_dim * _dtype(cfg.dtype).dtype.itemsize
+    return 8 + sum(ring_len(cfg, l) * width for l in range(cfg.n_layers))
+
+
+def check_config(cfg: ModelConfig) -> None:
+    held, offset = held_experts(cfg)
+    if cfg.n_heads % cfg.n_kv_heads:
+        raise ValueError(f"n_heads {cfg.n_heads} not a multiple of n_kv_heads {cfg.n_kv_heads}")
+    if cfg.head_dim % 2:
+        raise ValueError(f"head_dim {cfg.head_dim} must be even for RoPE")
+    if cfg.n_dense_layers < cfg.n_layers and not (
+        0 < cfg.experts_per_token <= cfg.moe_experts
+        and 0 <= offset and offset + held <= cfg.moe_experts
+    ):
+        raise ValueError(
+            f"afmoe routing: {cfg.experts_per_token} of {cfg.moe_experts} experts a token, "
+            f"experts {offset}..{offset + held - 1} held"
+        )
+
+
+def require_episode_fits(cfg: ModelConfig, episode_steps: int, rollout_len: int) -> None:
+    """A full layer sees its whole episode only while the episode, and the
+    chunk a learner is handed the start of, fit its ring."""
+    if rollout_len > cfg.rollout_chunk:
+        raise ValueError(
+            f"core 'afmoe': ppo.rollout_len {rollout_len} exceeds model.rollout_chunk "
+            f"{cfg.rollout_chunk}, the slack its window rings keep for one chunk"
+        )
+    if episode_steps + cfg.rollout_chunk > cfg.full_context:
+        raise ValueError(
+            f"core 'afmoe': an episode of {episode_steps} steps and a chunk of "
+            f"{cfg.rollout_chunk} do not fit model.full_context {cfg.full_context}"
+        )
+
+
+# -- the carry ------------------------------------------------------------------
+
+
+def initial_state(cfg: ModelConfig, batch_size: int) -> Dict[str, Any]:
+    dtype = _dtype(cfg.dtype)
+
+    def ring(layer: int):
+        return jnp.zeros((batch_size, ring_len(cfg, layer), cfg.n_kv_heads * cfg.head_dim), dtype)
+
+    return {
+        "pos": jnp.zeros((batch_size,), jnp.int32),
+        "cursor": jnp.zeros((batch_size,), jnp.int32),
+        "kv": tuple((ring(l), ring(l)) for l in range(cfg.n_layers)),
+    }
+
+
+def reset(carry: Dict[str, Any], keep: jnp.ndarray) -> Dict[str, Any]:
+    """Start a new episode in the rows where ``keep`` is 0: the position
+    returns to 0 and the mask hides what the ring still holds."""
+    return {**carry, "pos": jnp.where(keep > 0, carry["pos"], 0)}
+
+
+def chunk_start_view(start: Dict[str, Any], end: Dict[str, Any]) -> Dict[str, Any]:
+    """The carry as it stood when a chunk of at most ``rollout_chunk`` steps
+    began, read from the rings as the chunk left them."""
+    return {"pos": start["pos"], "cursor": start["cursor"], "kv": end["kv"]}
+
+
+def chunk_positions(pos0: jnp.ndarray, resets: Optional[jnp.ndarray], T: int):
+    """(episode segment [B, T], position in the episode [B, T]) of a chunk's
+    steps. Segment 0 continues the carry's episode."""
+    idx = jnp.arange(T, dtype=jnp.int32)[None, :]
+    if resets is None:
+        return jnp.zeros((pos0.shape[0], T), jnp.int32), pos0[:, None] + idx
+    starts = resets > 0
+    seg = jnp.cumsum(starts.astype(jnp.int32), axis=1)
+    first = jax.lax.cummax(jnp.where(starts, idx, 0), axis=1)
+    return seg, jnp.where(seg == 0, pos0[:, None] + idx, idx - first)
+
+
+# -- pieces ---------------------------------------------------------------------
+
+
+class RMSNorm(nn.Module):
+    config: ModelConfig
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        cfg = self.config
+        scale = self.param(
+            "scale", nn.initializers.ones, (x.shape[-1],), _dtype(cfg.param_dtype)
+        )
+        x = x.astype(jnp.float32)
+        x = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + cfg.rms_norm_eps)
+        return x * scale.astype(jnp.float32)
+
+
+def _dense(cfg: ModelConfig, features: int, name: str) -> nn.Dense:
+    return nn.Dense(
+        features, use_bias=False, dtype=_dtype(cfg.dtype),
+        param_dtype=_dtype(cfg.param_dtype), name=name,
+    )
+
+
+def rope(x: jnp.ndarray, p: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotate ``x [B, T, ..., D]`` (float32) by position ``p [B, T]``: pairs
+    (i, i + D/2) turn by ``p * theta ** (-2i / D)``."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = p.astype(jnp.float32)[..., None] * freq                # [B, T, D/2]
+    angle = angle.reshape(angle.shape[:2] + (1,) * (x.ndim - 3) + (half,))
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+class SwiGLU(nn.Module):
+    config: ModelConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, m: jnp.ndarray) -> jnp.ndarray:
+        cfg = self.config
+        g = _dense(cfg, self.width, "gate_proj")(m)
+        u = _dense(cfg, self.width, "up_proj")(m)
+        return _dense(cfg, cfg.hidden_dim, "down_proj")(nn.silu(g) * u)
+
+
+@jax.checkpoint
+def _attend(q, k, v, ring_k, ring_v, see_ring, see_chunk):
+    """Softmax attention of ``q [B, T, kv, G, D]`` (already scaled) over the
+    ring's keys ``[B, R, kv, D]`` and the chunk's own ``[B, T, kv, D]``, one
+    softmax over both parts (a query always sees itself) -> float32
+    ``[B, T, kv, G, D]``. Rematerialised in a backward pass: the scores of a
+    learner's chunk against its rings are a third of a gigabyte a layer, and
+    recomputing them costs one more read of the ring."""
+    s_ring = jnp.einsum("btkgd,brkd->bkgtr", q, ring_k, preferred_element_type=jnp.float32)
+    s_own = jnp.einsum("btkgd,bjkd->bkgtj", q, k, preferred_element_type=jnp.float32)
+    s_ring = jnp.where(see_ring[:, None, None], s_ring, _NEG)
+    s_own = jnp.where(see_chunk[:, None, None], s_own, _NEG)
+    top = jnp.maximum(s_ring.max(-1), s_own.max(-1))[..., None]
+    e_ring, e_own = jnp.exp(s_ring - top), jnp.exp(s_own - top)
+    total = e_ring.sum(-1) + e_own.sum(-1)                             # [B, kv, G, T]
+    out = jnp.einsum(
+        "bkgtr,brkd->btkgd", e_ring.astype(q.dtype), ring_v,
+        preferred_element_type=jnp.float32,
+    ) + jnp.einsum(
+        "bkgtj,bjkd->btkgd", e_own.astype(q.dtype), v,
+        preferred_element_type=jnp.float32,
+    )
+    return out / jnp.moveaxis(total, 3, 1)[..., None]
+
+
+class Attention(nn.Module):
+    config: ModelConfig
+    full: bool
+
+    @nn.compact
+    def __call__(self, a, ring, pos0, cursor0, seg, p):
+        cfg = self.config
+        dtype = _dtype(cfg.dtype)
+        B, T, _ = a.shape
+        nh, kv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        G, W = nh // kv, cfg.context_window
+        R = ring[0].shape[1]
+        ring_k, ring_v = (r.reshape(B, R, kv, D) for r in ring)    # stored [B, R, kv D]
+        with jax.named_scope("core_attn_full" if self.full else "core_attn_window"):
+            q = _dense(cfg, nh * D, "wq")(a).reshape(B, T, kv, G, D)
+            k = _dense(cfg, kv * D, "wk")(a).reshape(B, T, kv, D)
+            v = _dense(cfg, kv * D, "wv")(a).reshape(B, T, kv, D)
+            gate = _dense(cfg, nh * D, "wgate")(a)
+            q = RMSNorm(cfg, name="q_norm")(q)
+            k = RMSNorm(cfg, name="k_norm")(k)
+            if not self.full:
+                q, k = rope(q, p, cfg.rope_theta), rope(k, p, cfg.rope_theta)
+            q = (q / math.sqrt(D)).astype(dtype)
+            k = k.astype(dtype)
+
+            t = jnp.arange(T, dtype=jnp.int32)
+            age = (cursor0[:, None] - 1 - jnp.arange(R, dtype=jnp.int32)[None, :]) % R
+            in_episode = age < pos0[:, None]                               # [B, R]
+            see_ring = (seg == 0)[:, :, None] & in_episode[:, None, :]    # [B, T, R]
+            see_chunk = (t[:, None] >= t[None, :])[None] & (seg[:, :, None] == seg[:, None, :])
+            if not self.full:
+                see_ring &= (t[None, :, None] + 1 + age[:, None, :]) < W
+                see_chunk &= ((t[:, None] - t[None, :]) < W)[None]
+
+            out = _attend(q, k, v.astype(dtype), ring_k, ring_v, see_ring, see_chunk)
+            out = out.reshape(B, T, nh * D) * nn.sigmoid(gate.astype(jnp.float32))
+            attn = _dense(cfg, cfg.hidden_dim, "wo")(out.astype(dtype))
+        with jax.named_scope("core_cache_write"):
+            rows = jnp.arange(B)[:, None]
+            slots = (cursor0[:, None] + t[None, :]) % R                    # [B, T]
+            ring = tuple(
+                r.at[rows, slots].set(new.reshape(B, T, kv * D).astype(r.dtype))
+                for r, new in zip(ring, (k, v))
+            )
+        return attn, ring
+
+
+@jax.custom_vjp
+def _take_rows(x, idx, back):
+    """``x[idx]`` whose transpose is ``g[back]`` summed over each row's
+    copies: a gather both ways, where autodiff would scatter-add."""
+    return x[idx]
+
+
+def _take_rows_fwd(x, idx, back):
+    return x[idx], (back, x.shape[0])
+
+
+def _take_rows_bwd(res, g):
+    back, n = res
+    return g[back].reshape((n, -1) + g.shape[1:]).sum(axis=1), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+class RoutedExperts(nn.Module):
+    """Router, the held experts' terms and the shared expert: ``[B, T, H]``
+    -> ``[B, T, H]``."""
+
+    config: ModelConfig
+
+    @nn.compact
+    def __call__(self, m: jnp.ndarray) -> jnp.ndarray:
+        cfg = self.config
+        dtype, pdtype = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
+        B, T, H = m.shape
+        E, k, F = cfg.moe_experts, cfg.experts_per_token, cfg.expert_ffn_dim
+        held, offset = held_experts(cfg)
+        N = B * T
+        x = m.reshape(N, H)
+
+        with jax.named_scope("core_router"):
+            wr = self.param("router", nn.initializers.lecun_normal(), (H, E), pdtype)
+            bias = self.param("select_bias", nn.initializers.zeros, (E,), pdtype)
+            # the router multiplies in float32 whatever the compute type:
+            # its 128 scores pick the experts, and a rounded score picks others
+            s = nn.sigmoid(jnp.dot(
+                x.astype(jnp.float32), wr.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            ))                                                              # [N, E]
+            _, top = jax.lax.top_k(s + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+            w = jnp.take_along_axis(s, top, axis=1)                         # [N, k]
+            if cfg.route_norm:
+                w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+            w = w * cfg.route_scale
+            local = top - offset
+            here = (local >= 0) & (local < held)                            # [N, k]
+            # pairs sorted by held expert, pairs of absent experts last
+            key = jnp.where(here, local, held).reshape(N * k)
+            order = jnp.argsort(key, stable=True)
+            back = jnp.argsort(order)
+            load = (key[:, None] == jnp.arange(held)[None, :]).sum(axis=0).astype(jnp.int32)
+            n_here = load.sum()
+            row_here = jnp.arange(N * k) < n_here
+
+        def experts(name, shape, fan_in_axis):
+            return self.param(
+                name, nn.initializers.lecun_normal(in_axis=fan_in_axis, out_axis=-1, batch_axis=(0,)),
+                shape, pdtype,
+            ).astype(dtype)
+
+        with jax.named_scope("core_experts_routed"):
+            wg = experts("expert_gate", (held, H, F), 1)
+            wu = experts("expert_up", (held, H, F), 1)
+            wd = experts("expert_down", (held, F, H), 1)
+            # row j of the buffer is pair order[j], of token order[j] // k
+            xs = _take_rows(x.astype(dtype), order // k, back)              # [N k, H]
+            xs = jnp.where(row_here[:, None], xs, 0)
+            mid = nn.silu(jax.lax.ragged_dot(xs, wg, load)) * jax.lax.ragged_dot(xs, wu, load)
+            ys = jax.lax.ragged_dot(mid, wd, load)                          # [N k, H]
+            ys = _take_rows(ys, back, order).reshape(N, k, H)
+            ys = jnp.where(here[:, :, None], ys, 0)
+            routed = jnp.einsum(
+                "nkh,nk->nh", ys, w.astype(dtype), preferred_element_type=jnp.float32
+            )
+        with jax.named_scope("core_expert_shared"):
+            shared = SwiGLU(cfg, cfg.n_shared_experts * F, name="shared")(x.astype(dtype))
+
+        # per token, time-major like the scanned cores' sows (train/ppo.py
+        # masks the bootstrap step out of the auxiliary loss by time index)
+        def time_major(z):
+            return jnp.swapaxes(z.reshape(B, T, E), 0, 1)
+
+        # the choice itself, for whoever asks (``mutable=["routing"]``): a
+        # comparison with a reference has to know which experts were taken
+        self.sow("routing", "chosen", top.reshape(B, T, k))
+        chosen = jax.nn.one_hot(top, E, dtype=jnp.float32).sum(axis=1)      # [N, E]
+        self.sow("losses", "moe_probs", time_major(s / s.sum(axis=-1, keepdims=True)))
+        self.sow("losses", "moe_frac", time_major(chosen / k))
+        # a pair is multiplied iff its row of the buffer lies inside the groups' rows
+        covered = (back.reshape(N, k) < n_here) & here
+        self.sow("losses", "moe_local", here.sum().astype(jnp.float32))
+        self.sow("losses", "moe_dropped", (here.sum() - covered.sum()).astype(jnp.float32))
+        self.sow("losses", "moe_load", load.astype(jnp.float32))
+        # what the balancing update of the selection bias reads (train/ppo.py
+        # _balance_select_bias): each expert's tokens minus the mean, over
+        # the whole router, whatever share of it is held here
+        tokens = jax.lax.stop_gradient(chosen.sum(axis=0))
+        self.sow("losses", "select_bias_err", tokens - tokens.mean())
+        return (routed + shared.astype(jnp.float32)).reshape(B, T, H)
+
+
+class Block(nn.Module):
+    config: ModelConfig
+    layer: int
+
+    @nn.compact
+    def __call__(self, h, ring, pos0, cursor0, seg, p):
+        cfg = self.config
+        dtype = _dtype(cfg.dtype)
+        a = RMSNorm(cfg, name="in_norm")(h).astype(dtype)
+        attn, ring = Attention(cfg, layer_is_full(cfg, self.layer), name="attn")(
+            a, ring, pos0, cursor0, seg, p
+        )
+        h = h + RMSNorm(cfg, name="post_attn_norm")(attn)
+        m = RMSNorm(cfg, name="pre_mlp_norm")(h).astype(dtype)
+        if layer_is_dense(cfg, self.layer):
+            with jax.named_scope("core_dense_ffn"):
+                f = SwiGLU(cfg, cfg.dense_ffn_dim, name="ffn")(m)
+        else:
+            f = RoutedExperts(cfg, name="moe")(m)
+        return h + RMSNorm(cfg, name="post_mlp_norm")(f), ring
+
+
+class AfmoeCore(nn.Module):
+    """``(carry, x [B, T, H], resets [B, T] | None) -> (carry, y [B, T, H])``."""
+
+    config: ModelConfig
+
+    @nn.compact
+    def __call__(self, carry, x, resets=None):
+        cfg = self.config
+        check_config(cfg)
+        T = x.shape[1]
+        pos0, cursor0 = carry["pos"], carry["cursor"]
+        seg, p = chunk_positions(pos0, resets, T)
+        h = x.astype(jnp.float32)                  # the residual stream stays float32
+        if cfg.mup_enabled:
+            h = h * math.sqrt(cfg.hidden_dim)
+        rings = []
+        for layer in range(cfg.n_layers):
+            h, ring = Block(cfg, layer, name=f"layer_{layer}")(
+                h, carry["kv"][layer], pos0, cursor0, seg, p
+            )
+            rings.append(ring)
+        y = RMSNorm(cfg, name="out_norm")(h).astype(_dtype(cfg.dtype))
+        carry = {
+            "pos": p[:, -1] + 1,
+            "cursor": (cursor0 + T) % _cursor_modulus(cfg),
+            "kv": tuple(rings),
+        }
+        return carry, y

@@ -11,8 +11,18 @@ TPU-first design decisions (SURVEY.md §7 step 3):
 
 * One module serves both the actor's batch-step mode (``method="step"``) and
   the learner's teacher-forced sequence mode (``method="sequence"``), sharing
-  parameters — sequence mode drives the core with ``nn.scan`` (compiled
-  ``lax.scan``; no Python loop under jit).
+  parameters — sequence mode drives the LSTM and the windowed transformer
+  with ``nn.scan`` (compiled ``lax.scan``; no Python loop under jit), and
+  hands the afmoe core (``models/afmoe.py``) the whole ``[B, T]`` chunk in
+  ONE pass, of which its step is the T = 1 case.
+* The carry, its reset and the chunk-start carry a learner is handed are
+  the core's own: ``initial_state``, ``reset_carry`` and
+  ``chunk_start_carry``. The LSTM's ``(h, c)`` and the transformer's window
+  are rows that a reset zeroes (``mask_carry``) and a chunk start copies in
+  float32; the afmoe core's carry is per-lane attention caches of two sizes
+  (22 MiB a lane at Trinity-Mini's widths), which a reset never touches (a
+  position counter returns to 0) and a chunk start never copies (the start's
+  counters beside the end's rings).
 * The trunk and heads are written shape-polymorphically (Dense/einsum on the
   last axis) so the same code handles ``[B, ...]`` and ``[B, T, ...]``.
 * Compute dtype is configurable bfloat16 with float32 params; logits are cast
@@ -32,9 +42,14 @@ import jax.numpy as jnp
 from dotaclient_tpu.config import ActionSpec, ModelConfig, ObsSpec
 
 # Recurrent carry: (h, c) for the LSTM core; (valid, KV caches) for the
-# transformer core. Always a pytree whose leaves have leading batch axis —
-# mask/zero it with mask_carry, never by unpacking tuples.
+# transformer core; {"pos", "cursor", "kv"} for the afmoe core. Always a
+# pytree whose leaves have leading batch axis — reset it with
+# Policy.reset_carry, never by unpacking it.
 Carry = Any
+
+
+# Collections a core sows per call; never part of the parameters.
+TRANSIENT_COLLECTIONS = ("losses", "routing")
 
 
 def mask_carry(carry: Carry, keep: jnp.ndarray) -> Carry:
@@ -107,6 +122,10 @@ class Policy(nn.Module):
             from dotaclient_tpu.models.transformer import WindowedTransformerCore
 
             self.core = WindowedTransformerCore(cfg)
+        elif cfg.core == "afmoe":
+            from dotaclient_tpu.models.afmoe import AfmoeCore
+
+            self.core = AfmoeCore(cfg)
         else:
             raise ValueError(f"unknown core {cfg.core!r}")
         hs = self.action_spec.head_sizes
@@ -167,6 +186,10 @@ class Policy(nn.Module):
     # -- public modes ------------------------------------------------------
 
     def initial_state(self, batch_size: int) -> Carry:
+        if self.model.core == "afmoe":
+            from dotaclient_tpu.models import afmoe
+
+            return afmoe.initial_state(self.model, batch_size)
         if self.model.core == "transformer":
             from dotaclient_tpu.models.transformer import (
                 transformer_initial_state,
@@ -177,6 +200,29 @@ class Policy(nn.Module):
         dtype = _dtype(self.model.dtype)
         return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
+    def reset_carry(self, carry: Carry, keep: jnp.ndarray) -> Carry:
+        """Episode-boundary reset of the rows where ``keep`` ([B]) is 0, as
+        the core defines it: the LSTM and the windowed transformer zero the
+        row (``mask_carry``); the afmoe core returns the row's position to 0
+        and touches no cache."""
+        if self.model.core == "afmoe":
+            from dotaclient_tpu.models import afmoe
+
+            return afmoe.reset(carry, keep)
+        return mask_carry(carry, keep)
+
+    def chunk_start_carry(self, start: Carry, end: Carry) -> Carry:
+        """What a learner is handed as a chunk's ``carry0``, given the carry
+        before the chunk's first step and after its last: the start in
+        float32 for the cores whose carry is rewritten every step, and for
+        the afmoe core the start's counters beside the END's rings
+        (``afmoe.chunk_start_view``: no copy of a cache, no widening)."""
+        if self.model.core == "afmoe":
+            from dotaclient_tpu.models import afmoe
+
+            return afmoe.chunk_start_view(start, end)
+        return jax.tree.map(lambda t: t.astype(jnp.float32), start)
+
     def step(
         self, obs: Mapping[str, jnp.ndarray], carry: Carry
     ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, Carry]:
@@ -184,7 +230,12 @@ class Policy(nn.Module):
         with jax.named_scope("policy_trunk"):
             x, unit_emb = self._trunk(obs)
         with jax.named_scope("policy_core"):
-            carry, y = self.core(carry, x)
+            if self.model.core == "afmoe":
+                # the chunk function at T = 1
+                carry, y = self.core(carry, x[:, None])
+                y = y[:, 0]
+            else:
+                carry, y = self.core(carry, x)
         with jax.named_scope("policy_heads"):
             logits, value = self._heads(y, unit_emb)
         return logits, value, carry
@@ -220,6 +271,15 @@ class Policy(nn.Module):
                 axis=1,
             )
 
+        if self.model.core == "afmoe":
+            # one pass over the chunk: T queries against the carried keys
+            # and the chunk's own, the resets as a segment mask
+            with jax.named_scope("policy_core"):
+                carry, ys = self.core(carry, x, resets)
+            with jax.named_scope("policy_heads"):
+                logits, value = self._heads(ys, unit_emb)
+            return logits, value, carry
+
         def scan_step(cell, c, inp):
             xt, reset_t = inp
             c = mask_carry(c, 1.0 - reset_t)
@@ -247,13 +307,40 @@ class Policy(nn.Module):
         return self.step(obs, carry)
 
 
+def require_carry_stays(model: ModelConfig, where: str) -> None:
+    """Raise where ``where`` would ship a carry with every chunk or reply and
+    the core's carry is attention caches: the LSTM's and the windowed
+    transformer's rows travel, the afmoe core's megabytes a lane stay on
+    the chip (the fused trainer, the serve engine's resident carries)."""
+    if model.core == "afmoe":
+        from dotaclient_tpu.models.afmoe import carry_bytes_per_lane
+
+        raise ValueError(
+            f"core 'afmoe' carries {carry_bytes_per_lane(model):,} bytes of "
+            f"attention caches a lane: it trains in actor mode 'fused' and "
+            f"serves from the engine's resident carries, not in {where}, "
+            f"which would copy that carry with every chunk or reply"
+        )
+
+
+def require_episode_fits(model: ModelConfig, episode_steps: int, rollout_len: int) -> None:
+    """Raise where the core's carry cannot hold an episode of
+    ``episode_steps`` observations rolled out ``rollout_len`` at a time (the
+    afmoe core's rings: ``afmoe.require_episode_fits``)."""
+    if model.core == "afmoe":
+        from dotaclient_tpu.models import afmoe
+
+        afmoe.require_episode_fits(model, episode_steps, rollout_len)
+
+
 def make_policy(model: ModelConfig, obs_spec: ObsSpec, action_spec: ActionSpec) -> Policy:
-    if model.moe_experts > 0 and model.core != "transformer":
-        # only the transformer core routes an MoE FFN; silently training a
+    if model.moe_experts > 0 and model.core not in ("transformer", "afmoe"):
+        # only these cores route an MoE FFN; silently training a
         # dense LSTM under an "8-expert" label would mislabel every result
         raise ValueError(
             f"moe_experts={model.moe_experts} requires core='transformer' "
-            f"(got core={model.core!r}); the LSTM core has no FFN to route"
+            f"or 'afmoe' (got core={model.core!r}); the LSTM core has no "
+            f"FFN to route"
         )
     return Policy(model=model, obs_spec=obs_spec, action_spec=action_spec)
 
@@ -263,13 +350,14 @@ def init_params(policy: Policy, rng: jax.Array):
     from the policy's own specs).
 
     The ``losses`` collection (sown per-call intermediates like the MoE
-    load-balancing loss) is transient output, not state — it is stripped so
-    it never rides inside the param tree (where the learner's scan would
-    mistake it for a scannable variable)."""
+    load-balancing loss) and ``routing`` (the experts a routed layer took,
+    for a comparison that asks) are transient output, not state — they are
+    stripped so they never ride inside the param tree (where the learner's
+    scan would mistake them for scannable variables)."""
     dummy = dummy_obs_batch(1, policy.obs_spec, policy.action_spec)
     carry = policy.initial_state(1)
     variables = policy.init(rng, dummy, carry)
-    return {k: v for k, v in variables.items() if k != "losses"}
+    return {k: v for k, v in variables.items() if k not in TRANSIENT_COLLECTIONS}
 
 
 def dummy_obs_batch(

@@ -54,7 +54,11 @@ import numpy as np
 
 from dotaclient_tpu.config import RunConfig
 from dotaclient_tpu.models import distributions as D
-from dotaclient_tpu.models.policy import Policy, dummy_obs_batch, mask_carry
+from dotaclient_tpu.models.policy import (
+    Policy,
+    dummy_obs_batch,
+    require_carry_stays,
+)
 from dotaclient_tpu.utils import telemetry, utilization
 
 logger = logging.getLogger(__name__)
@@ -146,6 +150,8 @@ class ServeEngine:
         # host row tree) and the batcher installs them BETWEEN dispatches
         # — the same marshalling discipline as slot zeroes.
         self._carry_shadow = bool(scfg.carry_shadow)
+        if self._carry_shadow:
+            require_carry_stays(policy.model, "serve.carry_shadow")
         self._install_carries: Dict[int, Any] = {}
         # one carry ROW's pytree shape: the wire flatten/unflatten template
         # (leaves keyed c0..cN in jax.tree order)
@@ -158,8 +164,9 @@ class ServeEngine:
 
         def _dispatch_impl(params, obs, slots, reset, carries, rng):
             carry = jax.tree.map(lambda c: c[slots], carries)   # [B, ...]
-            # reset rows (fresh episodes AND padding rows) start from zeros
-            carry = mask_carry(carry, 1.0 - reset)
+            # reset rows (fresh episodes AND padding rows) start afresh, as
+            # the core defines it (a zeroed row; a cache core's position 0)
+            carry = self._policy.reset_carry(carry, 1.0 - reset)
             logits, _, carry2 = self._policy.apply(
                 params, obs, carry, method="step"
             )

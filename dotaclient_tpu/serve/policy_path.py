@@ -35,10 +35,12 @@ TRAIN_ONLY_PARAM_KEYS = ("head_value",)
 def make_inference_policy(config: RunConfig) -> Policy:
     """The serving-plane policy module: identical architecture, no value
     head (``value_head=False``), so it applies the sliced tree directly."""
-    if config.model.moe_experts > 0 and config.model.core != "transformer":
+    if config.model.moe_experts > 0 and config.model.core not in (
+        "transformer", "afmoe"
+    ):
         raise ValueError(
             f"moe_experts={config.model.moe_experts} requires "
-            f"core='transformer' (got core={config.model.core!r})"
+            f"core='transformer' or 'afmoe' (got core={config.model.core!r})"
         )
     return Policy(
         model=config.model,

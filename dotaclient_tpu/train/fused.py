@@ -5,9 +5,19 @@ The reference's loop crosses process and device boundaries every iteration
 collapsed the actor side into a single program; this module goes the rest of
 the way for the synchronous on-policy regime: the whole iteration —
 T-step rollout scan (featurize, policy, sample, env step, reward, episode
-reset), then the PPO update on the chunk it just produced — is one jitted,
-donated call. One dispatch per optimizer step, zero host round-trips,
-nothing staged through the trajectory buffer.
+reset), then the PPO update on the chunk it just produced — is one jitted
+call (``FusedStep``). One dispatch per optimizer step, zero host
+round-trips, nothing staged through the trajectory buffer. Where the train
+state and the actor state together hold more than
+``DONATE_ABOVE_BYTES`` on one device (the afmoe core's gigabytes
+of attention caches beside 400 M parameters) the call DONATES both: they
+update in place in HBM, so states that do not fit the chip twice run through
+it, and what reads the state across a dispatch (the league's snapshot, a
+weights publish, a checkpoint) copies on the device BEFORE the next enqueue.
+Smaller states keep one undonated program (``FusedStep`` says why).
+The episode reset and the chunk-start carry the update is handed are the
+core's own (``Policy.reset_carry``, ``Policy.chunk_start_carry``): no cache
+is rewritten or copied.
 
 This is the Anakin architecture (PAPERS.md [P:7]) taken to its endpoint: the
 buffered device loop issues 4–5 dispatches per optimizer step (collect +
@@ -42,6 +52,7 @@ The learner exposes it as ``actor="fused"``.
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -58,6 +69,7 @@ from dotaclient_tpu.parallel.mesh import (
 from dotaclient_tpu.train.ppo import (
     _train_step,
     fold_scan_metrics,
+    train_state_shape,
     train_state_sharding,
 )
 
@@ -106,8 +118,8 @@ def lane_minibatches(chunk, step, seed: int, n_lanes: int, n_shards: int,
 def make_fused_step(
     policy: Policy, config: RunConfig, mesh, actor, anchor_params=None
 ):
-    """Compile (state, actor_state, opp_params) → (state', actor_state',
-    metrics, stats) against ``mesh``.
+    """Build the fused program (``FusedStep``): (state, actor_state,
+    opp_params) → (state', actor_state', metrics, stats) against ``mesh``.
 
     The train state keeps the TP/DP shardings of ``make_train_step``; the
     actor state is pinned LANE-SHARDED (``actor_state_sharding``): games —
@@ -115,9 +127,9 @@ def make_fused_step(
     so sim stepping, featurize, the policy forward, sampling, and the
     in-graph outcome partials all compute on local lanes only and the chunk
     is BORN data-sharded; the mid-program sharding constraints are no-op
-    assertions, not reshards. ``opp_params`` must always be passed —
-    self-play callers pass the live params (the jitted program has one
-    signature for both modes).
+    assertions, not reshards. ``opp_params`` is a frozen snapshot, or
+    ``None`` (or the state's own ``params``) where the opponent is the live
+    policy: see ``FusedStep``.
     """
     if (config.ppo.anchor_kl_coef > 0) != (anchor_params is not None):
         raise ValueError(
@@ -127,6 +139,14 @@ def make_fused_step(
     repl = replicated(mesh)
     st_sh = train_state_sharding(policy, config, mesh)
     st_act_sh = actor_state_sharding(actor.state, mesh, config.mesh)
+    # Decided once, here, from what the two states hold on one device: past
+    # the limit both are donated, and the actor is told that its state is.
+    resident = _bytes_on_one_device(
+        train_state_shape(policy, config), st_sh
+    ) + _bytes_on_one_device(actor.state, st_act_sh)
+    donate = resident > DONATE_ABOVE_BYTES
+    if donate:
+        actor.donate_state()
 
     n_epochs = config.ppo.epochs_per_batch
     n_mb = max(1, config.ppo.minibatches)
@@ -238,12 +258,6 @@ def make_fused_step(
             stats = jax.tree.map(lambda s: s.sum(axis=0), stat_seq)
             return state, actor_state, metrics, stats
 
-    # No donation: in self-play the caller passes state.params AS
-    # opp_params (one signature for both modes), so donating the state
-    # would alias a donated buffer with a live input; the actor state's
-    # zero carries can likewise alias a cached constant on the first call.
-    # The state is LSTM(128)-scale — the copy cost is noise next to the
-    # dispatch savings this path exists for.
     # opp_params shards like the live params (st_sh's params subtree): under
     # TP, pinning it replicated would all-gather the full param set every
     # step — on the one-dispatch hot path this module exists to shorten.
@@ -252,8 +266,129 @@ def make_fused_step(
     # partitioned in HBM across dispatches; the per-chunk stats output
     # keeps the same partial layout (its game/lane axes are the sharded
     # ones), so emitting it is collective-free too.
-    return jax.jit(
+    return FusedStep(
         fused,
-        in_shardings=(st_sh, st_act_sh, st_sh.params),
+        state_sharding=st_sh,
+        actor_sharding=st_act_sh,
         out_shardings=(st_sh, st_act_sh, repl, st_act_sh.stats),
+        donate=donate,
+        both_kinds=actor.opponent_players != [],
     )
+
+
+# Past this many bytes of train state and actor state on one device the fused
+# program donates both: every dispatch in flight otherwise holds a second copy
+# (a quarter of the smallest chip this runs on, the v5e's 16 GB). Below it the
+# program stays the one undonated program: see ``FusedStep``.
+DONATE_ABOVE_BYTES = 4e9
+
+
+def _bytes_on_one_device(shapes: Any, shardings: Any) -> int:
+    return sum(
+        math.prod(sh.shard_shape(x.shape)) * x.dtype.itemsize
+        for x, sh in zip(jax.tree.leaves(shapes), jax.tree.leaves(shardings))
+    )
+
+
+class FusedStep:
+    """The fused program as the learner calls it:
+    ``step(state, actor_state, opp_params) -> (state', actor_state', metrics,
+    stats)``; ``opp_params`` is a frozen snapshot, or ``None`` (or the
+    state's own ``params``) where the opponent is the live policy.
+
+    ``donate`` is ``make_fused_step``'s decision, from the bytes the train
+    state and the actor state hold on one device against
+    ``DONATE_ABOVE_BYTES``.
+
+    **Without donation** (states that are a tenth of the chip: every LSTM
+    and windowed-transformer configuration this repo runs) it is ONE
+    program of three arguments and the live opponent is the state's
+    ``params`` passed a second time, as before PR 26: a second program
+    costs its build at every start (``setup_s`` +17.6% and +19.4% at the
+    benchmark's small and wide LSTM cells, bound 10%: my chip runs, PR 26).
+
+    **With donation** (states that are most of the chip and do not fit
+    twice: the afmoe core's attention caches beside 400 M parameters and
+    their Adam moments) both update in place in HBM, and a dispatch in
+    flight holds no second copy of either. A donated buffer cannot also be read as another
+    argument, so the live opponent is a program of its own with two
+    arguments. Both call one traced function (``jax.jit`` caches its
+    jaxpr), so the second costs a lowering and a compile, not a second
+    trace. With opponent lanes (``both_kinds``) either draw can come at any
+    dispatch, so both programs are built at the first call, from its
+    arguments' shapes: nothing is left to compile in the middle of a run.
+    Whoever reads the state across a dispatch copies on the device before
+    the next enqueue (``league/pool.py`` snapshot, ``train/snapshot.py``).
+
+    ``lower(state, actor_state, opp_params)`` lowers the program those
+    arguments would run, for ahead-of-time inspection.
+    """
+
+    def __init__(self, fused, state_sharding, actor_sharding, out_shardings,
+                 donate: bool, both_kinds: bool) -> None:
+        self.donate = donate
+        three = (state_sharding, actor_sharding, state_sharding.params)
+        if not donate:
+            self._jits = {"frozen": jax.jit(
+                fused, in_shardings=three, out_shardings=out_shardings,
+            )}
+            return
+        # traced once, its jaxpr laid into both programs (no call boundary)
+        inner = jax.jit(fused, inline=True)
+
+        def frozen_opponent(state, actor_state, opp_params):
+            return inner(state, actor_state, opp_params)
+
+        def live_opponent(state, actor_state):
+            return inner(state, actor_state, state.params)
+
+        self._jits = {
+            "frozen": jax.jit(
+                frozen_opponent, in_shardings=three,
+                out_shardings=out_shardings, donate_argnums=(0, 1),
+            ),
+            "live": jax.jit(
+                live_opponent, in_shardings=three[:2],
+                out_shardings=out_shardings, donate_argnums=(0, 1),
+            ),
+        }
+        self._both_kinds = both_kinds
+        self._programs: dict = {}
+
+    def _args(self, state, actor_state, opp_params):
+        live = opp_params is None or opp_params is state.params
+        if not self.donate:
+            return "frozen", (
+                state, actor_state, state.params if live else opp_params
+            )
+        if live:
+            return "live", (state, actor_state)
+        return "frozen", (state, actor_state, opp_params)
+
+    def _build(self, kind: str, state, actor_state) -> None:
+        args = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+            (state, actor_state),
+        )
+        if kind == "frozen":
+            args += (args[0].params,)
+        self._programs[kind] = self._jits[kind].lower(*args).compile()
+
+    def __call__(self, state, actor_state, opp_params=None):
+        kind, args = self._args(state, actor_state, opp_params)
+        if not self.donate:
+            return self._jits[kind](*args)
+        for k in tuple(self._jits) if self._both_kinds else (kind,):
+            if k not in self._programs:
+                self._build(k, state, actor_state)
+        return self._programs[kind](*args)
+
+    def lower(self, state, actor_state, opp_params=None):
+        kind, args = self._args(state, actor_state, opp_params)
+        return self._jits[kind].lower(*args)
+
+    def _cache_size(self) -> int:
+        """Programs built so far (``tracing.InstrumentedJit`` reads it)."""
+        if not self.donate:
+            return self._jits["frozen"]._cache_size()
+        return len(self._programs)

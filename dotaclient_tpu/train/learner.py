@@ -164,6 +164,12 @@ class Learner:
                 "checkify instruments the buffered train step, which fused "
                 "mode never calls — use actor='device' to hunt NaNs"
             )
+        if mode != "fused":
+            from dotaclient_tpu.models.policy import require_carry_stays
+
+            # every other mode ships each chunk's carry0 through the ring
+            # buffer or over the wire
+            require_carry_stays(config.model, f"actor mode {mode!r}")
         if mode == "external" and transport is None:
             raise ValueError(
                 "external actor mode needs a transport (TransportServer or "
@@ -1384,8 +1390,11 @@ class Learner:
                 self._host_step + self.config.league.opponent_hold
             )
         params, uid = self._held_opponent
-        if uid == league_pool.LIVE:
+        if uid == league_pool.LIVE and self.fused_step is None:
             params = self.state.params
+        # fused mode: a live draw stays None, and the fused program reads
+        # the opponent from the state it is handed (the state is donated,
+        # so it is never passed a second reference to state.params)
         return params, uid
 
     def _report_league(self, idx: int, chunk_stats) -> None:
@@ -1482,6 +1491,7 @@ class Learner:
 
         def _finish_metrics(host) -> None:
             scalars = {k: float(v) for k, v in host["m"].items()}   # host-sync-ok: snapshot thread, fetched host arrays
+            self._fold_moe_counters(scalars)
             if stats_source is not None:
                 # host-only read: every stat drain submitted up to this
                 # boundary was folded by the engine BEFORE this job ran
@@ -1506,6 +1516,23 @@ class Learner:
             self._last_metrics = self.metrics.log(step, scalars)
 
         return _finish_metrics
+
+    def _fold_moe_counters(self, scalars: Dict[str, float]) -> None:
+        """A logged step's routed-expert counts (``train/ppo._moe_counters``:
+        present only for a core that holds part of a routed layer) into the
+        registry, at the log cadence: the logged step's pairs and load as
+        gauges, the pairs its weights left out as a counter that a correct
+        layer never moves."""
+        if "moe_local_assignments" not in scalars:
+            return
+        tel = self.telemetry
+        tel.gauge("moe/local_assignments").set(scalars["moe_local_assignments"])
+        tel.gauge("moe/max_over_mean_expert_load").set(
+            scalars["moe_max_over_mean_load"]
+        )
+        tel.counter("moe/dropped_assignments").inc(
+            scalars["moe_dropped_assignments"]
+        )
 
     def _publish_pipeline_gauges(self) -> None:
         """Refresh the cross-stage gauges at a log boundary: actor weight
@@ -1688,6 +1715,7 @@ class Learner:
                                 scalars.update(self.device_actor.drain_stats())
                             elif self.pool is not None:
                                 scalars.update(self.pool.drain_stats())
+                        self._fold_moe_counters(scalars)
                         # the fetch blocked on the dispatched step — overlap
                         # window for prefetch accounting closes here
                         self._dispatch_inflight = False
@@ -1788,8 +1816,8 @@ class Learner:
                     with tel.span("learner/iteration", step=self._host_step):
                         with tel.span("learner/league_draw"):
                             opp_params, opp_idx = self._league_opponent()
-                        if opp_params is None:       # self-play / scripted: one
-                            opp_params = self.state.params   # signature for all modes
+                        # opp_params None: self-play, scripted, or a live
+                        # league draw (the opponent is the state's own params)
                         t0 = time.perf_counter()
                         with tel.span("learner/dispatch"):
                             self.state, da.state, m, chunk_stats = self.fused_step(

@@ -121,6 +121,61 @@ def _moe_aux_loss(losses_col: Any, valid: jnp.ndarray) -> jnp.ndarray:
     return aux
 
 
+def _moe_counters(losses_col: Any) -> Dict[str, jnp.ndarray]:
+    """What a core that holds part of a routed layer counted in this pass
+    (``models/afmoe.py``), summed over its expert layers: token-expert pairs
+    computed here, pairs its weights left out (always 0), and the busiest
+    held expert's load over the mean. Empty for every other core."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(losses_col)
+
+    def leaves(name):
+        return [l for p, l in flat if name in jax.tree_util.keystr(p)]
+
+    loads = leaves("moe_load")
+    if not loads:
+        return {}
+    load = jnp.stack(loads)                                   # [layers, held]
+    return {
+        "moe_local_assignments": sum(leaves("moe_local")),
+        "moe_dropped_assignments": sum(leaves("moe_dropped")),
+        "moe_max_over_mean_load": (
+            load.max(axis=1) / jnp.maximum(load.mean(axis=1), 1e-9)
+        ).max(),
+    }
+
+
+def _select_bias_errors(losses_col: Any) -> Dict[Tuple[str, ...], jnp.ndarray]:
+    """{module path: [E] tokens an expert took minus the mean} for every
+    routed layer that balances its load by a selection bias
+    (``models/afmoe.py``). Empty for every other core."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(losses_col)
+    return {
+        tuple(k.key for k in p[:-2]): l
+        for p, l in flat if getattr(p[-2], "key", None) == "select_bias_err"
+    }
+
+
+def _balance_select_bias(params: Any, errors: Dict[Tuple[str, ...], jnp.ndarray], rate: float) -> Any:
+    """The load-balancing update of a routed layer's selection bias, which no
+    gradient reaches: an expert that took more tokens than the mean in this
+    batch is chosen a little less readily in the next, ``bias += rate *
+    centred(sign(mean - tokens))``."""
+
+    def at(tree, path, fn):
+        if not path:
+            return fn(tree)
+        return {**tree, path[0]: at(tree[path[0]], path[1:], fn)}
+
+    for path, err in errors.items():
+        step = -jnp.sign(err)
+        step = rate * (step - step.mean())
+        params = at(
+            params, ("params",) + path + ("select_bias",),
+            lambda b: b + step.astype(b.dtype),
+        )
+    return params
+
+
 def ppo_loss(
     policy: Policy,
     params: Any,
@@ -160,6 +215,8 @@ def ppo_loss(
         mutable=["losses"],
     )
     moe_aux = _moe_aux_loss(mutated.get("losses", {}), valid)
+    moe_counters = _moe_counters(mutated.get("losses", {}))
+    bias_errors = _select_bias_errors(mutated.get("losses", {}))
     # Trailing slot is the bootstrap step: value used, policy outputs unused.
     logits_t = {k: v[:, :T] for k, v in logits.items()}
     obs_t = {k: v[:, :T] for k, v in obs.items()}
@@ -266,6 +323,7 @@ def ppo_loss(
     metrics = {
         "loss": loss,
         "moe_aux": moe_aux,
+        **moe_counters,
         **(
             {"anchor_kl": anchor_kl}
             if cfg.anchor_kl_coef > 0 and anchor_params is not None
@@ -275,6 +333,9 @@ def ppo_loss(
         # there — never reaches the logger). Only when the KL-adaptive lr
         # is on, to avoid carrying a [B, T] array through aux otherwise.
         **({"_logp": logp} if cfg.kl_target > 0 else {}),
+        # Likewise popped by _train_step: the load errors its balancing
+        # update of the selection biases reads (a routed afmoe layer only).
+        **({"_select_bias_err": bias_errors} if bias_errors else {}),
         "policy_loss": policy_loss,
         "value_loss": value_loss,
         "entropy": ent,
@@ -308,6 +369,7 @@ def _train_step(
     # trace times loss, optimizer and probes by name.
     with jax.named_scope("update_loss"):
         (_, metrics), grads = grad_fn(state.params)
+    bias_errors = metrics.pop("_select_bias_err", {})
     with jax.named_scope("update_optimizer"):
         if cfg.value_warmup_steps:
             # Critic-only warmup: zero every gradient outside the value head so
@@ -348,6 +410,8 @@ def _train_step(
             )
         updates, opt_state = opt.update(grads, opt_state_in, state.params)
         params = optax.apply_updates(state.params, updates)
+        if cfg.select_bias_rate > 0:
+            params = _balance_select_bias(params, bias_errors, cfg.select_bias_rate)
     with jax.named_scope("update_probe"):
         if cfg.kl_target > 0:
             # KL-adaptive lr: measure the POST-update policy shift on this
@@ -455,15 +519,20 @@ def train_state_sharding(policy: Policy, config: RunConfig, mesh: Mesh):
     """The TrainState sharding tree (TP partition rules applied to params
     and the Adam mirrors, scalars replicated) — the single source of truth
     shared by ``make_train_step`` and the fused step."""
-    from dotaclient_tpu.models import init_params
     from dotaclient_tpu.parallel.sharding import state_shardings
 
-    state_shape = jax.eval_shape(
+    return state_shardings(train_state_shape(policy, config), mesh, config.mesh)
+
+
+def train_state_shape(policy: Policy, config: RunConfig) -> TrainState:
+    """The TrainState's shapes and dtypes, with nothing allocated."""
+    from dotaclient_tpu.models import init_params
+
+    return jax.eval_shape(
         lambda: init_train_state(
             init_params(policy, jax.random.PRNGKey(0)), config.ppo
         )
     )
-    return state_shardings(state_shape, mesh, config.mesh)
 
 
 def make_train_step(
