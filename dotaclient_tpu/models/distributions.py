@@ -116,7 +116,40 @@ def log_prob(
 
 
 def _take(logp: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    return jnp.take_along_axis(logp, idx[..., None].astype(jnp.int32), axis=-1)[..., 0]
+    """``logp[..., idx]`` along the head's (last) axis as compare-select-reduce,
+    never an XLA ``gather``, and in the backward pass never a ``scatter-add``
+    (the gradient of a select is a select).
+
+    A data-dependent gather runs close to one element at a time on the TPU
+    and takes its operand from HBM, so the log-softmax it reads was written
+    out whole: the five lookups of ``_joint_logp`` were a fifth of the
+    ``dota5v5-lstm128`` step (PERF.md section 6, PR 27). The select reads the
+    head's columns once more inside the fusion that normalised them.
+
+    Select BEFORE reduce, deliberately NOT a one-hot product: masked entries
+    are ``NEG_INF`` and an ``einsum`` at default precision rounds float32
+    through bfloat16 on the MXU. Nothing in an unselected slot reaches the
+    sum, and a sum of one value and zeros is that value: for an index in
+    ``[0, K)`` the result is the gather's bit for bit (a ``-0.0`` comes out
+    ``+0.0``).
+
+    An index outside ``[0, K)`` gives ``NaN``, negatives included (the
+    gather filled ``NaN`` past the head and wrapped ``-K..-1``). That loud
+    result is kept on purpose, at one compare a row: sampled actions are in
+    range by construction (``jax.random.categorical``), but actions from
+    external actors reach ``log_prob`` through an ingest door that holds
+    integers to their narrow store dtype's range only
+    (``transport/serialize.py _narrow_rollout_flat``,
+    ``buffer/trajectory_buffer.py _payload_in_bounds``: int8 for every head,
+    and no range check at all on a full-width wire), not to the head's
+    ``size - 1``. A corrupt action therefore still poisons the loss and trips
+    the non-finite-loss latch (``train/health.py``), as it did before.
+    """
+    k = logp.shape[-1]
+    idx = idx.astype(jnp.int32)
+    mask = idx[..., None] == jnp.arange(k, dtype=jnp.int32)
+    picked = jnp.where(mask, logp, 0.0).sum(axis=-1)
+    return jnp.where((idx >= 0) & (idx < k), picked, jnp.nan)
 
 
 def _joint_logp(

@@ -383,12 +383,40 @@ STAGE_SCOPES = (
 POLICY_SCOPES = ("policy_trunk", "policy_core", "policy_core_scan", "policy_heads")
 
 
+def _hlo_sources(hlo):
+    """``stack_frame_id -> (file, line)`` of the program line an instruction
+    of an HLO text came from, read from the tables at the module's head
+    (``FileNames``, ``FileLocations``, ``StackFrames``)."""
+    def table(name):
+        rows = {}
+        for line in hlo[hlo.index(f"\n{name}\n") + len(name) + 2:].split("\n"):
+            m = re.match(r"(\d+) (.*)$", line)
+            if not m:
+                break
+            rows[int(m.group(1))] = m.group(2)
+        return rows
+
+    def field(row, key):
+        return int(re.search(rf"\b{key}=(\d+)", row).group(1))
+
+    files = {k: v.strip('"') for k, v in table("FileNames").items()}
+    locs = {
+        k: (files[field(v, "file_name_id")], field(v, "line"))
+        for k, v in table("FileLocations").items()
+    }
+    return {
+        k: locs[field(v, "file_location_id")]
+        for k, v in table("StackFrames").items()
+    }
+
+
 class TestFusedScopes:
-    def test_lowered_step_carries_every_scope_and_little_unscoped_work(self):
-        """The guard for `unscoped_device_share` that needs no chip: every
-        scope is in the lowered step's metadata, and nearly every operation
-        of the lowered module was written under one of the two phases."""
-        from benchmark.readers import _scopes
+    @pytest.fixture(scope="class")
+    def lowered_and_hlo(self):
+        """The fused step at test size, lowered (the metadata as written)
+        and compiled without optimisation: only in the compiled module are
+        calls inlined and every instruction's `op_name` the whole path from
+        the program's root."""
         from dotaclient_tpu.actor.device_rollout import DeviceActor
         from dotaclient_tpu.models import init_params, make_policy
         from dotaclient_tpu.parallel import make_mesh
@@ -407,16 +435,24 @@ class TestFusedScopes:
         lowered = make_fused_step(policy, cfg, mesh, actor).lower(
             state, actor.state, params
         )
-        text = lowered.as_text(debug_info=True)
-        for scope in PHASE_SCOPES + STAGE_SCOPES + POLICY_SCOPES:
-            assert re.search(rf'[/"(]{scope}[/")]', text), scope
-
-        # the optimised module: only there are calls inlined and every
-        # instruction's `op_name` the whole path from the program's root
         hlo = lowered.compile(compiler_options={
             "xla_backend_optimization_level": 0,     # the names, not the code
             "xla_llvm_disable_expensive_passes": True,
         }).as_text()
+        return lowered.as_text(debug_info=True), hlo
+
+    def test_lowered_step_carries_every_scope_and_little_unscoped_work(
+        self, lowered_and_hlo
+    ):
+        """The guard for `unscoped_device_share` that needs no chip: every
+        scope is in the lowered step's metadata, and nearly every operation
+        of the lowered module was written under one of the two phases."""
+        from benchmark.readers import _scopes
+
+        text, hlo = lowered_and_hlo
+        for scope in PHASE_SCOPES + STAGE_SCOPES + POLICY_SCOPES:
+            assert re.search(rf'[/"(]{scope}[/")]', text), scope
+
         names = re.findall(r'op_name="([^"]*)"', hlo)
         assert len(names) > 5000
         rest = collections.Counter(
@@ -430,3 +466,36 @@ class TestFusedScopes:
         assert share > 0.94, (share, rest.most_common(40))
         for phase in PHASE_SCOPES:
             assert sum(phase in _scopes.segments(n) for n in names) > 500
+
+    def test_sampling_and_loss_look_nothing_up_by_gather(self, lowered_and_hlo):
+        """ISSUE 27: the action distribution looks a chosen action's
+        log-probability up by compare-select-reduce, so under
+        `rollout_sample` and under `update_loss` no `gather` and no
+        `scatter` comes from `models/distributions.py` (on the chip they
+        were 22% of the small cell's step, PERF.md section 6). What is left
+        under the two scopes is named: the featurizer's slot translation in
+        `actions_to_sim` (ROADMAP S11) and the trunk's hero embedding."""
+        from benchmark.readers import _scopes
+
+        _, hlo = lowered_and_hlo
+        sources = _hlo_sources(hlo)
+        rows = re.findall(
+            r'^\s*(?:ROOT )?%[\w.\-]+ = \S+ ([\w\-]+)\(.*op_name="([^"]*)"'
+            r"(?: stack_frame_id=(\d+))?",
+            hlo, re.M,
+        )
+        assert len(rows) > 5000
+        lookups = [
+            (kind, name, sources[int(frame)][0] if frame else "")
+            for kind, name, frame in rows
+            if "gather" in kind or "scatter" in kind
+        ]
+        # the featurizer's are found, so a lookup would be seen if it were there
+        assert any("features/jax_featurizer.py" in src for _, _, src in lookups)
+        assert not [row for row in lookups if "models/distributions.py" in row[2]]
+        left = collections.Counter(
+            src.rsplit("/", 2)[-2] + "/" + src.rsplit("/", 1)[-1]
+            for _, name, src in lookups
+            if {"rollout_sample", "update_loss"} & set(_scopes.segments(name))
+        )
+        assert set(left) <= {"features/jax_featurizer.py", "linen/linear.py"}, left
