@@ -407,11 +407,8 @@ class ActorPool(WindowedStatsMixin):
             if done and lane is self._env_owner(lane.env_idx):
                 self._on_episode_end(lane.env_idx, ws)
         outcome_records.add_reward_terms(self._tel, step_terms)
-        if self._util is not None:
-            self._util.phase("featurize", feat_s)
-            self._util.phase(
-                "env_step", time.perf_counter() - t_env - feat_s
-            )
+        self._util.phase("featurize", feat_s)
+        self._util.phase("env_step", time.perf_counter() - t_env - feat_s)
 
         if finished:
             H = self.config.model.hidden_dim
@@ -493,9 +490,8 @@ class ActorPool(WindowedStatsMixin):
         )
         self._next_rollout_id += 1
         t_ship = time.perf_counter()
-        if self._util is not None:
-            # chunk assembly above is encode; the publish leg is ship_wait
-            self._util.phase("encode", t_ship - t_enc)
+        # chunk assembly above is encode; the publish leg is ship_wait
+        self._util.phase("encode", t_ship - t_enc)
         if self.rollout_sink is not None:
             # in-proc consumers get full-width protos (gRPC-parity path —
             # no wire to save bytes on)
@@ -504,8 +500,7 @@ class ActorPool(WindowedStatsMixin):
             self.transport.publish_rollout(
                 encode_rollout(arrays, **meta, **self._wire_kwargs)
             )
-        if self._util is not None:
-            self._util.phase("ship_wait", time.perf_counter() - t_ship)
+        self._util.phase("ship_wait", time.perf_counter() - t_ship)
         self.rollouts_shipped += 1
         self._tel.counter("actor/rollouts_shipped").inc()
         self._tel.counter("actor/frames_shipped").inc(n)
@@ -515,9 +510,8 @@ class ActorPool(WindowedStatsMixin):
         for t in range(n_steps):
             if refresh_every and t % refresh_every == 0:
                 self.refresh_weights()
-                if self._util is not None:
-                    # cadence-gated fold (one clock compare per boundary)
-                    self._util.maybe_fold()
+                # cadence-gated fold (one clock compare per boundary)
+                self._util.maybe_fold()
             self.step()
         return self.stats()
 

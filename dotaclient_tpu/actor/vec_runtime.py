@@ -307,10 +307,9 @@ class VecActorPool(WindowedStatsMixin):
         self.sim.step(sim_actions)
 
         r = self.rewards.compute()                                 # [L]
-        if self._util is not None:
-            # env_step = sim advance + reward compute (both host-side
-            # simulation work, indivisible from the env's point of view)
-            self._util.phase("env_step", time.perf_counter() - t_env)
+        # env_step = sim advance + reward compute (both host-side
+        # simulation work, indivisible from the env's point of view)
+        self._util.phase("env_step", time.perf_counter() - t_env)
         # outcome plane: every live game advanced one env step, and the
         # step's weighted per-term reward sums feed the decomposition
         self._ep_game_steps += 1
@@ -328,8 +327,7 @@ class VecActorPool(WindowedStatsMixin):
 
         t_feat = time.perf_counter()
         obs_next = self.feat.featurize_all()
-        if self._util is not None:
-            self._util.phase("featurize", time.perf_counter() - t_feat)
+        self._util.phase("featurize", time.perf_counter() - t_feat)
         finished = (self._cursor >= T) | done_lane
         if finished.any():
             self._emit_chunks(np.nonzero(finished)[0], done_lane, obs_next, carry_np, version)
@@ -350,8 +348,7 @@ class VecActorPool(WindowedStatsMixin):
             self._reset_mask |= done_lane
             t_feat = time.perf_counter()
             obs_next = self.feat.featurize_all()  # fresh-episode observations
-            if self._util is not None:
-                self._util.phase("featurize", time.perf_counter() - t_feat)
+            self._util.phase("featurize", time.perf_counter() - t_feat)
         self._pending_obs = obs_next
 
     def _emit_chunks(
@@ -444,10 +441,9 @@ class VecActorPool(WindowedStatsMixin):
                     jax.tree.leaves(self._carry0), jax.tree.leaves(carry_np)
                 ):
                     buf[l] = src[l]
-        if self._util is not None:
-            # encode = chunk assembly (buffer slicing, pad, trace stamps);
-            # the publish leg below is ship_wait
-            self._util.phase("encode", time.perf_counter() - t_enc)
+        # encode = chunk assembly (buffer slicing, pad, trace stamps);
+        # the publish leg below is ship_wait
+        self._util.phase("encode", time.perf_counter() - t_enc)
         self._tel.counter("actor/rollouts_shipped").inc(len(out))
         self._tel.counter("actor/frames_shipped").inc(
             float(sum(m["length"] for m, _ in out))
@@ -474,8 +470,7 @@ class VecActorPool(WindowedStatsMixin):
                             arrays, **meta, **self._wire_kwargs, trace=blob
                         )
                     )
-        if self._util is not None:
-            self._util.phase("ship_wait", time.perf_counter() - t_ship)
+        self._util.phase("ship_wait", time.perf_counter() - t_ship)
         self.rollouts_shipped += len(out)
 
     def _record_episodes(self, games: np.ndarray) -> None:
@@ -519,10 +514,9 @@ class VecActorPool(WindowedStatsMixin):
                     # errors propagate like a failed rollout publish —
                     # the actor's reconnect machinery owns them
                     self._fleet.maybe_publish(self.transport)
-                if self._util is not None:
-                    # cadence-gated fold (one clock compare) at refresh
-                    # boundaries, same rhythm as the fleet publisher
-                    self._util.maybe_fold()
+                # cadence-gated fold (one clock compare) at refresh
+                # boundaries, same rhythm as the fleet publisher
+                self._util.maybe_fold()
             self.step()
         return self.stats()
 

@@ -398,7 +398,9 @@ class LearnerConfig:
     # batch N's in-flight donated epoch step — instead of at consume
     # time. advantage/overlap_fraction measures how much of the pass's
     # host time actually hid behind a dispatch. False defers every pass
-    # to consume time (the serial one-pass baseline bench.py measures).
+    # to consume time (the serial one-pass path; both give the same
+    # advantages, tests/test_advantage.py). Which is faster: not measured
+    # on chip (ROADMAP.md S1/D5).
     overlap_advantage: bool = True
 
 
@@ -450,8 +452,8 @@ class ServeConfig:
     (whichever first), runs ONE jitted dispatch over the padded batch with
     server-resident recurrent carries, and scatters sampled actions back
     per requester. These knobs trade latency (smaller window) against
-    throughput (fuller batches) — ``bench.py``'s serve stage measures the
-    curve."""
+    throughput (fuller batches); the curve is not measured on chip
+    (ROADMAP.md S6/R6)."""
 
     # Batch-collection deadline in milliseconds. 0 dispatches whatever is
     # pending immediately (minimum latency, worst batching).

@@ -12,8 +12,8 @@ Two operator contracts drift the same way telemetry keys do:
 * **CLI flags.** Every ``--flag`` OPERATIONS.md mentions must exist in
   some entrypoint (a doc'd flag that argparse rejects is a broken
   runbook), and every flag the learner/actor CLIs define must appear in
-  OPERATIONS.md (those two are the operator-facing surfaces; bench and
-  one-off scripts document themselves).
+  OPERATIONS.md (those two are the operator-facing surfaces; one-off
+  scripts document themselves).
 
 Everything is extracted statically: ``config.py`` dataclass fields via
 AST, ``add_argument("--x", ...)`` calls via AST, documented flags via a
@@ -64,11 +64,9 @@ ALL_CLIS = OPERATOR_CLIS + (
     "scripts/train_demo.py",
     "scripts/curriculum_5v5.py",
     "scripts/bench_configs.py",
-    "scripts/bench_transport_producer.py",
     "scripts/check_telemetry_schema.py",
     "scripts/check_host_sync.py",
-    "scripts/bench_trajectory.py",
-    "bench.py",
+    "benchmark/run.py",
 )
 
 # `--flag` mention: lowercase-dashed word; a trailing [_a-z0-9] after the

@@ -34,9 +34,9 @@ package closes that loop end to end:
 * **Surfacing**: alert rules with runbook anchors (win-rate collapse,
   episode-length anomaly, outcome-stream staleness) in the PR 13 engine,
   ``scripts/outcome_report.py`` (curves + per-opponent table +
-  ``OUTCOME_STATUS`` line), an outcome panel in
-  ``scripts/fleet_status.py``, and a ``bench.py outcome`` stage pinning
-  ``stages.outcome_overhead``.
+  ``OUTCOME_STATUS`` line), and an outcome panel in
+  ``scripts/fleet_status.py``. What the plane costs a step is not measured
+  on chip (ROADMAP.md R1).
 """
 
 from dotaclient_tpu.outcome.records import (  # noqa: F401

@@ -45,7 +45,7 @@ def make_mesh(
     device set takes the first ``dcn×data×model`` devices: a 1-device mesh
     in an 8-device process is the degenerate case of the one sharded code
     path (`--mesh data_parallel=1`), not a separate fork — the parity
-    probes in bench.py's multichip stage and tests/test_multichip.py
+    tests in tests/test_multichip.py and tests/test_fused_multichip.py
     depend on both sizes coexisting in one process.
     """
     devices = list(devices if devices is not None else jax.devices())

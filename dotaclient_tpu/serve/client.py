@@ -27,7 +27,8 @@ default mode resumes on a fresh zeroed carry (counted via
 ``carry_resets``/the router's ``router/carry_resets_total``); with
 ``serve.carry_shadow`` on, the client stashes the carry row each reply
 ships back and resends it on the first post-re-home request, so the
-session resumes bit-exact (the chaos/bench parity digest pins it).
+session resumes bit-exact (the re-home parity digest pins it:
+tests/test_router.py::test_rehome_parity_digest_is_bitwise).
 
 Request payloads ride the rollout codec, so
 ``serve.request_wire_dtype="bfloat16"`` narrows observation (and shadow

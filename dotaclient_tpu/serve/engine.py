@@ -26,7 +26,8 @@ same way: ``release_slot`` enqueues, the batcher zeroes the carry row
 between dispatches — every carry mutation happens on the batcher thread.
 
 Sampling determinism: dispatch ``i`` samples with ``fold_in(key(seed), i)``.
-The parity digest (bench.py serve stage) replays the same request stream
+The parity digest (tests/test_router.py::test_rehome_parity_digest_is_bitwise,
+the router stage of scripts/ci_gate.sh) replays the same request stream
 through this same compiled function in-process and requires bitwise-equal
 actions — the transport and batching machinery must be invisible to the
 policy.
@@ -363,7 +364,7 @@ class ServeEngine:
 
     def stop(self, timeout: float = 30.0) -> None:
         """Serve every pending request, then stop the batcher (tests and
-        bench teardown; production engines live for the process)."""
+        script teardown; production engines live for the process)."""
         with self._cond:
             self._stopped = True
             self._cond.notify_all()
@@ -389,10 +390,7 @@ class ServeEngine:
                     # the batcher is request-starved either way
                     t_w = time.perf_counter()
                     self._cond.wait()
-                    if self._util is not None:
-                        self._util.phase(
-                            "window_wait", time.perf_counter() - t_w
-                        )
+                    self._util.phase("window_wait", time.perf_counter() - t_w)
                 if self._stopped and not self._pending:
                     return
                 resets = list(self._reset_slots)
@@ -427,8 +425,7 @@ class ServeEngine:
                         "request(s) dropped; batcher continues",
                         type(e).__name__, e, len(rows),
                     )
-            if self._util is not None:
-                self._util.maybe_fold()
+            self._util.maybe_fold()
 
     def _peek_pending_weights(self) -> Optional[Tuple[int, Any]]:
         with self._weights_lock:
@@ -483,10 +480,7 @@ class ServeEngine:
                     self._tel.counter("serve/batch_window_hits").inc()
                     return rows
                 self._cond.wait(min(deadline - now, 0.05))
-                if self._util is not None:
-                    self._util.phase(
-                        "window_wait", time.perf_counter() - now
-                    )
+                self._util.phase("window_wait", time.perf_counter() - now)
 
     def _dispatch_window(self, rows: List[_Request]) -> None:
         n = len(rows)
@@ -519,8 +513,7 @@ class ServeEngine:
         self._dispatch_idx += 1
         version = self._version
         t_done = time.perf_counter()
-        if self._util is not None:
-            self._util.phase("dispatch", t_done - t_d)
+        self._util.phase("dispatch", t_done - t_d)
         timer = self._tel.timer("span/serve/request")
         errors = 0
         for i, req in enumerate(rows):
@@ -541,8 +534,7 @@ class ServeEngine:
                     )
             except Exception:   # noqa: BLE001 - a dead client must not kill the batcher
                 errors += 1
-        if self._util is not None:
-            self._util.phase("reply", time.perf_counter() - t_done)
+        self._util.phase("reply", time.perf_counter() - t_done)
         self._tel.counter("serve/dispatches_total").inc()
         self._tel.counter("serve/replies_total").inc(n - errors)
         if errors:
@@ -565,7 +557,8 @@ class ServeEngine:
     ) -> Tuple[np.ndarray, np.ndarray, Any]:
         """Replay one dispatch through the SAME compiled function the
         batcher runs — the in-process reference the serve parity digest
-        compares server replies against (bench.py serve stage). Maintains
+        compares server replies against (scripts/serve_loadgen.py
+        rehome_parity). Maintains
         its OWN carry tree (pass the previous call's return), so it never
         perturbs the live store. Returns ``(packed [B,5], logp [B],
         carries)``; rows past ``len(obs_rows)`` are padding."""

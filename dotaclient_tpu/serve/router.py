@@ -27,7 +27,8 @@ assignment epoch bumped so the client's next ``where`` sees the redirect.
 The state contract is the client's (serve/client.py): default mode resumes
 on a fresh zeroed carry slot (the reset_recurrent discipline, counted);
 carry-shadow mode resends the stashed carry row so the session resumes
-bit-exact (the chaos/bench parity digest pins it).
+bit-exact (the re-home parity digest pins it:
+tests/test_router.py::test_rehome_parity_digest_is_bitwise).
 
 Telemetry (all ``router/*`` keys eager-created at construction;
 ``check_telemetry_schema.py --require-router``): session and re-home

@@ -12,7 +12,8 @@ loop:
   scalar AND inside the compiled program; scanned multi-update programs
   (the fused epoch step, the fused rollout+update program, dispatch
   batching) AND-fold it across their updates. Cost: two scalar ops per
-  program — the bench ``health`` stage pins the overhead ≤ 2%.
+  program; on the chip ``update_probe`` is 0 of the device time in every
+  cell (PERF.md section 5).
 * **Submit (train thread, zero sync):** :meth:`HealthMonitor.submit`
   appends the step's tiny verdict scalars (device arrays — program
   outputs, never donated) to a host-side pending deque. No fetch, no

@@ -678,8 +678,7 @@ class Learner:
         # wall-clock second to a closed phase set at the boundaries the
         # loop already has. The factory eager-creates every util/* gauge
         # (so `check_telemetry_schema.py --require-utilization` validates
-        # ANY learner JSONL) and returns None when the module knob is off
-        # — the faults.get() one-pointer-test discipline.
+        # ANY learner JSONL) and returns the accountant.
         self._util = utilization.make_learner(self.telemetry)
         # Pipeline restore (buffer contents + device-actor state) happens
         # after those components exist; weights/opt-state restored above.
@@ -793,14 +792,11 @@ class Learner:
                 self.state, m = self.epoch_step(
                     self.state, batch, perms.astype(np.int32)
                 )
-            if self._util is not None:
-                # the dispatch call's host time: in a throughput-bound
-                # loop it blocks on donation back-pressure — the
-                # host-observable proxy for device busy time (the
-                # accounting contract, docs/ARCHITECTURE.md)
-                self._util.phase(
-                    "dispatch_inflight", time.perf_counter() - t0
-                )
+            # the dispatch call's host time: in a throughput-bound
+            # loop it blocks on donation back-pressure — the
+            # host-observable proxy for device busy time (the
+            # accounting contract, docs/ARCHITECTURE.md)
+            self._util.phase("dispatch_inflight", time.perf_counter() - t0)
             self._dispatch_inflight = True
             self._host_step += E * M
             self._host_version += E * M
@@ -813,10 +809,7 @@ class Learner:
                 t0 = time.perf_counter()
                 with self.telemetry.span("learner/dispatch"):
                     self.state, m = self.train_step(self.state, batch)
-                if self._util is not None:
-                    self._util.phase(
-                        "dispatch_inflight", time.perf_counter() - t0
-                    )
+                self._util.phase("dispatch_inflight", time.perf_counter() - t0)
                 self._dispatch_inflight = True
                 self._host_step += 1
                 self._host_version += 1
@@ -833,11 +826,8 @@ class Learner:
                 t1 = time.perf_counter()
                 with self.telemetry.span("learner/dispatch"):
                     self.state, m = self.train_step(self.state, sub)
-                if self._util is not None:
-                    self._util.phase("gather", t1 - t0)
-                    self._util.phase(
-                        "dispatch_inflight", time.perf_counter() - t1
-                    )
+                self._util.phase("gather", t1 - t0)
+                self._util.phase("dispatch_inflight", time.perf_counter() - t1)
                 self._dispatch_inflight = True
                 self._host_step += 1
                 self._host_version += 1
@@ -879,12 +869,9 @@ class Learner:
             self.ingest()
         batch = self.buffer.take(current_version=self._host_version)
         dt = time.perf_counter() - t0
-        if self._util is not None:
-            # a productive take is batch assembly; an empty one is the
-            # buffer below min consumable — starvation, not staging
-            self._util.phase(
-                "gather" if batch is not None else "ingest_wait", dt
-            )
+        # a productive take is batch assembly; an empty one is the
+        # buffer below min consumable — starvation, not staging
+        self._util.phase("gather" if batch is not None else "ingest_wait", dt)
         if batch is not None:
             # only productive staging counts toward the overlap accounting
             # — empty polls while starved are idle waiting, not assemble
@@ -912,15 +899,11 @@ class Learner:
             current_version=self._host_version, hold=True
         )
         if taken is None:
-            if self._util is not None:
-                self._util.phase(
-                    "ingest_wait", time.perf_counter() - t0
-                )
+            self._util.phase("ingest_wait", time.perf_counter() - t0)
             return   # nothing staged: idle waiting, not assemble cost
         self._prefetched, self._prefetch_ticket = taken
         dt = time.perf_counter() - t0
-        if self._util is not None:
-            self._util.phase("gather", dt)
+        self._util.phase("gather", dt)
         # recorded only when a batch was actually staged, like the
         # transport/consume span — empty attempts would dilute both the
         # span stats and the overlap fraction toward meaninglessness
@@ -980,8 +963,7 @@ class Learner:
         dt = time.perf_counter() - t0
         self.telemetry.gauge("advantage/pass_ms").set(dt * 1e3)
         self.telemetry.counter("advantage/passes_total").inc()
-        if self._util is not None:
-            self._util.phase("advantage_pass", dt)
+        self._util.phase("advantage_pass", dt)
         if self._adv_first:
             # the first call pays the pass's XLA compile — steady-state
             # dispatch is sub-ms, so folding seconds of compile into the
@@ -1326,8 +1308,7 @@ class Learner:
                 )
         stall = time.perf_counter() - t0
         self._stall_s += stall
-        if self._util is not None:
-            self._util.phase("publish_stall", stall)
+        self._util.phase("publish_stall", stall)
         self.telemetry.gauge("learner/publish_stall_ms").set(stall * 1e3)
 
     def _drain_snapshots(self) -> None:
@@ -1592,8 +1573,7 @@ class Learner:
         # same host-sync boundary — host arithmetic only, arms
         # util/duty_cycle and advances the steps/s EMA + the
         # warmup-armed baseline the throughput sentinel compares against
-        if self._util is not None:
-            self._util.fold(self._host_step)
+        self._util.fold(self._host_step)
 
     def train(
         self,
@@ -1781,8 +1761,7 @@ class Learner:
                             )
                     ckpt_dt = time.perf_counter() - t0
                     self._stall_s += ckpt_dt
-                    if self._util is not None:
-                        self._util.phase("checkpoint_stall", ckpt_dt)
+                    self._util.phase("checkpoint_stall", ckpt_dt)
             if (
                 publish_midrun
                 and refresh_every
@@ -1823,10 +1802,9 @@ class Learner:
                             self.state, da.state, m, chunk_stats = self.fused_step(
                                 self.state, da.state, opp_params
                             )
-                        if self._util is not None:
-                            self._util.phase(
-                                "dispatch_inflight", time.perf_counter() - t0
-                            )
+                        self._util.phase(
+                            "dispatch_inflight", time.perf_counter() - t0
+                        )
                         dispatches.inc()
                         if opp_idx != league_pool.LIVE:
                             frozen.inc()
@@ -1881,8 +1859,7 @@ class Learner:
                     batch = self._next_batch()
                     if batch is None:
                         time.sleep(0.005)
-                        if self._util is not None:
-                            self._util.phase("ingest_wait", 0.005)
+                        self._util.phase("ingest_wait", 0.005)
                         continue
                     m = self._optimize(batch)
                     if steps_done + epochs < num_steps:   # see device loop
@@ -1916,8 +1893,7 @@ class Learner:
                         batch = self._next_batch()
                         if batch is None:
                             time.sleep(0.002)
-                            if self._util is not None:
-                                self._util.phase("ingest_wait", 0.002)
+                            self._util.phase("ingest_wait", 0.002)
                             continue
                         m = self._optimize(batch)
                         if steps_done + epochs < num_steps:   # see device loop

@@ -203,7 +203,7 @@ class SnapshotEngine:
 
     def stop(self, timeout: float = 30.0) -> None:
         """Process whatever is pending, then stop the thread (tests and
-        bench teardown; production engines live for the process)."""
+        script teardown; production engines live for the process)."""
         with self._cond:
             self._stopped = True
             self._cond.notify_all()

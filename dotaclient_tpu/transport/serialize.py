@@ -241,7 +241,7 @@ def decode_drained_payloads(
 def rollout_wire_kwargs(config) -> Dict[str, Any]:
     """The encode-call kwargs this config's rollout wire needs — ``{}``
     for a full-width wire. The ONE derivation every encoder shares
-    (actor pools, bench): a change to the encode contract (a new bound
+    (both actor pools): a change to the encode contract (a new bound
     source, say) lands here once instead of drifting across hand-rolled
     copies."""
     if config.transport.rollout_wire_dtype == "float32":

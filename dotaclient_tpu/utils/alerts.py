@@ -270,14 +270,14 @@ ALERT_WAIVERS: Dict[str, str] = {
         "to watch while alive"
     ),
     "rb:stall-diagnostics": (
-        "diagnostic gauge pair with no universal threshold; compared "
-        "against bench stages by a human"
+        "diagnostic gauge pair with no universal threshold; a human "
+        "compares it with the run's own earlier windows"
     ),
     "rb:advantage-speedup": (
-        "bench-time capability gate; the runtime overlap fraction varies "
-        "legitimately with consume patterns (serial consume-time passes "
-        "are correct, just unoverlapped) — compared against bench stages "
-        "by a human"
+        "the runtime overlap fraction varies legitimately with consume "
+        "patterns (serial consume-time passes are correct, just "
+        "unoverlapped); a human compares it with the run's own earlier "
+        "windows"
     ),
     "rb:divergence-exhausted": (
         "terminal non-zero exit is its own page; the precursor pages via "

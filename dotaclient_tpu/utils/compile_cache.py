@@ -1,9 +1,9 @@
 """One persistent XLA compile cache, placeable from outside.
 
 Every entry point that compiles for a device calls :func:`enable` first
-thing — the learner, the serve server, ``bench.py``, ``chip_smoke.py``, the
-``scripts/`` benchmarks and probes — so a second process (a restart, the
-serve server after the trainer, the next benchmark run) loads the compiled
+thing — the learner, the serve server, ``chip_smoke.py``, the ``scripts/``
+probes — so a second process (a restart, the serve server after the
+trainer, the next benchmark run) loads the compiled
 programs instead of rebuilding them.
 
 Where it goes: ``JAX_COMPILATION_CACHE_DIR`` is the outside handle. JAX reads

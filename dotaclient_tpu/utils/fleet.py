@@ -334,7 +334,7 @@ class FleetAggregator:
 
     Construction alone eager-creates every ``fleet/``+``alerts/`` tier
     key; ``start()`` is only called when a fleet can actually report
-    (the learner's external-transport modes, the bench stage)."""
+    (the learner's external-transport modes)."""
 
     def __init__(
         self,
@@ -437,8 +437,8 @@ class FleetAggregator:
                 warnings.warn(f"fleet aggregator tick failed: {e}")
 
     def tick(self, now: Optional[float] = None) -> None:
-        """One merge + rollup + alert-evaluation pass (public for tests
-        and the bench stage; production calls come from ``_run``)."""
+        """One merge + rollup + alert-evaluation pass (public for tests;
+        production calls come from ``_run``)."""
         if now is None:
             now = time.monotonic()
         with self._lock:

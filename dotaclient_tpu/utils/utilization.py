@@ -28,14 +28,13 @@ baseline — the cross-run perf-regression sentinel two alert rules watch
 (``learner_duty_cycle_low``, ``throughput_regression``; see
 ``utils/alerts.py`` and docs/OPERATIONS.md).
 
-Cost discipline (the ``faults.get()`` pattern, pinned by tests): every
-factory eager-creates its ``util/*`` gauges so
+Every factory eager-creates its ``util/*`` gauges so
 ``check_telemetry_schema.py --require-utilization`` validates ANY
-learner JSONL deterministically, then returns ``None`` when the module
-knob ``enabled`` is off — a disabled call site costs one pointer test.
-``util/duty_cycle`` initializes to the neutral 1.0 (and ``util/armed``
-to 0) so the duty-cycle alert cannot fire before the first fold arms the
-plane.
+learner JSONL deterministically, then returns its accountant: the plane
+is always on and has no switch. ``util/duty_cycle`` initializes to the
+neutral 1.0 (and ``util/armed`` to 0) so the duty-cycle alert cannot fire
+before the first fold arms the plane. What the accounting costs a
+dispatch is not measured on chip (ROADMAP.md S7).
 """
 
 from __future__ import annotations
@@ -45,11 +44,6 @@ import time
 from typing import Dict, Optional, Tuple
 
 from dotaclient_tpu.utils import telemetry
-
-# Module knob: bench.py's utilization stage flips this off for its
-# baseline variant; everything else leaves it on (the plane is designed
-# to be always-on — the bench stage gates its overhead at <= 2%).
-enabled: bool = True
 
 # steps/s smoothing: the fast EMA tracks the current regime, the slow
 # baseline EMA remembers the run's demonstrated throughput. Both are
@@ -81,8 +75,8 @@ SERVE_PHASES = ("window_wait", "dispatch", "reply", "other")
 def ensure_learner_keys(reg: telemetry.Registry) -> Dict[str, telemetry.Gauge]:
     """Eager-create the learner-side ``util/*`` gauges; returns handles.
 
-    Called even when the plane is disabled, so the schema tier holds for
-    any learner JSONL. Key names are literal (the telemetry-drift lint
+    Called at construction, so the schema tier holds for any learner
+    JSONL. Key names are literal (the telemetry-drift lint
     statically resolves every emission)."""
     handles: Dict[str, telemetry.Gauge] = {}
     for key in (
@@ -290,22 +284,17 @@ class PoolUtilization:
 
 def make_learner(
     registry: Optional[telemetry.Registry] = None,
-) -> Optional[LearnerUtilization]:
+) -> LearnerUtilization:
     reg = registry if registry is not None else telemetry.get_registry()
-    handles = ensure_learner_keys(reg)
-    if not enabled:
-        return None
-    return LearnerUtilization(handles)
+    return LearnerUtilization(ensure_learner_keys(reg))
 
 
 def make_actor(
     registry: Optional[telemetry.Registry] = None,
     interval_s: Optional[float] = None,
-) -> Optional[PoolUtilization]:
+) -> PoolUtilization:
     reg = registry if registry is not None else telemetry.get_registry()
     handles = ensure_actor_keys(reg)
-    if not enabled:
-        return None
     itv = telemetry.fleet_interval_s if interval_s is None else interval_s
     return PoolUtilization(handles, ACTOR_PHASES, "util/actor/", itv)
 
@@ -313,10 +302,8 @@ def make_actor(
 def make_serve(
     registry: Optional[telemetry.Registry] = None,
     interval_s: Optional[float] = None,
-) -> Optional[PoolUtilization]:
+) -> PoolUtilization:
     reg = registry if registry is not None else telemetry.get_registry()
     handles = ensure_serve_keys(reg)
-    if not enabled:
-        return None
     itv = telemetry.fleet_interval_s if interval_s is None else interval_s
     return PoolUtilization(handles, SERVE_PHASES, "util/serve/", itv)

@@ -1,10 +1,10 @@
 #!/usr/bin/env bash
 # One CI entrypoint (ISSUE 16): tier-1 tests, strict lint, the telemetry
 # schema contract (every --require-* tier against ONE smoke-run JSONL),
-# and the bench-trajectory perf gate — with a greppable
-# `CI_GATE <stage> PASS|FAIL` line per stage and a nonzero exit when any
-# stage fails. Stages keep running after a failure so one invocation
-# reports the full picture.
+# the fused lane-sharding parity verdict and the router failover smoke —
+# with a greppable `CI_GATE <stage> PASS|FAIL` line per stage and a
+# nonzero exit when any stage fails. Stages keep running after a failure
+# so one invocation reports the full picture.
 #
 # Usage:
 #   bash scripts/ci_gate.sh                 # all stages
@@ -64,20 +64,16 @@ else
     report schema $?
 fi
 
-# -- stage 4: bench-trajectory perf gate -----------------------------------
-python scripts/bench_trajectory.py --gate
-report bench_gate $?
-
-# -- stage 5: fused lane-sharding parity (PR 18) ---------------------------
+# -- stage 4: fused lane-sharding parity (PR 18) ---------------------------
 # The 1-vs-2 forced-host shape of the fused-parity verdict: the
 # lane-sharded one-dispatch program must produce a matching rollout
 # digest (1e-7 relative), Adam-tolerance losses, a 1e-5 param checksum,
-# AND the compiled lane-sharding proof. bench.py's fused_multichip stage runs
-# the same tool at 1-vs-8; this is the fast always-on pin.
+# AND the compiled lane-sharding proof. The fast always-on pin; the same
+# tool takes any N (`--fused-parity 8`).
 python scripts/run_multichip.py --fused-parity 2 --steps 2 --parity-steps 2
 report fused_parity $?
 
-# -- stage 6: router failover smoke (ISSUE 19) -----------------------------
+# -- stage 5: router failover smoke (ISSUE 19) -----------------------------
 # In-process serve-fleet failover: three tiny backends, a session-affine
 # router, a mid-game backend kill — the re-home must land bit-exact
 # (parity digest "bitwise", exit 0 iff so) and the router's JSONL must

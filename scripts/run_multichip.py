@@ -9,29 +9,21 @@ initialise as anything but a failure: the accelerator preflight is
   host devices (``XLA_FLAGS=--xla_force_host_platform_device_count`` +
   ``JAX_PLATFORMS=cpu``) in a fresh subprocess — the sharded-path validation
   tests/conftest.py and the driver use.
-* **--probe**: measurement mode for ``bench.py``'s multichip stage. Runs
-  the learner's fused epoch step (``train/ppo.make_epoch_step`` — the
-  production multi-update program) on THIS process's visible devices:
-  optimizer frames/sec plus a deterministic parity digest (per-step
-  losses and a param checksum from a fixed seed + the learner's
-  ``_mb_rng`` permutation stream). bench.py spawns one probe per device
-  count and compares digests — the sharded-vs-single-device numerical
-  parity headline. The caller pins the device count via env BEFORE the
-  probe process initializes its backend; ``--devices`` only *asserts*
-  the count.
-* **--fused**: same measurement contract for the ONE-dispatch fused
-  program (``train/fused.make_fused_step``, ``actor="fused"``): the whole
-  rollout+update iteration runs lane-sharded over this process's devices,
-  and the payload carries the compiled ``lane_sharded`` PROOF read off
-  ``input_shardings`` — the actor state's lane arrays must be
-  data-sharded, not replicated.
+* **--fused**: probe of the ONE-dispatch fused program
+  (``train/fused.make_fused_step``, ``actor="fused"``) on THIS process's
+  visible devices: the whole rollout+update iteration runs lane-sharded
+  over them, and the payload carries a deterministic parity digest
+  (per-dispatch losses and a param checksum from a fixed seed) and the
+  compiled ``lane_sharded`` PROOF read off ``input_shardings`` — the
+  actor state's lane arrays must be data-sharded, not replicated. The
+  caller pins the device count via env BEFORE the probe process
+  initializes its backend; ``--devices`` only *asserts* the count.
 * **--fused-parity N**: one-command verdict — spawns the fused probe at 1
   and N forced host devices (fresh subprocess each, env-pinned before
   backend init), compares per-dispatch losses + float64 param-L1 at
   reassociation tolerance, and requires the lane-sharding proof at N.
-  Shared by ``scripts/ci_gate.sh`` (fused-parity stage) and ``bench.py``
-  (fused_multichip stage).
-* **--dcn-slices M** (probe modes): build the 3-axis (dcn, data, model)
+  Run by ``scripts/ci_gate.sh`` (fused-parity stage).
+* **--dcn-slices M** (``--fused``): build the 3-axis (dcn, data, model)
   mesh — the multi-host spelling, exercisable single-host because forced
   host devices reshape the same way.
 
@@ -41,7 +33,6 @@ its environment.
 
 Usage:
     python scripts/run_multichip.py --force-host 8       # sharded dry run
-    python scripts/run_multichip.py --probe --steps 10   # bench probe
     python scripts/run_multichip.py --fused-parity 8     # fused verdict
 """
 
@@ -135,114 +126,6 @@ def _probe_config(dcn_slices: int):
             config.ppo, epochs_per_batch=2, minibatches=2
         ),
         mesh=dataclasses.replace(config.mesh, dcn_slices=dcn_slices),
-    )
-
-
-def probe(
-    expect_devices: Optional[int], n_steps: int, parity_steps: int,
-    dcn_slices: int = 1,
-) -> int:
-    """Measure the sharded fused epoch step on this process's devices."""
-    import time
-
-    import jax
-    import numpy as np
-
-    config = _probe_config(dcn_slices)
-    from dotaclient_tpu.models import init_params, make_policy
-    from dotaclient_tpu.parallel import make_mesh
-    from dotaclient_tpu.train import example_batch, init_train_state
-    from dotaclient_tpu.train.ppo import make_epoch_step, train_state_sharding
-    from dotaclient_tpu.utils import compile_cache
-
-    compile_cache.enable()
-
-    n_devices = len(jax.devices())
-    if expect_devices is not None and n_devices != expect_devices:
-        return _result(
-            {
-                "ok": False,
-                "n_devices": n_devices,
-                "error": (
-                    f"probe expected {expect_devices} devices but the "
-                    f"backend initialized {n_devices} — set XLA_FLAGS/"
-                    f"JAX_PLATFORMS before spawning the probe"
-                ),
-            }
-        )
-    # E×M > 1 (set in _probe_config) so the probe exercises the production
-    # multi-update program (in-program minibatch gathers + per-update grad
-    # psum), with the learner's exact permutation-stream contract.
-    B, T = config.ppo.batch_rollouts, config.ppo.rollout_len
-    E = config.ppo.epochs_per_batch
-    mesh = make_mesh(config.mesh)
-    policy = make_policy(config.model, config.obs, config.actions)
-    st_sh = train_state_sharding(policy, config, mesh)
-    step = make_epoch_step(policy, config, mesh)
-
-    def fresh_state():
-        state = init_train_state(
-            init_params(policy, jax.random.PRNGKey(config.seed)), config.ppo
-        )
-        return jax.device_put(state, st_sh)
-
-    rng = np.random.default_rng(0)
-    batch = example_batch(config, batch=B)
-    batch = dict(batch)
-    batch["obs"] = dict(batch["obs"])
-    batch["obs"]["units"] = jax.numpy.asarray(
-        rng.normal(size=batch["obs"]["units"].shape).astype(np.float32)
-    )
-    batch["rewards"] = jax.numpy.asarray(
-        rng.normal(size=(B, T)).astype(np.float32) * 0.1
-    )
-    batch["behavior_logp"] = jax.numpy.asarray(
-        -np.abs(rng.normal(size=(B, T))).astype(np.float32)
-    )
-
-    mb_rng = np.random.default_rng(config.seed + 1)
-
-    def perms() -> np.ndarray:
-        return np.stack(
-            [mb_rng.permutation(B) for _ in range(E)]
-        ).astype(np.int32)
-
-    # -- parity digest: K deterministic steps from a fresh state ------------
-    state = fresh_state()
-    losses: List[float] = []
-    for _ in range(parity_steps):
-        state, m = step(state, batch, perms())
-        losses.append(float(np.asarray(m["loss"])))
-    param_l1 = float(
-        sum(
-            np.abs(np.asarray(leaf, np.float64)).sum()
-            for leaf in jax.tree.leaves(jax.device_get(state.params))
-        )
-    )
-
-    # -- throughput: warmed steps, best of 2 segments -----------------------
-    state = fresh_state()
-    state, m = step(state, batch, perms())   # warm (compiled above, settle)
-    jax.block_until_ready(m["loss"])
-    fps = 0.0
-    for _ in range(2):
-        t0 = time.perf_counter()
-        for _ in range(n_steps):
-            state, m = step(state, batch, perms())
-        jax.block_until_ready(m["loss"])
-        fps = max(fps, n_steps * B * T / (time.perf_counter() - t0))
-
-    return _result(
-        {
-            "ok": True,
-            "n_devices": n_devices,
-            "mesh": {
-                "data": int(mesh.shape[config.mesh.data_axis]),
-                "model": int(mesh.shape[config.mesh.model_axis]),
-            },
-            "optimizer_frames_per_sec": round(fps, 1),
-            "parity": {"losses": losses, "param_l1": param_l1},
-        }
     )
 
 
@@ -444,8 +327,8 @@ def fused_parity(
       (≈1e-7 gradient deltas), and Adam's ``1/(sqrt(v̂)+ε)`` amplifies
       those on near-zero-gradient coordinates, so post-update losses
       agree to ~1e-4 absolute, not machine level (measured headroom ≈3×).
-    * ``param_l1`` checksum at ``|c1-cN| <= 1e-5·max(1, |c1|)`` — the
-      bench multichip stage's tolerance.
+    * ``param_l1`` checksum at ``|c1-cN| <= 1e-5·max(1, |c1|)``: a sum
+      over every parameter, where per-coordinate differences average out.
     """
     probes = {}
     for n in (1, n_high):
@@ -510,11 +393,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         "JAX_PLATFORMS=cpu), fresh subprocess",
     )
     mode.add_argument(
-        "--probe", action="store_true",
-        help="measurement mode (bench.py's multichip stage): fused epoch "
-        "step throughput + parity digest on this process's devices",
-    )
-    mode.add_argument(
         "--fused", action="store_true",
         help="measurement mode for the ONE-dispatch fused program "
         "(rollout + update, actor='fused'): lane-sharded throughput + "
@@ -528,18 +406,18 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     p.add_argument(
         "--devices", type=int, default=8,
-        help="probe modes: the device count to assert",
+        help="--fused: the device count to assert",
     )
     p.add_argument(
         "--dcn-slices", type=int, default=1,
-        help="probe modes: build the (dcn, data, model) mesh with this "
+        help="--fused: build the (dcn, data, model) mesh with this "
         "many DCN slices (multi-host spelling; device count must divide "
         "dcn_slices x model_parallel)",
     )
     p.add_argument("--steps", type=int, default=10,
-                   help="probe modes: timed optimizer dispatches per segment")
+                   help="fused modes: timed dispatches per segment")
     p.add_argument("--parity-steps", type=int, default=3,
-                   help="probe modes: deterministic steps in the parity "
+                   help="fused modes: deterministic steps in the parity "
                    "digest")
     p.add_argument("--rollout-len", type=int, default=8,
                    help="--fused/--fused-parity: rollout chunk length T for "
@@ -554,10 +432,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         return fused_probe(
             args.devices, args.steps, args.parity_steps, args.dcn_slices,
             args.rollout_len,
-        )
-    if args.probe:
-        return probe(
-            args.devices, args.steps, args.parity_steps, args.dcn_slices
         )
     return force_host_dryrun(args.force_host)
 

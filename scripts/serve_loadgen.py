@@ -3,9 +3,8 @@
 Drives N concurrent synthetic clients — each one attached game sending
 sequential step requests, exactly the serve protocol's cadence — against a
 ``PolicyServer`` and reports the headline serving curve: actions/sec and
-request-latency percentiles. ``bench.py``'s serve stage imports
-:func:`run_loadgen` to measure the curve at multiple batch windows; run
-standalone against a live ``python -m dotaclient_tpu.serve`` server:
+request-latency percentiles (closed loop; the curve is not measured on
+chip: ROADMAP.md S6/R6). Run standalone against a live ``python -m dotaclient_tpu.serve`` server:
 
     python scripts/serve_loadgen.py --addr 127.0.0.1:7788 \
         --clients 32 --requests 100
@@ -78,7 +77,7 @@ def run_loadgen(
     many sessions re-homed and how many requests missed their deadline.
     ``collect_samples`` additionally returns per-reply ``(t_end, latency,
     client)`` tuples (monotonic clock) so callers can split the latency
-    curve around a failover event (bench.py's blackout p99). ``think_s``
+    curve around a failover event. ``think_s``
     sleeps between a client's requests — a game's frame cadence, which
     stretches the run so a chaos plan can land faults mid-game."""
     from dotaclient_tpu.serve.client import ServeClient, ServeDeadlineError
@@ -199,9 +198,8 @@ def run_rehome_parity(
     replaying the first post-kill step from a ZEROED carry must disagree,
     so a carry the model ignores cannot fake a pass.
 
-    Returns the digest dict; bench.py's serve_fleet stage, the chaos
-    ``serve_failover`` scenario, ci_gate.sh, and the tier-2 router tests
-    all gate on it."""
+    Returns the digest dict; the chaos ``serve_failover`` scenario,
+    ci_gate.sh, and the tier-2 router tests all gate on it."""
     import jax
     import jax.numpy as jnp
 

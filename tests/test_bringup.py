@@ -7,7 +7,6 @@ All CPU, all well under a second: the chip side of these contracts is
 import importlib.util
 import os
 import re
-import sys
 
 import jax
 import pytest
@@ -86,17 +85,3 @@ class TestNoHiddenCpuPath:
         assert exc.value.code not in (0, None)
         assert "platform='cpu'" in str(exc.value.code)
         assert capsys.readouterr().out == ""   # no result line, nothing
-
-    def test_bench_refuses_the_cpu_before_building_anything(
-        self, monkeypatch
-    ):
-        bench = _load_root_script("bench")
-        # the learner may be loaded by an earlier test; main() must refuse
-        # before it would import it
-        monkeypatch.delitem(
-            sys.modules, "dotaclient_tpu.train.learner", raising=False
-        )
-        with pytest.raises(SystemExit) as exc:
-            bench.main()
-        assert "platform='cpu'" in str(exc.value.code)
-        assert "dotaclient_tpu.train.learner" not in sys.modules

@@ -4,8 +4,7 @@ trajectory ring, sharded snapshot/checkpoint paths.
 tests/conftest.py forces 8 host devices, so every test here runs on a real
 8-way mesh; the 1-device comparisons build a second mesh over
 ``jax.devices()[:1]`` in the same process (make_mesh's explicit-layout
-slicing) — exactly how bench.py's multichip parity probe and the
-single-chip degenerate case work.
+slicing) — exactly how the single-chip degenerate case works.
 """
 
 import dataclasses

@@ -8,8 +8,8 @@ counters riding the fleet snapshot frames (delta-merge across restarts,
 priority-aware leaf cut), the OutcomeAggregator's windowed curves +
 arming discipline, the outcome alert rules end to end through the
 engine, the --require-outcome schema tier, the JSONL sink's
-crash-mid-write torn-tail seal (bugfix sweep), the outcome_report and
-bench_trajectory consoles, and the alert-drift rule-key extension.
+crash-mid-write torn-tail seal (bugfix sweep), the outcome_report
+console, and the alert-drift rule-key extension.
 """
 
 import dataclasses
@@ -821,62 +821,6 @@ class TestJsonlTornTail:
         lines = telemetry.load_jsonl(path)
         assert len(lines) == 1
         assert json.loads(lines[0])["step"] == 5
-
-
-# ---------------------------------------------------------------------------
-# bench trajectory
-
-
-class TestBenchTrajectory:
-    def _write(self, tmp_path, name, body):
-        (tmp_path / name).write_text(json.dumps(body))
-
-    def test_trajectory_fingerprint_rules(self, tmp_path, capsys):
-        traj = _script_module("bench_trajectory")
-        host_a = {
-            "platform": "Linux-x", "device_kind": "cpu",
-            "device_count": 1, "forced_host": False, "jax": "0.9",
-            "libtpu": None,
-        }
-        host_b = {**host_a, "device_kind": "TPU v5 lite"}
-        # r01: the driver-wrapper shape, no fingerprint
-        self._write(
-            tmp_path, "BENCH_r01.json",
-            {"n": 1, "rc": 0, "cmd": "x", "tail": "",
-             "parsed": {"metric": "m", "value": 100.0, "unit": "f/s",
-                        "vs_baseline": 1.0}},
-        )
-        # r02/r03: flat shape, same host; r04: unlike host
-        for name, value, host in (
-            ("BENCH_r02.json", 110.0, host_a),
-            ("BENCH_r03.json", 121.0, host_a),
-            ("BENCH_r04.json", 9000.0, host_b),
-        ):
-            self._write(
-                tmp_path, name,
-                {"metric": "m", "value": value, "unit": "f/s",
-                 "vs_baseline": 1.0, "host": host,
-                 "stages": {"fleet_overhead": 0.01,
-                            "outcome_overhead": 0.005,
-                            "learner_dispatch_ema_s": 0.5}},
-            )
-        rc = traj.main(["--dir", str(tmp_path)])
-        out = capsys.readouterr().out
-        assert rc == 0
-        line = [
-            l for l in out.splitlines() if l.startswith("BENCH_TRAJECTORY ")
-        ][0]
-        t = json.loads(line[len("BENCH_TRAJECTORY "):])
-        assert len(t["records"]) == 4
-        # exactly ONE headline comparison: r02 → r03 (like hosts); the
-        # unknown-host r01 and the unlike-host r04 never compare
-        assert len(t["headline_comparisons"]) == 1
-        c = t["headline_comparisons"][0]
-        assert (c["from"], c["to"]) == ("BENCH_r02.json", "BENCH_r03.json")
-        assert c["headline_ratio"] == 1.1
-        # ratio stages tracked; absolute-time stages are NOT
-        assert "outcome_overhead" in t["ratio_stages"]
-        assert "learner_dispatch_ema_s" not in t["ratio_stages"]
 
 
 # ---------------------------------------------------------------------------

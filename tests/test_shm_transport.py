@@ -5,8 +5,8 @@ deferred-release zero-copy contract), the seqlock'd weights slab
 (latest-wins, torn-read retry surface), slot claim/release, and the
 Transport-protocol parity the learner relies on (consume_decoded feeding
 the buffer's staging lanes). Everything runs in-process — attach works
-within one process, and the cross-process path is exercised by bench.py's
-transport stage and the producer script."""
+within one process; the cross-process path has no test and no cell yet
+(ROADMAP.md S4/R1: 8 actor processes over this lane)."""
 
 import os
 

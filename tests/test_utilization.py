@@ -29,15 +29,6 @@ def _script_module(name):
     return mod
 
 
-@pytest.fixture(autouse=True)
-def _accountant_enabled():
-    """Every test starts and ends with the plane enabled (the always-on
-    default); a leaked False would silently disable other tests' pools."""
-    utilization.enabled = True
-    yield
-    utilization.enabled = True
-
-
 # ---------------------------------------------------------------------------
 # phase accounting arithmetic
 
@@ -188,19 +179,24 @@ class TestPoolUtilization:
 
 
 # ---------------------------------------------------------------------------
-# off-path cost: the faults.get() discipline
+# the factories: always on, keys eager
 
 
-class TestOffPathDiscipline:
-    def test_factories_return_none_but_keys_exist(self):
-        """Disabled, every factory still eager-creates its keys (the
-        schema tier holds for ANY JSONL) and returns None — a call site
-        pays exactly one `is not None` pointer test."""
-        utilization.enabled = False
+class TestFactories:
+    def test_enabled_factories_return_accountants(self):
+        """On a fresh registry every factory returns its accountant AND
+        has eager-created its keys at their neutral values (the schema
+        tier holds for ANY JSONL, before the first fold)."""
         reg = telemetry.Registry()
-        assert utilization.make_learner(reg) is None
-        assert utilization.make_actor(reg) is None
-        assert utilization.make_serve(reg) is None
+        assert isinstance(
+            utilization.make_learner(reg), utilization.LearnerUtilization
+        )
+        assert isinstance(
+            utilization.make_actor(reg), utilization.PoolUtilization
+        )
+        assert isinstance(
+            utilization.make_serve(reg), utilization.PoolUtilization
+        )
         snap = reg.snapshot()
         for key in (
             "util/armed", "util/duty_cycle", "util/steps_per_sec_ema",
@@ -209,15 +205,9 @@ class TestOffPathDiscipline:
         ):
             assert key in snap, key
         # the duty-cycle gauge reads its NEUTRAL 1.0, not a 0.0 that
-        # would trip learner_duty_cycle_low on a disabled run
+        # would trip learner_duty_cycle_low before the first fold
         assert snap["util/duty_cycle"] == 1.0
         assert snap["util/armed"] == 0.0
-
-    def test_enabled_factories_return_accountants(self):
-        reg = telemetry.Registry()
-        assert utilization.make_learner(reg) is not None
-        assert utilization.make_actor(reg) is not None
-        assert utilization.make_serve(reg) is not None
 
 
 # ---------------------------------------------------------------------------
