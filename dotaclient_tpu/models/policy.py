@@ -11,10 +11,10 @@ TPU-first design decisions (SURVEY.md §7 step 3):
 
 * One module serves both the actor's batch-step mode (``method="step"``) and
   the learner's teacher-forced sequence mode (``method="sequence"``), sharing
-  parameters — sequence mode drives the LSTM and the windowed transformer
-  with ``nn.scan`` (compiled ``lax.scan``; no Python loop under jit), and
-  hands the afmoe core (``models/afmoe.py``) the whole ``[B, T]`` chunk in
-  ONE pass, of which its step is the T = 1 case.
+  parameters — sequence mode runs the LSTM through ``models/lstm.py`` (one
+  ``lax.scan``, the weight gradient one product AFTER the backward loop) and
+  the windowed transformer under ``nn.scan``, and hands the afmoe core
+  (``models/afmoe.py``) the ``[B, T]`` chunk in ONE pass (its step: T = 1).
 * The carry, its reset and the chunk-start carry a learner is handed are
   the core's own: ``initial_state``, ``reset_carry`` and
   ``chunk_start_carry``. The LSTM's ``(h, c)`` and the transformer's window
@@ -280,24 +280,24 @@ class Policy(nn.Module):
                 logits, value = self._heads(ys, unit_emb)
             return logits, value, carry
 
-        def scan_step(cell, c, inp):
-            xt, reset_t = inp
-            c = mask_carry(c, 1.0 - reset_t)
-            return cell(c, xt)
+        if self.model.core == "lstm":
+            # the cell's mathematics (step mode calls the cell), backward by hand
+            from dotaclient_tpu.models.lstm import lstm_sequence
 
-        scan = nn.scan(
-            scan_step,
-            variable_broadcast="params",
-            # intermediates sown by the core (the MoE load-balancing loss,
-            # a scalar per step) stack along a leading time axis; empty for
-            # cores that sow nothing
-            variable_axes={"losses": 0},
-            split_rngs={"params": False},
-            in_axes=1,
-            out_axes=1,
-        )
-        with jax.named_scope("policy_core_scan"):
-            carry, ys = scan(self.core, carry, (x, resets))       # ys [B, T, H]
+            with jax.named_scope("policy_core_scan"):
+                core_params, dtype = self.core.variables["params"], _dtype(self.model.dtype)
+                carry, ys = lstm_sequence(core_params, carry, x, resets, dtype)
+        else:
+            def scan_step(cell, c, inp):
+                xt, reset_t = inp
+                return cell(mask_carry(c, 1.0 - reset_t), xt)
+
+            # what a core sows (the MoE load-balancing loss, a scalar per
+            # step) stacks along a leading time axis
+            scan = nn.scan(scan_step, variable_broadcast="params", variable_axes={"losses": 0},
+                           split_rngs={"params": False}, in_axes=1, out_axes=1)
+            with jax.named_scope("policy_core_scan"):
+                carry, ys = scan(self.core, carry, (x, resets))   # ys [B, T, H]
         with jax.named_scope("policy_heads"):
             logits, value = self._heads(ys, unit_emb)
         return logits, value, carry

@@ -2031,7 +2031,7 @@ def main(argv=None) -> Dict[str, float]:
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--core", type=str, default=None,
                    choices=("lstm", "transformer"),
-                   help="policy core: nn.scan LSTM(128) (reference parity, "
+                   help="policy core: scanned LSTM(128) (reference parity, "
                    "default) or the GTrXL-gated windowed-attention "
                    "transformer (scale-out option)")
     p.add_argument("--moe-experts", type=int, default=None,

@@ -467,6 +467,33 @@ class TestFusedScopes:
         for phase in PHASE_SCOPES:
             assert sum(phase in _scopes.segments(n) for n in names) > 500
 
+    def test_weight_gradient_products_stay_under_the_core_and_the_update(
+        self, lowered_and_hlo
+    ):
+        """ISSUE 29: the LSTM's backward is written by hand
+        (`models/lstm.py`), and a `custom_vjp`'s backward is traced apart
+        from its forward. Its two weight-gradient products, the whole
+        `[T x B]` contraction, must still carry `policy_core_scan` and
+        `phase_update` as segments of their `op_name`: that is where
+        `policy_core_share`, `policy_core_roofline` and
+        `update_device_share` look for them. And nothing of the core runs
+        under the loop's name but the recurrence's one product a step."""
+        from benchmark.readers import _scopes
+
+        _, hlo = lowered_and_hlo
+        rows = re.findall(
+            r'^\s*(?:ROOT )?%[\w.\-]+ = (\S+) dot\(.*op_name="([^"]*)"', hlo, re.M
+        )
+        products = [
+            (shape, name) for shape, name in rows if name.endswith("->hg/dot_general")
+        ]
+        hidden = tiny_cfg().model.hidden_dim
+        assert len(products) == 2, products
+        for shape, name in products:
+            assert shape.startswith(f"f32[{hidden},{4 * hidden}]"), shape
+            assert {"policy_core_scan", "phase_update"} <= set(_scopes.segments(name)), name
+            assert "while" not in _scopes.segments(name), name
+
     def test_sampling_and_loss_look_nothing_up_by_gather(self, lowered_and_hlo):
         """ISSUE 27: the action distribution looks a chosen action's
         log-probability up by compare-select-reduce, so under

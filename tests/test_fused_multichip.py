@@ -135,7 +135,8 @@ class TestLaneShardedCompile:
         collectives = [
             (kind, op_name)
             for kind, op_name in re.findall(
-                r"= \S+ (all-gather|all-reduce|all-to-all|reduce-scatter|"
+                # `.*?`: a combined all-reduce's result is a tuple, with spaces
+                r"= .*? (all-gather|all-reduce|all-to-all|reduce-scatter|"
                 r"collective-permute|collective-broadcast)(?:-start)?\("
                 r".*?op_name=\"([^\"]*)\"",
                 hlo,
@@ -148,6 +149,16 @@ class TestLaneShardedCompile:
         in_rollout = [c for c in collectives if "phase_rollout" in c[1]]
         assert not in_rollout, in_rollout
         assert not [c for c in collectives if c[0] == "all-gather"], collectives
+        # The update reduces three times, each ONCE a minibatch: the two
+        # scalar sums of the loss and one all-reduce of the whole gradient.
+        # Until PR 29 this backend had a fourth INSIDE the LSTM's backward
+        # loop (each step's weight gradient, reduced before it was added to
+        # the accumulator); the v5e's compiler had six either way
+        # (compile_for_topology.py, PERF.md section 6). `models/lstm.py`
+        # contracts over the lane-sharded batch axis once, after the loop.
+        in_update = [c for c in collectives if "phase_update" in c[1]]
+        assert len(in_update) == len(collectives) == 3, collectives
+        assert not [c for c in in_update if "/while/" in c[1]], in_update
 
     def test_degenerate_games_fall_back_to_replicated(self):
         """4 games on an 8-way mesh cannot lane-shard: the layout must
