@@ -1,0 +1,9 @@
+"""``loop_attn_device_share``: share of device busy time in operations written under
+``core_loop`` and ``core_attn_full`` (``models/looplm.py`` around ``models/afmoe.py``'s
+attention), forward and transposed, mean over chips; 0 where a program has no such scopes."""
+
+from benchmark.readers import _scopes
+
+
+def read(record):
+    return _scopes.share(record, lambda op: _scopes.under(op, "core_loop", "core_attn_full"))

@@ -52,7 +52,7 @@ class ModelConfig:
     hidden_dim: int = 128        # LSTM hidden size — parity with reference
     n_hero_ids: int = 32         # hero-embedding vocabulary (multi-hero pools)
     hero_embed_dim: int = 16
-    core: str = "lstm"           # "lstm" | "transformer" | "afmoe"
+    core: str = "lstm"           # "lstm" | "transformer" | "afmoe" | "looplm" (RING_CORES: carry_is_rings)
     # Transformer-core options (scale-out path, SURVEY.md §7 step 8).
     n_layers: int = 2
     n_heads: int = 4
@@ -85,6 +85,31 @@ class ModelConfig:
     rope_theta: float = 10000.0
     rms_norm_eps: float = 1e-5
     mup_enabled: bool = True     # stream input scaled by sqrt(hidden_dim)
+    # What an afmoe layer does besides, each switched off by a core that
+    # lacks it ("looplm", models/looplm.py: a stack of these layers with
+    # moe_experts 0, so every FFN is dense, and global_attn_every 1, so
+    # every layer attends fully):
+    attn_qk_norm: bool = True    # RMSNorm of each head's query and key
+    attn_out_gate: bool = True   # attention output times sigmoid(a Wgate)
+    rope_full_layers: bool = False  # RoPE on full-attention layers too
+    # "looplm" core: the stack of n_layers runs loop_steps times over a
+    # position with ONE set of weights; every (loop step, layer) pair keeps
+    # its own ring of full_context rows, and a gate after each loop step
+    # gives the probability of stopping there (train/ppo.exit_weighted_loss)
+    loop_steps: int = 1
+
+    @property
+    def carry_is_rings(self) -> bool:
+        """The core's carry is per-lane attention caches (``models/afmoe.py``
+        ``initial_state``: ``{"pos", "cursor", "kv"}``, megabytes a lane)
+        that stay on the chip: a reset moves a counter, a chunk start copies
+        nothing, and only the fused trainer and the serve engine's resident
+        carries run it (``models/policy.py require_carry_stays``)."""
+        return self.core in RING_CORES
+
+
+# Cores whose carry is ring caches (ModelConfig.carry_is_rings).
+RING_CORES = ("afmoe", "looplm")
 
 
 # Valid PPOConfig.adv_norm values — the single source of truth for the
@@ -117,6 +142,9 @@ class PPOConfig:
     # afmoe core: step of the selection bias's balancing update per optimizer
     # step (train/ppo._balance_select_bias); 0 leaves the bias where it is
     select_bias_rate: float = 0.001
+    # looped core (model.loop_steps > 1): weight of the exit distribution's
+    # entropy bonus in the exit-weighted loss (train/ppo.exit_weighted_loss)
+    exit_entropy_coef: float = 0.05
     # Advantage normalization. "batch" (the standard per-batch whitening) is
     # right for training from scratch, but it amplifies GAE noise to unit
     # scale when the true advantage signal is ~zero — measured to destroy a

@@ -96,6 +96,10 @@ DYNAMIC_KEY_EXPANSIONS: Dict[Tuple[str, str], Tuple[str, ...]] = {
     # indices are runtime values — representative members; documented as
     # the `router/backend/<i>/sessions` wildcard row
     ("router/backend/", "/sessions"): ("0", "1"),
+    # train/learner.py: a looped core's exit mass per loop step
+    # (model.loop_steps is a runtime value: representative members;
+    # documented as the `looplm/exit_mass/<r>` wildcard row)
+    ("looplm/exit_mass/", ""): ("0", "1"),
     # Outcome attribution plane (ISSUE 15; dotaclient_tpu/outcome/).
     # Keep the value tuples in sync with outcome.records BUCKETS / SIDES
     # / REWARD_TERMS / N_LEN_BUCKETS and the OUTCOME_KEYS schema tier.
@@ -137,7 +141,7 @@ _DOC_KEY_RE = re.compile(
 KEY_PREFIXES = (
     "actor/", "advantage/", "alerts/", "buffer/", "checkpoint/",
     "compile/", "faults/", "fleet/", "fused/", "health/", "league/",
-    "learner/", "mem/", "mesh/", "moe/", "outcome/", "router/", "serve/",
+    "learner/", "looplm/", "mem/", "mesh/", "moe/", "outcome/", "router/", "serve/",
     "shm/", "snapshot/", "span/", "trace/", "transport/", "util/",
 )
 # single-line inline code only: multi-line matches would mispair across

@@ -25,7 +25,10 @@ expert in the rest. One layer, on the stream ``h``, the query at position
            f = SwiGLU_shared(m) + sum over the chosen e of w_e SwiGLU_e(m)
   h = h + RMSNorm(f);   after the last layer y = RMSNorm(h)
 
-The stream enters scaled by sqrt(hidden_dim) (``mup_enabled``).
+The stream enters scaled by sqrt(hidden_dim) (``mup_enabled``). A core that is
+a stack of these layers with less in each (``models/looplm.py``) says so in its
+configuration: ``attn_qk_norm``, ``attn_out_gate``, ``rope_full_layers`` (RoPE on
+full layers too), ``moe_experts`` 0 (every FFN dense), ``loop_steps`` rings a layer.
 
 **One function for a step and for a chunk.** ``AfmoeCore(carry, x, resets)``
 takes ``x [B, T, H]``: the learner's pass is ONE pass over the chunk (T
@@ -71,14 +74,11 @@ training lanes that watch like game states choose like experts, so a chip's
 share of the pairs is a draw of the weights (PERF.md section 6). With
 ``held_experts`` 0 or ``moe_experts`` the layer is the whole layer.
 
-**The selection bias** is the one parameter no gradient reaches. The layer
-sows each expert's tokens minus the mean (``select_bias_err``), and the
-optimizer step moves the bias against it (``train/ppo._balance_select_bias``,
-``ppo.select_bias_rate``): the published balancing rule.
-
-Scopes inside ``policy_core``: ``core_attn_window``, ``core_attn_full``,
-``core_cache_write``, ``core_router``, ``core_experts_routed``,
-``core_expert_shared``, ``core_dense_ffn``.
+**The selection bias** is the one parameter no gradient reaches: the layer
+sows each expert's tokens minus the mean (``select_bias_err``) and the optimizer
+step moves the bias against it (``train/ppo._balance_select_bias``). Scopes inside
+``policy_core``: ``core_attn_window``, ``core_attn_full``, ``core_cache_write``,
+``core_router``, ``core_experts_routed``, ``core_expert_shared``, ``core_dense_ffn``.
 """
 
 from __future__ import annotations
@@ -110,7 +110,7 @@ def layer_is_full(cfg: ModelConfig, layer: int) -> bool:
 
 
 def layer_is_dense(cfg: ModelConfig, layer: int) -> bool:
-    return layer < cfg.n_dense_layers
+    return cfg.moe_experts == 0 or layer < cfg.n_dense_layers
 
 
 def ring_len(cfg: ModelConfig, layer: int) -> int:
@@ -130,7 +130,7 @@ def _cursor_modulus(cfg: ModelConfig) -> int:
 
 def carry_bytes_per_lane(cfg: ModelConfig) -> int:
     width = 2 * cfg.n_kv_heads * cfg.head_dim * _dtype(cfg.dtype).dtype.itemsize
-    return 8 + sum(ring_len(cfg, l) * width for l in range(cfg.n_layers))
+    return 8 + cfg.loop_steps * sum(ring_len(cfg, l) * width for l in range(cfg.n_layers))
 
 
 def check_config(cfg: ModelConfig) -> None:
@@ -139,7 +139,7 @@ def check_config(cfg: ModelConfig) -> None:
         raise ValueError(f"n_heads {cfg.n_heads} not a multiple of n_kv_heads {cfg.n_kv_heads}")
     if cfg.head_dim % 2:
         raise ValueError(f"head_dim {cfg.head_dim} must be even for RoPE")
-    if cfg.n_dense_layers < cfg.n_layers and not (
+    if cfg.moe_experts and cfg.n_dense_layers < cfg.n_layers and not (
         0 < cfg.experts_per_token <= cfg.moe_experts
         and 0 <= offset and offset + held <= cfg.moe_experts
     ):
@@ -154,12 +154,12 @@ def require_episode_fits(cfg: ModelConfig, episode_steps: int, rollout_len: int)
     chunk a learner is handed the start of, fit its ring."""
     if rollout_len > cfg.rollout_chunk:
         raise ValueError(
-            f"core 'afmoe': ppo.rollout_len {rollout_len} exceeds model.rollout_chunk "
+            f"core {cfg.core!r}: ppo.rollout_len {rollout_len} exceeds model.rollout_chunk "
             f"{cfg.rollout_chunk}, the slack its window rings keep for one chunk"
         )
     if episode_steps + cfg.rollout_chunk > cfg.full_context:
         raise ValueError(
-            f"core 'afmoe': an episode of {episode_steps} steps and a chunk of "
+            f"core {cfg.core!r}: an episode of {episode_steps} steps and a chunk of "
             f"{cfg.rollout_chunk} do not fit model.full_context {cfg.full_context}"
         )
 
@@ -176,7 +176,7 @@ def initial_state(cfg: ModelConfig, batch_size: int) -> Dict[str, Any]:
     return {
         "pos": jnp.zeros((batch_size,), jnp.int32),
         "cursor": jnp.zeros((batch_size,), jnp.int32),
-        "kv": tuple((ring(l), ring(l)) for l in range(cfg.n_layers)),
+        "kv": tuple((ring(l), ring(l)) for _ in range(cfg.loop_steps) for l in range(cfg.n_layers)),
     }
 
 
@@ -294,10 +294,10 @@ class Attention(nn.Module):
             q = _dense(cfg, nh * D, "wq")(a).reshape(B, T, kv, G, D)
             k = _dense(cfg, kv * D, "wk")(a).reshape(B, T, kv, D)
             v = _dense(cfg, kv * D, "wv")(a).reshape(B, T, kv, D)
-            gate = _dense(cfg, nh * D, "wgate")(a)
-            q = RMSNorm(cfg, name="q_norm")(q)
-            k = RMSNorm(cfg, name="k_norm")(k)
-            if not self.full:
+            gate = _dense(cfg, nh * D, "wgate")(a) if cfg.attn_out_gate else None
+            q = RMSNorm(cfg, name="q_norm")(q) if cfg.attn_qk_norm else q.astype(jnp.float32)
+            k = RMSNorm(cfg, name="k_norm")(k) if cfg.attn_qk_norm else k.astype(jnp.float32)
+            if cfg.rope_full_layers or not self.full:
                 q, k = rope(q, p, cfg.rope_theta), rope(k, p, cfg.rope_theta)
             q = (q / math.sqrt(D)).astype(dtype)
             k = k.astype(dtype)
@@ -311,8 +311,8 @@ class Attention(nn.Module):
                 see_ring &= (t[None, :, None] + 1 + age[:, None, :]) < W
                 see_chunk &= ((t[:, None] - t[None, :]) < W)[None]
 
-            out = _attend(q, k, v.astype(dtype), ring_k, ring_v, see_ring, see_chunk)
-            out = out.reshape(B, T, nh * D) * nn.sigmoid(gate.astype(jnp.float32))
+            out = _attend(q, k, v.astype(dtype), ring_k, ring_v, see_ring, see_chunk) if G > 1 or T >= _MXU_ROWS else _attend_few_rows(q, k, v.astype(dtype), ring_k, ring_v, see_ring, see_chunk)
+            out = out.reshape(B, T, nh * D) * nn.sigmoid(gate.astype(jnp.float32)) if cfg.attn_out_gate else out.reshape(B, T, nh * D)
             attn = _dense(cfg, cfg.hidden_dim, "wo")(out.astype(dtype))
         with jax.named_scope("core_cache_write"):
             rows = jnp.arange(B)[:, None]
@@ -480,3 +480,47 @@ class AfmoeCore(nn.Module):
             "kv": tuple(rings),
         }
         return carry, y
+
+
+# -- a step of a layer without grouped queries -------------------------------------
+
+_MXU_ROWS = 8
+
+
+@jax.checkpoint
+def _attend_few_rows(q, k, v, ring_k, ring_v, see_ring, see_chunk):
+    """``_attend`` where a KV head serves ONE query head (G = 1) and the chunk
+    is a few steps: the rollout's T = 1 of a layer with as many KV heads as
+    query heads. Each of ``_attend``'s products against a ring then has T < 8
+    rows a head, and the TPU compiler does not multiply so few on the MXU: it
+    widened the ring to float32, copied it into another layout and reduced it
+    on the vector unit, every step, 77% of the device's time at the looped
+    cell's widths (my chip run, PR 30; PERF.md section 6). Here every head's
+    query is a column of ONE block-diagonal ``[kv D, kv]`` matrix, so the
+    scores of all heads are one product with the ring as it lies, ``[R, kv D]``
+    rows, and the values one product ``[kv, R] x [R, kv D]`` of which head k
+    keeps its own D columns: kv times the operations a head needs, which are
+    a hundredth of what the ring's bytes cost. Same softmax as ``_attend``."""
+    B, T, kv, _, D = q.shape
+    R = ring_k.shape[1]
+    heads = jnp.eye(kv, dtype=q.dtype)
+    q_blocks = (q[:, :, :, 0, :, None] * heads[:, None, :]).reshape(B, T, kv * D, kv)
+    s_ring = jnp.einsum(
+        "brc,btck->bktr", ring_k.reshape(B, R, kv * D), q_blocks, preferred_element_type=jnp.float32
+    )
+    s_own = jnp.einsum("btkgd,bjkd->bkgtj", q, k, preferred_element_type=jnp.float32)[:, :, 0]
+    s_ring = jnp.where(see_ring[:, None], s_ring, _NEG)
+    s_own = jnp.where(see_chunk[:, None], s_own, _NEG)
+    top = jnp.maximum(s_ring.max(-1), s_own.max(-1))[..., None]
+    e_ring, e_own = jnp.exp(s_ring - top), jnp.exp(s_own - top)
+    total = e_ring.sum(-1) + e_own.sum(-1)                               # [B, kv, T]
+    # (a batched matmul and not an einsum that moves axes: XLA:CPU has no
+    # bfloat16 kernel for the latter, and the rehearsals run there)
+    every = jnp.matmul(
+        e_ring.astype(q.dtype).reshape(B, kv * T, R), ring_v.reshape(B, R, kv * D),
+        preferred_element_type=jnp.float32,
+    ).reshape(B, kv, T, kv, D)
+    out = jnp.einsum("bktkd->btkd", every) + jnp.einsum(
+        "bktj,bjkd->btkd", e_own.astype(q.dtype), v, preferred_element_type=jnp.float32
+    )
+    return (out / jnp.moveaxis(total, 2, 1)[..., None])[:, :, :, None]

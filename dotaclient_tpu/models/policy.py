@@ -13,16 +13,16 @@ TPU-first design decisions (SURVEY.md §7 step 3):
   the learner's teacher-forced sequence mode (``method="sequence"``), sharing
   parameters — sequence mode runs the LSTM through ``models/lstm.py`` (one
   ``lax.scan``, the weight gradient one product AFTER the backward loop) and
-  the windowed transformer under ``nn.scan``, and hands the afmoe core
-  (``models/afmoe.py``) the ``[B, T]`` chunk in ONE pass (its step: T = 1).
+  the windowed transformer under ``nn.scan``, and hands a ring-cache core
+  (``models/afmoe.py``, ``models/looplm.py``) the chunk in ONE pass (its step: T = 1).
 * The carry, its reset and the chunk-start carry a learner is handed are
   the core's own: ``initial_state``, ``reset_carry`` and
   ``chunk_start_carry``. The LSTM's ``(h, c)`` and the transformer's window
   are rows that a reset zeroes (``mask_carry``) and a chunk start copies in
-  float32; the afmoe core's carry is per-lane attention caches of two sizes
-  (22 MiB a lane at Trinity-Mini's widths), which a reset never touches (a
-  position counter returns to 0) and a chunk start never copies (the start's
-  counters beside the end's rings).
+  float32; where ``ModelConfig.carry_is_rings`` the carry is per-lane attention
+  caches (22 MiB a lane at Trinity-Mini's widths, 403 MB at Ouro's), which a
+  reset never touches (a position counter returns to 0) and a chunk start
+  never copies (the start's counters beside the end's rings).
 * The trunk and heads are written shape-polymorphically (Dense/einsum on the
   last axis) so the same code handles ``[B, ...]`` and ``[B, T, ...]``.
 * Compute dtype is configurable bfloat16 with float32 params; logits are cast
@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from dotaclient_tpu.config import ActionSpec, ModelConfig, ObsSpec
 
 # Recurrent carry: (h, c) for the LSTM core; (valid, KV caches) for the
-# transformer core; {"pos", "cursor", "kv"} for the afmoe core. Always a
+# transformer core; {"pos", "cursor", "kv"} for a ring-cache core. Always a
 # pytree whose leaves have leading batch axis — reset it with
 # Policy.reset_carry, never by unpacking it.
 Carry = Any
@@ -122,10 +122,10 @@ class Policy(nn.Module):
             from dotaclient_tpu.models.transformer import WindowedTransformerCore
 
             self.core = WindowedTransformerCore(cfg)
-        elif cfg.core == "afmoe":
-            from dotaclient_tpu.models.afmoe import AfmoeCore
+        elif cfg.carry_is_rings:
+            from dotaclient_tpu.models import afmoe, looplm
 
-            self.core = AfmoeCore(cfg)
+            self.core = {"afmoe": afmoe.AfmoeCore, "looplm": looplm.LoopLMCore}[cfg.core](cfg)
         else:
             raise ValueError(f"unknown core {cfg.core!r}")
         hs = self.action_spec.head_sizes
@@ -186,7 +186,7 @@ class Policy(nn.Module):
     # -- public modes ------------------------------------------------------
 
     def initial_state(self, batch_size: int) -> Carry:
-        if self.model.core == "afmoe":
+        if self.model.carry_is_rings:
             from dotaclient_tpu.models import afmoe
 
             return afmoe.initial_state(self.model, batch_size)
@@ -203,9 +203,9 @@ class Policy(nn.Module):
     def reset_carry(self, carry: Carry, keep: jnp.ndarray) -> Carry:
         """Episode-boundary reset of the rows where ``keep`` ([B]) is 0, as
         the core defines it: the LSTM and the windowed transformer zero the
-        row (``mask_carry``); the afmoe core returns the row's position to 0
-        and touches no cache."""
-        if self.model.core == "afmoe":
+        row (``mask_carry``); a ring-cache core returns the row's position
+        to 0 and touches no cache."""
+        if self.model.carry_is_rings:
             from dotaclient_tpu.models import afmoe
 
             return afmoe.reset(carry, keep)
@@ -215,9 +215,9 @@ class Policy(nn.Module):
         """What a learner is handed as a chunk's ``carry0``, given the carry
         before the chunk's first step and after its last: the start in
         float32 for the cores whose carry is rewritten every step, and for
-        the afmoe core the start's counters beside the END's rings
+        a ring-cache core the start's counters beside the END's rings
         (``afmoe.chunk_start_view``: no copy of a cache, no widening)."""
-        if self.model.core == "afmoe":
+        if self.model.carry_is_rings:
             from dotaclient_tpu.models import afmoe
 
             return afmoe.chunk_start_view(start, end)
@@ -230,10 +230,10 @@ class Policy(nn.Module):
         with jax.named_scope("policy_trunk"):
             x, unit_emb = self._trunk(obs)
         with jax.named_scope("policy_core"):
-            if self.model.core == "afmoe":
-                # the chunk function at T = 1
+            if self.model.carry_is_rings:
+                # the chunk function at T = 1 (of a looped core's [R, B, 1, H] the last loop step)
                 carry, y = self.core(carry, x[:, None])
-                y = y[:, 0]
+                y = y[:, 0] if y.ndim == 3 else y[-1, :, 0]
             else:
                 carry, y = self.core(carry, x)
         with jax.named_scope("policy_heads"):
@@ -271,9 +271,9 @@ class Policy(nn.Module):
                 axis=1,
             )
 
-        if self.model.core == "afmoe":
-            # one pass over the chunk: T queries against the carried keys
-            # and the chunk's own, the resets as a segment mask
+        if self.model.carry_is_rings:
+            # one pass over the chunk: T queries against the carried keys and the chunk's own, the resets a segment
+            # mask. A looped core hands back every loop step, so logits and value lead with [R] (its gates are sown)
             with jax.named_scope("policy_core"):
                 carry, ys = self.core(carry, x, resets)
             with jax.named_scope("policy_heads"):
@@ -310,13 +310,13 @@ class Policy(nn.Module):
 def require_carry_stays(model: ModelConfig, where: str) -> None:
     """Raise where ``where`` would ship a carry with every chunk or reply and
     the core's carry is attention caches: the LSTM's and the windowed
-    transformer's rows travel, the afmoe core's megabytes a lane stay on
+    transformer's rows travel, a ring-cache core's megabytes a lane stay on
     the chip (the fused trainer, the serve engine's resident carries)."""
-    if model.core == "afmoe":
+    if model.carry_is_rings:
         from dotaclient_tpu.models.afmoe import carry_bytes_per_lane
 
         raise ValueError(
-            f"core 'afmoe' carries {carry_bytes_per_lane(model):,} bytes of "
+            f"core {model.core!r} carries {carry_bytes_per_lane(model):,} bytes of "
             f"attention caches a lane: it trains in actor mode 'fused' and "
             f"serves from the engine's resident carries, not in {where}, "
             f"which would copy that carry with every chunk or reply"
@@ -325,23 +325,23 @@ def require_carry_stays(model: ModelConfig, where: str) -> None:
 
 def require_episode_fits(model: ModelConfig, episode_steps: int, rollout_len: int) -> None:
     """Raise where the core's carry cannot hold an episode of
-    ``episode_steps`` observations rolled out ``rollout_len`` at a time (the
-    afmoe core's rings: ``afmoe.require_episode_fits``)."""
-    if model.core == "afmoe":
+    ``episode_steps`` observations rolled out ``rollout_len`` at a time (a
+    ring-cache core's rings: ``afmoe.require_episode_fits``)."""
+    if model.carry_is_rings:
         from dotaclient_tpu.models import afmoe
 
         afmoe.require_episode_fits(model, episode_steps, rollout_len)
 
 
 def make_policy(model: ModelConfig, obs_spec: ObsSpec, action_spec: ActionSpec) -> Policy:
-    if model.moe_experts > 0 and model.core not in ("transformer", "afmoe"):
-        # only these cores route an MoE FFN; silently training a
-        # dense LSTM under an "8-expert" label would mislabel every result
-        raise ValueError(
-            f"moe_experts={model.moe_experts} requires core='transformer' "
-            f"or 'afmoe' (got core={model.core!r}); the LSTM core has no "
-            f"FFN to route"
-        )
+    # Only some cores route an MoE FFN, and the rest refuse ``moe_experts``:
+    # ``require_routed_ffn``, at the end of this file, which the serving
+    # plane's policy (``serve/policy_path.py``) asks too. (This function keeps
+    # its ten lines: the line of ``init_params``' ``policy.init`` below is in
+    # the traced frames of operations first traced during initialisation, and
+    # so in the fused programs' compile-cache keys: PERF.md section 6, PR 30.)
+    require_routed_ffn(model)
+
     return Policy(model=model, obs_spec=obs_spec, action_spec=action_spec)
 
 
@@ -376,3 +376,14 @@ def dummy_obs_batch(
         "mask_cast_target": jnp.ones(lead + (action_spec.max_units,), bool),
         "mask_ability": jnp.ones(lead + (action_spec.max_abilities,), bool),
     }
+
+
+def require_routed_ffn(model: ModelConfig) -> None:
+    """Raise where ``moe_experts`` asks for a routed FFN of a core that has
+    none: silently training a dense core under an "8-expert" label would
+    mislabel every result."""
+    if model.moe_experts > 0 and model.core not in ("transformer", "afmoe"):
+        raise ValueError(
+            f"moe_experts={model.moe_experts} requires core='transformer' "
+            f"or 'afmoe' (got core={model.core!r}), the cores that route an FFN"
+        )

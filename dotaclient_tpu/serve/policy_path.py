@@ -24,7 +24,7 @@ from __future__ import annotations
 from typing import Any, Dict, Tuple
 
 from dotaclient_tpu.config import RunConfig
-from dotaclient_tpu.models.policy import Policy
+from dotaclient_tpu.models.policy import Policy, require_routed_ffn
 
 # Top-level param-tree entries that exist only for training. The slice is
 # name-based (not shape-based) so a future training-only head lands here
@@ -35,13 +35,7 @@ TRAIN_ONLY_PARAM_KEYS = ("head_value",)
 def make_inference_policy(config: RunConfig) -> Policy:
     """The serving-plane policy module: identical architecture, no value
     head (``value_head=False``), so it applies the sliced tree directly."""
-    if config.model.moe_experts > 0 and config.model.core not in (
-        "transformer", "afmoe"
-    ):
-        raise ValueError(
-            f"moe_experts={config.model.moe_experts} requires "
-            f"core='transformer' or 'afmoe' (got core={config.model.core!r})"
-        )
+    require_routed_ffn(config.model)
     return Policy(
         model=config.model,
         obs_spec=config.obs,

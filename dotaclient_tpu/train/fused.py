@@ -9,8 +9,8 @@ reset), then the PPO update on the chunk it just produced — is one jitted
 call (``FusedStep``). One dispatch per optimizer step, zero host
 round-trips, nothing staged through the trajectory buffer. Where the train
 state and the actor state together hold more than
-``DONATE_ABOVE_BYTES`` on one device (the afmoe core's gigabytes
-of attention caches beside 400 M parameters) the call DONATES both: they
+``DONATE_ABOVE_BYTES`` on one device (a core whose carry is ring caches,
+``ModelConfig.carry_is_rings``: gigabytes beside the parameters) the call DONATES both: they
 update in place in HBM, so states that do not fit the chip twice run through
 it, and what reads the state across a dispatch (the league's snapshot, a
 weights publish, a checkpoint) copies on the device BEFORE the next enqueue.
@@ -308,7 +308,7 @@ class FusedStep:
     benchmark's small and wide LSTM cells, bound 10%: my chip runs, PR 26).
 
     **With donation** (states that are most of the chip and do not fit
-    twice: the afmoe core's attention caches beside 400 M parameters and
+    twice: a ring-cache core's attention caches beside its parameters and
     their Adam moments) both update in place in HBM, and a dispatch in
     flight holds no second copy of either. A donated buffer cannot also be read as another
     argument, so the live opponent is a program of its own with two

@@ -1472,7 +1472,7 @@ class Learner:
 
         def _finish_metrics(host) -> None:
             scalars = {k: float(v) for k, v in host["m"].items()}   # host-sync-ok: snapshot thread, fetched host arrays
-            self._fold_moe_counters(scalars)
+            self._fold_core_counters(scalars)
             if stats_source is not None:
                 # host-only read: every stat drain submitted up to this
                 # boundary was folded by the engine BEFORE this job ran
@@ -1498,22 +1498,22 @@ class Learner:
 
         return _finish_metrics
 
-    def _fold_moe_counters(self, scalars: Dict[str, float]) -> None:
-        """A logged step's routed-expert counts (``train/ppo._moe_counters``:
-        present only for a core that holds part of a routed layer) into the
-        registry, at the log cadence: the logged step's pairs and load as
-        gauges, the pairs its weights left out as a counter that a correct
-        layer never moves."""
-        if "moe_local_assignments" not in scalars:
-            return
+    def _fold_core_counters(self, scalars: Dict[str, float]) -> None:
+        """A logged step's counts from a core that keeps some, into the registry at the log cadence:
+        a routed layer's pairs and load as gauges and the pairs its weights left out as a counter that
+        never moves (``train/ppo._moe_counters``); a looped core's exit statistics as gauges and its
+        passes over the stack as a counter, R a logged forward pass (``train/ppo.exit_weighted_loss``)."""
         tel = self.telemetry
-        tel.gauge("moe/local_assignments").set(scalars["moe_local_assignments"])
-        tel.gauge("moe/max_over_mean_expert_load").set(
-            scalars["moe_max_over_mean_load"]
-        )
-        tel.counter("moe/dropped_assignments").inc(
-            scalars["moe_dropped_assignments"]
-        )
+        if "moe_local_assignments" in scalars:
+            tel.gauge("moe/local_assignments").set(scalars["moe_local_assignments"])
+            tel.gauge("moe/max_over_mean_expert_load").set(scalars["moe_max_over_mean_load"])
+            tel.counter("moe/dropped_assignments").inc(scalars["moe_dropped_assignments"])
+        if "looplm_loop_passes" in scalars:
+            tel.counter("looplm/loop_passes_total").inc(scalars["looplm_loop_passes"])
+            tel.gauge("looplm/expected_exit_step").set(scalars["looplm_expected_exit_step"])
+            tel.gauge("looplm/exit_entropy").set(scalars["looplm_exit_entropy"])
+            for r in range(self.config.model.loop_steps):
+                tel.gauge(f"looplm/exit_mass/{r}").set(scalars[f"looplm_exit_mass_{r}"])
 
     def _publish_pipeline_gauges(self) -> None:
         """Refresh the cross-stage gauges at a log boundary: actor weight
@@ -1695,7 +1695,7 @@ class Learner:
                                 scalars.update(self.device_actor.drain_stats())
                             elif self.pool is not None:
                                 scalars.update(self.pool.drain_stats())
-                        self._fold_moe_counters(scalars)
+                        self._fold_core_counters(scalars)
                         # the fetch blocked on the dispatched step — overlap
                         # window for prefetch accounting closes here
                         self._dispatch_inflight = False

@@ -196,12 +196,12 @@ def ppo_loss(
     regularizer: one extra frozen-policy forward over the batch, exact
     conditional KL(π_θ ‖ π_anchor) per frame (PPOConfig.anchor_kl_coef).
 
-    A batch carrying precomputed ``advantages``/``returns`` leaves (the
-    one-pass advantage plane, ``train/advantage.py``) skips the in-step
-    estimator entirely and shortens the forward to the T transition steps
-    — the bootstrap slot existed solely to seed the estimator, so every
-    forward AND backward in the epoch drops one timestep.
+    A batch carrying precomputed ``advantages``/``returns`` leaves (the one-pass advantage plane,
+    ``train/advantage.py``) skips the in-step estimator and shortens the forward to the T transition
+    steps: the bootstrap slot only seeded the estimator. A looped core's loss is its own (below).
     """
+    if policy.model.loop_steps > 1:
+        return exit_weighted_loss(policy, params, batch, cfg, step, anchor_params)
     obs = batch["obs"]
     T = batch["rewards"].shape[1]
     valid = batch["valid"].astype(jnp.float32)
@@ -726,3 +726,135 @@ def example_batch(config: RunConfig, batch: int, as_struct: bool = False) -> Bat
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), out
         )
     return out
+
+
+def exit_weighted_loss(
+    policy: Policy,
+    params: Any,
+    batch: Batch,
+    cfg: PPOConfig,
+    step: Any = None,
+    anchor_params: Any = None,
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """``ppo_loss`` for a core that runs its stack R times and may leave
+    after any of them (``models/looplm.py``): ``Policy.sequence`` hands back
+    logits and values of EVERY loop step (a leading axis R) and the core
+    sows its exit gates' logits. Per valid frame, with ``l_r`` every term
+    of ``ppo_loss`` that reads the policy's outputs (clipped surrogate,
+    entropy bonus, value loss, with their coefficients) evaluated from loop
+    step r's heads against the SAME behaviour log-probability, advantage and
+    return, and ``p`` the exit distribution (``looplm.exit_distribution``):
+
+      L = mean over valid frames of [ sum_r p_r l_r - exit_entropy_coef H(p) ]
+
+    Advantages and returns come from the LAST loop step's values, as the
+    rollout's action, log-probability and value do. A policy whose core is
+    not looped is one exit of mass 1, and the loss is ``ppo_loss``'s bit for
+    bit (a test holds that). The anchor KL, the KL-adaptive learning rate
+    and precomputed advantages read one set of head outputs a frame and are
+    refused here; a looped core trains in fused mode only, which uses none.
+    """
+    from dotaclient_tpu.models.looplm import exit_distribution
+
+    if cfg.anchor_kl_coef > 0 or cfg.kl_target > 0 or "advantages" in batch:
+        raise ValueError(
+            f"core {policy.model.core!r} hands the loss {policy.model.loop_steps} sets of "
+            "head outputs a frame: ppo.anchor_kl_coef, ppo.kl_target and precomputed "
+            "advantages (the one-pass advantage plane) are not written for that"
+        )
+    obs = batch["obs"]
+    T = batch["rewards"].shape[1]
+    valid = batch["valid"].astype(jnp.float32)
+    n_valid = jnp.maximum(valid.sum(), 1.0)
+
+    (logits, values, _), mutated = policy.apply(
+        params, obs, batch["carry0"], batch["dones"], method="sequence",
+        mutable=["losses"],
+    )
+    sown = mutated.get("losses", {}).get("core", {})
+    if values.ndim == 2:
+        logits, values = jax.tree.map(lambda x: x[None], (logits, values))
+    R = values.shape[0]
+    # Trailing slot is the bootstrap step: value used, policy outputs unused.
+    logits_t = {k: v[:, :, :T] for k, v in logits.items()}
+    obs_t = {k: v[:, :T] for k, v in obs.items()}
+    values_t = values[:, :, :T]
+    logp = jax.vmap(lambda lg: D.log_prob(lg, obs_t, batch["actions"]))(logits_t)   # [R, B, T]
+
+    with jax.named_scope("update_gae"):
+        last_values = jax.lax.stop_gradient(values[-1])
+        if cfg.advantage == "gae":
+            adv, returns = gae(
+                batch["rewards"], last_values, batch["dones"], cfg.gamma, cfg.gae_lambda,
+            )
+        elif cfg.advantage == "vtrace":
+            adv, returns = vtrace(
+                batch["rewards"], last_values, batch["dones"], batch["behavior_logp"],
+                jax.lax.stop_gradient(logp[-1]), cfg.gamma, cfg.vtrace_rho_clip, cfg.vtrace_c_clip,
+            )
+        else:
+            raise ValueError(f"unknown advantage {cfg.advantage!r} (one of {ADVANTAGE_MODES})")
+    adv_mean = (adv * valid).sum() / n_valid
+    adv = adv - adv_mean
+    if cfg.adv_norm == "batch":
+        adv_var = (jnp.square(adv) * valid).sum() / n_valid
+        adv_std = jnp.sqrt(adv_var + 1e-8)
+        adv = adv / jnp.maximum(adv_std, cfg.adv_norm_floor)
+    elif cfg.adv_norm not in ADV_NORM_MODES:
+        raise ValueError(f"unknown adv_norm {cfg.adv_norm!r} (one of {ADV_NORM_MODES})")
+    ratio = jnp.exp(logp - batch["behavior_logp"])
+    clipped = jnp.clip(ratio, 1.0 - cfg.clip_eps, 1.0 + cfg.clip_eps)
+    surrogate = jnp.minimum(ratio * adv, clipped * adv)                 # [R, B, T]
+    value_sq = jnp.square(values_t - returns)
+    ent = jax.vmap(lambda lg: D.entropy(lg, obs_t))(logits_t)
+
+    with jax.named_scope("update_exit_mix"):
+        if "exit_logits" in sown:
+            p = jnp.moveaxis(exit_distribution(sown["exit_logits"][0][:, :T]), -1, 0)
+        else:
+            p = jnp.ones((1,) + valid.shape, jnp.float32)
+
+        def mix(x):
+            """``[R, B, T]`` -> the exit-weighted sum over loop steps, masked: ``[B, T]``."""
+            return (p * x).sum(axis=0) * valid
+
+        exit_ent = mix(-jnp.log(jnp.maximum(p, 1e-30))).sum() / n_valid
+        # each term in ``ppo_loss``'s own form, so that one exit of mass 1 is that loss
+        policy_loss = -mix(surrogate).sum() / n_valid
+        value_loss = 0.5 * mix(value_sq).sum() / n_valid
+        ent = mix(ent).sum() / n_valid
+
+    if cfg.value_warmup_steps and step is not None:
+        policy_on = (step >= cfg.value_warmup_steps).astype(jnp.float32)
+    else:
+        policy_on = 1.0
+    loss = (
+        policy_on
+        * (policy_loss - cfg.entropy_coef * ent - cfg.exit_entropy_coef * exit_ent)
+        + cfg.value_coef * value_loss
+    )
+    metrics = {
+        "loss": loss,
+        "moe_aux": jnp.zeros(()),
+        "policy_loss": policy_loss,
+        "value_loss": value_loss,
+        "entropy": ent,
+        # against the behaviour policy, which acted from the last loop step
+        "approx_kl": ((batch["behavior_logp"] - logp[-1]) * valid).sum() / n_valid,
+        "clip_frac": (
+            (jnp.abs(ratio[-1] - 1.0) > cfg.clip_eps).astype(jnp.float32) * valid
+        ).sum() / n_valid,
+        "value_mean": (values_t[-1] * valid).sum() / n_valid,
+        "reward_mean": (batch["rewards"] * valid).sum() / n_valid,
+    }
+    if "exit_logits" in sown:
+        # what the learner folds into the registry at the log cadence
+        # (``looplm/*``): the mean exit mass of each loop step, the mean loop
+        # step of exit counted from 1, the exit entropy, and the passes the
+        # core made over its stack in this forward (R where none is skipped)
+        mass = (p * valid).sum(axis=(1, 2)) / n_valid
+        metrics.update({f"looplm_exit_mass_{r}": mass[r] for r in range(R)})
+        metrics["looplm_expected_exit_step"] = (mass * jnp.arange(1, R + 1)).sum()
+        metrics["looplm_exit_entropy"] = exit_ent
+        metrics["looplm_loop_passes"] = sown["loop_passes"][0]
+    return loss, metrics
