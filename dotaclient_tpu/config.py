@@ -52,7 +52,7 @@ class ModelConfig:
     hidden_dim: int = 128        # LSTM hidden size — parity with reference
     n_hero_ids: int = 32         # hero-embedding vocabulary (multi-hero pools)
     hero_embed_dim: int = 16
-    core: str = "lstm"           # "lstm" | "transformer" | "afmoe" | "looplm" (RING_CORES: carry_is_rings)
+    core: str = "lstm"           # "lstm" | "transformer" | RESIDENT_CORES ("afmoe", "looplm", "kimilinear")
     # Transformer-core options (scale-out path, SURVEY.md §7 step 8).
     n_layers: int = 2
     n_heads: int = 4
@@ -97,19 +97,45 @@ class ModelConfig:
     # its own ring of full_context rows, and a gate after each loop step
     # gives the probability of stopping there (train/ppo.exit_weighted_loss)
     loop_steps: int = 1
+    # "kimilinear" core (models/kimilinear.py): delta-rule linear-attention
+    # layers (KDA: a float32 matrix state a head and a short convolution's
+    # history) beside latent-attention layers (MLA: one ring of kv_lora_rank +
+    # qk_rope_head_dim numbers a position). Which layer is which comes from
+    # global_attn_every / global_attn_offset / n_dense_layers ("full" = MLA);
+    # n_heads serves both kinds; the FFNs are the afmoe core's. Widths no
+    # field above states:
+    kv_lora_rank: int = 512      # MLA: the normalised latent of keys and values
+    qk_nope_head_dim: int = 128  # MLA: a head's query/key width from the latent
+    qk_rope_head_dim: int = 64   # MLA: the key part shared by all heads
+    v_head_dim: int = 128        # MLA: a head's value width
+    kda_head_dim: int = 128      # KDA: d_k = d_v of a head's state
+    kda_conv_kernel: int = 4     # KDA: taps of the causal depthwise convolution
 
     @property
-    def carry_is_rings(self) -> bool:
-        """The core's carry is per-lane attention caches (``models/afmoe.py``
-        ``initial_state``: ``{"pos", "cursor", "kv"}``, megabytes a lane)
-        that stay on the chip: a reset moves a counter, a chunk start copies
-        nothing, and only the fused trainer and the serve engine's resident
-        carries run it (``models/policy.py require_carry_stays``)."""
-        return self.core in RING_CORES
+    def carry_stays_on_chip(self) -> bool:
+        """The core's carry is megabytes a lane (ring caches, a linear-
+        attention layer's matrix states) that stay on the chip, and the core
+        owns its reset and its chunk start: a reset moves a counter and
+        rewrites no leaf, a chunk start widens and copies nothing the core
+        can read back, and only the fused trainer and the serve engine's
+        resident carries run it (``models/policy.py require_carry_stays``).
+        The core's module (``RESIDENT_CORES``) answers for the carry."""
+        return self.core in RESIDENT_CORES
+
+    # the property's name before PR 32, when every such carry was rings:
+    # benchmark/tests/test_looplm_cell.py asks under it, and files under
+    # benchmark/ are a `benchmark` PR's to edit
+    carry_is_rings = carry_stays_on_chip
 
 
-# Cores whose carry is ring caches (ModelConfig.carry_is_rings).
-RING_CORES = ("afmoe", "looplm")
+# Cores whose carry stays on the chip (ModelConfig.carry_stays_on_chip) and
+# the module under dotaclient_tpu/models/ that holds each one's ``Core``,
+# ``initial_state``, ``reset``, ``chunk_start_view``, ``carry_bytes_per_lane``
+# and ``require_episode_fits``.
+RESIDENT_CORES = {"afmoe": "afmoe", "looplm": "looplm", "kimilinear": "kimilinear"}
+
+# Cores whose FFN slot can be a routed mixture (``moe_experts`` > 0).
+ROUTED_FFN_CORES = ("transformer", "afmoe", "kimilinear")
 
 
 # Valid PPOConfig.adv_norm values — the single source of truth for the

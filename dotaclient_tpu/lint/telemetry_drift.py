@@ -141,7 +141,7 @@ _DOC_KEY_RE = re.compile(
 KEY_PREFIXES = (
     "actor/", "advantage/", "alerts/", "buffer/", "checkpoint/",
     "compile/", "faults/", "fleet/", "fused/", "health/", "league/",
-    "learner/", "looplm/", "mem/", "mesh/", "moe/", "outcome/", "router/", "serve/",
+    "kda/", "learner/", "looplm/", "mem/", "mesh/", "moe/", "outcome/", "router/", "serve/",
     "shm/", "snapshot/", "span/", "trace/", "transport/", "util/",
 )
 # single-line inline code only: multi-line matches would mispair across

@@ -134,17 +134,22 @@ def carry_bytes_per_lane(cfg: ModelConfig) -> int:
 
 
 def check_config(cfg: ModelConfig) -> None:
-    held, offset = held_experts(cfg)
     if cfg.n_heads % cfg.n_kv_heads:
         raise ValueError(f"n_heads {cfg.n_heads} not a multiple of n_kv_heads {cfg.n_kv_heads}")
     if cfg.head_dim % 2:
         raise ValueError(f"head_dim {cfg.head_dim} must be even for RoPE")
+    check_routing(cfg)
+
+
+def check_routing(cfg: ModelConfig) -> None:
+    """What ``RoutedExperts`` needs of a configuration with expert layers."""
+    held, offset = held_experts(cfg)
     if cfg.moe_experts and cfg.n_dense_layers < cfg.n_layers and not (
         0 < cfg.experts_per_token <= cfg.moe_experts
         and 0 <= offset and offset + held <= cfg.moe_experts
     ):
         raise ValueError(
-            f"afmoe routing: {cfg.experts_per_token} of {cfg.moe_experts} experts a token, "
+            f"{cfg.core} routing: {cfg.experts_per_token} of {cfg.moe_experts} experts a token, "
             f"experts {offset}..{offset + held - 1} held"
         )
 
@@ -202,6 +207,28 @@ def chunk_positions(pos0: jnp.ndarray, resets: Optional[jnp.ndarray], T: int):
     seg = jnp.cumsum(starts.astype(jnp.int32), axis=1)
     first = jax.lax.cummax(jnp.where(starts, idx, 0), axis=1)
     return seg, jnp.where(seg == 0, pos0[:, None] + idx, idx - first)
+
+
+def ring_masks(pos0: jnp.ndarray, cursor0: jnp.ndarray, seg: jnp.ndarray, R: int):
+    """What a chunk's T queries may see of a ring of ``R`` rows and of the
+    chunk's own rows, from the two counters and the chunk's segments:
+    (``age [B, R]`` of each slot's row in steps before the newest,
+    ``see_ring [B, T, R]``: the row is of the carry's episode and so is the
+    query, ``see_chunk [B, T, T]``: causal and of the same segment)."""
+    t = jnp.arange(seg.shape[1], dtype=jnp.int32)
+    age = (cursor0[:, None] - 1 - jnp.arange(R, dtype=jnp.int32)[None, :]) % R
+    in_episode = age < pos0[:, None]                               # [B, R]
+    see_ring = (seg == 0)[:, :, None] & in_episode[:, None, :]    # [B, T, R]
+    see_chunk = (t[:, None] >= t[None, :])[None] & (seg[:, :, None] == seg[:, None, :])
+    return age, see_ring, see_chunk
+
+
+def write_rows(ring: jnp.ndarray, cursor0: jnp.ndarray, new: jnp.ndarray) -> jnp.ndarray:
+    """``ring [B, R, C]`` with a chunk's rows ``new [B, T, C]`` in the T slots
+    from ``cursor0`` on: a scatter of T rows, never a copy of the ring."""
+    B, R, _ = ring.shape
+    slots = (cursor0[:, None] + jnp.arange(new.shape[1], dtype=jnp.int32)[None, :]) % R
+    return ring.at[jnp.arange(B)[:, None], slots].set(new.astype(ring.dtype))
 
 
 # -- pieces ---------------------------------------------------------------------
@@ -303,10 +330,7 @@ class Attention(nn.Module):
             k = k.astype(dtype)
 
             t = jnp.arange(T, dtype=jnp.int32)
-            age = (cursor0[:, None] - 1 - jnp.arange(R, dtype=jnp.int32)[None, :]) % R
-            in_episode = age < pos0[:, None]                               # [B, R]
-            see_ring = (seg == 0)[:, :, None] & in_episode[:, None, :]    # [B, T, R]
-            see_chunk = (t[:, None] >= t[None, :])[None] & (seg[:, :, None] == seg[:, None, :])
+            age, see_ring, see_chunk = ring_masks(pos0, cursor0, seg, R)
             if not self.full:
                 see_ring &= (t[None, :, None] + 1 + age[:, None, :]) < W
                 see_chunk &= ((t[:, None] - t[None, :]) < W)[None]
@@ -315,11 +339,8 @@ class Attention(nn.Module):
             out = out.reshape(B, T, nh * D) * nn.sigmoid(gate.astype(jnp.float32)) if cfg.attn_out_gate else out.reshape(B, T, nh * D)
             attn = _dense(cfg, cfg.hidden_dim, "wo")(out.astype(dtype))
         with jax.named_scope("core_cache_write"):
-            rows = jnp.arange(B)[:, None]
-            slots = (cursor0[:, None] + t[None, :]) % R                    # [B, T]
             ring = tuple(
-                r.at[rows, slots].set(new.reshape(B, T, kv * D).astype(r.dtype))
-                for r, new in zip(ring, (k, v))
+                write_rows(r, cursor0, new.reshape(B, T, kv * D)) for r, new in zip(ring, (k, v))
             )
         return attn, ring
 
@@ -524,3 +545,6 @@ def _attend_few_rows(q, k, v, ring_k, ring_v, see_ring, see_chunk):
         "bktj,bjkd->btkd", e_own.astype(q.dtype), v, preferred_element_type=jnp.float32
     )
     return (out / jnp.moveaxis(total, 2, 1)[..., None])[:, :, :, None]
+
+
+Core = AfmoeCore      # what ``models/policy.py resident_core`` constructs

@@ -125,3 +125,9 @@ class LoopLMCore(nn.Module):
             "kv": tuple(rings),
         }
         return carry, jnp.stack(ys)
+
+
+# what ``models/policy.py resident_core`` asks of a core's module: the carry is the afmoe core's
+Core = LoopLMCore
+initial_state, reset, chunk_start_view = afmoe.initial_state, afmoe.reset, afmoe.chunk_start_view
+carry_bytes_per_lane, require_episode_fits = afmoe.carry_bytes_per_lane, afmoe.require_episode_fits

@@ -13,16 +13,16 @@ TPU-first design decisions (SURVEY.md §7 step 3):
   the learner's teacher-forced sequence mode (``method="sequence"``), sharing
   parameters — sequence mode runs the LSTM through ``models/lstm.py`` (one
   ``lax.scan``, the weight gradient one product AFTER the backward loop) and
-  the windowed transformer under ``nn.scan``, and hands a ring-cache core
-  (``models/afmoe.py``, ``models/looplm.py``) the chunk in ONE pass (its step: T = 1).
+  the windowed transformer under ``nn.scan``, and hands a core whose carry stays on the chip
+  (``models/afmoe.py``, ``looplm.py``, ``kimilinear.py``) the chunk in ONE pass (its step: T = 1).
 * The carry, its reset and the chunk-start carry a learner is handed are
   the core's own: ``initial_state``, ``reset_carry`` and
   ``chunk_start_carry``. The LSTM's ``(h, c)`` and the transformer's window
   are rows that a reset zeroes (``mask_carry``) and a chunk start copies in
-  float32; where ``ModelConfig.carry_is_rings`` the carry is per-lane attention
-  caches (22 MiB a lane at Trinity-Mini's widths, 403 MB at Ouro's), which a
-  reset never touches (a position counter returns to 0) and a chunk start
-  never copies (the start's counters beside the end's rings).
+  float32; where ``ModelConfig.carry_stays_on_chip`` the carry is megabytes a lane
+  (attention caches: 22 MiB at Trinity-Mini's widths, 403 MB at Ouro's; matrix states and a
+  latent ring: 12 MB at Kimi-Linear's), which a reset never touches (a position counter returns
+  to 0) and a chunk start never widens: the core's own module answers (``resident_core``).
 * The trunk and heads are written shape-polymorphically (Dense/einsum on the
   last axis) so the same code handles ``[B, ...]`` and ``[B, T, ...]``.
 * Compute dtype is configurable bfloat16 with float32 params; logits are cast
@@ -39,7 +39,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from dotaclient_tpu.config import ActionSpec, ModelConfig, ObsSpec
+from dotaclient_tpu.config import RESIDENT_CORES, ROUTED_FFN_CORES, ActionSpec, ModelConfig, ObsSpec
 
 # Recurrent carry: (h, c) for the LSTM core; (valid, KV caches) for the
 # transformer core; {"pos", "cursor", "kv"} for a ring-cache core. Always a
@@ -122,10 +122,10 @@ class Policy(nn.Module):
             from dotaclient_tpu.models.transformer import WindowedTransformerCore
 
             self.core = WindowedTransformerCore(cfg)
-        elif cfg.carry_is_rings:
-            from dotaclient_tpu.models import afmoe, looplm
-
-            self.core = {"afmoe": afmoe.AfmoeCore, "looplm": looplm.LoopLMCore}[cfg.core](cfg)
+        elif cfg.carry_stays_on_chip:
+            # the constructor's lookup: the core's own module, found by the
+            # core's name (config.RESIDENT_CORES)
+            self.core = resident_core(cfg).Core(cfg)
         else:
             raise ValueError(f"unknown core {cfg.core!r}")
         hs = self.action_spec.head_sizes
@@ -186,10 +186,10 @@ class Policy(nn.Module):
     # -- public modes ------------------------------------------------------
 
     def initial_state(self, batch_size: int) -> Carry:
-        if self.model.carry_is_rings:
-            from dotaclient_tpu.models import afmoe
+        if self.model.carry_stays_on_chip:
+            # counters, rings and states as the core's module lays them out
 
-            return afmoe.initial_state(self.model, batch_size)
+            return resident_core(self.model).initial_state(self.model, batch_size)
         if self.model.core == "transformer":
             from dotaclient_tpu.models.transformer import (
                 transformer_initial_state,
@@ -203,24 +203,24 @@ class Policy(nn.Module):
     def reset_carry(self, carry: Carry, keep: jnp.ndarray) -> Carry:
         """Episode-boundary reset of the rows where ``keep`` ([B]) is 0, as
         the core defines it: the LSTM and the windowed transformer zero the
-        row (``mask_carry``); a ring-cache core returns the row's position
-        to 0 and touches no cache."""
-        if self.model.carry_is_rings:
-            from dotaclient_tpu.models import afmoe
+        row (``mask_carry``); a core whose carry stays on the chip returns
+        the row's position to 0 and touches no cache and no state."""
+        if self.model.carry_stays_on_chip:
+            # (a layer that keeps a state reads it as void at position 0)
 
-            return afmoe.reset(carry, keep)
+            return resident_core(self.model).reset(carry, keep)
         return mask_carry(carry, keep)
 
     def chunk_start_carry(self, start: Carry, end: Carry) -> Carry:
         """What a learner is handed as a chunk's ``carry0``, given the carry
         before the chunk's first step and after its last: the start in
-        float32 for the cores whose carry is rewritten every step, and for
-        a ring-cache core the start's counters beside the END's rings
-        (``afmoe.chunk_start_view``: no copy of a cache, no widening)."""
-        if self.model.carry_is_rings:
-            from dotaclient_tpu.models import afmoe
+        float32 for the LSTM and the windowed transformer, and for a core
+        whose carry stays on the chip its own ``chunk_start_view``: the start's
+        counters (and states) beside the END's rings, no cache copied or widened."""
+        if self.model.carry_stays_on_chip:
+            # the start's buffers and the end's, never a new one
 
-            return afmoe.chunk_start_view(start, end)
+            return resident_core(self.model).chunk_start_view(start, end)
         return jax.tree.map(lambda t: t.astype(jnp.float32), start)
 
     def step(
@@ -230,7 +230,7 @@ class Policy(nn.Module):
         with jax.named_scope("policy_trunk"):
             x, unit_emb = self._trunk(obs)
         with jax.named_scope("policy_core"):
-            if self.model.carry_is_rings:
+            if self.model.carry_stays_on_chip:
                 # the chunk function at T = 1 (of a looped core's [R, B, 1, H] the last loop step)
                 carry, y = self.core(carry, x[:, None])
                 y = y[:, 0] if y.ndim == 3 else y[-1, :, 0]
@@ -271,7 +271,7 @@ class Policy(nn.Module):
                 axis=1,
             )
 
-        if self.model.carry_is_rings:
+        if self.model.carry_stays_on_chip:
             # one pass over the chunk: T queries against the carried keys and the chunk's own, the resets a segment
             # mask. A looped core hands back every loop step, so logits and value lead with [R] (its gates are sown)
             with jax.named_scope("policy_core"):
@@ -309,15 +309,15 @@ class Policy(nn.Module):
 
 def require_carry_stays(model: ModelConfig, where: str) -> None:
     """Raise where ``where`` would ship a carry with every chunk or reply and
-    the core's carry is attention caches: the LSTM's and the windowed
-    transformer's rows travel, a ring-cache core's megabytes a lane stay on
+    the core's carry is caches or matrix states: the LSTM's and the windowed
+    transformer's rows travel, such a core's megabytes a lane stay on
     the chip (the fused trainer, the serve engine's resident carries)."""
-    if model.carry_is_rings:
-        from dotaclient_tpu.models.afmoe import carry_bytes_per_lane
+    if model.carry_stays_on_chip:
+        carry_bytes_per_lane = resident_core(model).carry_bytes_per_lane
 
         raise ValueError(
             f"core {model.core!r} carries {carry_bytes_per_lane(model):,} bytes of "
-            f"attention caches a lane: it trains in actor mode 'fused' and "
+            f"caches and states a lane: it trains in actor mode 'fused' and "
             f"serves from the engine's resident carries, not in {where}, "
             f"which would copy that carry with every chunk or reply"
         )
@@ -325,12 +325,12 @@ def require_carry_stays(model: ModelConfig, where: str) -> None:
 
 def require_episode_fits(model: ModelConfig, episode_steps: int, rollout_len: int) -> None:
     """Raise where the core's carry cannot hold an episode of
-    ``episode_steps`` observations rolled out ``rollout_len`` at a time (a
-    ring-cache core's rings: ``afmoe.require_episode_fits``)."""
-    if model.carry_is_rings:
-        from dotaclient_tpu.models import afmoe
+    ``episode_steps`` observations rolled out ``rollout_len`` at a time (the
+    full-attention rings of a core whose carry stays on the chip)."""
+    if model.carry_stays_on_chip:
+        # the core's own module knows which of its leaves an episode must fit
 
-        afmoe.require_episode_fits(model, episode_steps, rollout_len)
+        resident_core(model).require_episode_fits(model, episode_steps, rollout_len)
 
 
 def make_policy(model: ModelConfig, obs_spec: ObsSpec, action_spec: ActionSpec) -> Policy:
@@ -382,8 +382,19 @@ def require_routed_ffn(model: ModelConfig) -> None:
     """Raise where ``moe_experts`` asks for a routed FFN of a core that has
     none: silently training a dense core under an "8-expert" label would
     mislabel every result."""
-    if model.moe_experts > 0 and model.core not in ("transformer", "afmoe"):
+    if model.moe_experts > 0 and model.core not in ROUTED_FFN_CORES:
         raise ValueError(
-            f"moe_experts={model.moe_experts} requires core='transformer' "
-            f"or 'afmoe' (got core={model.core!r}), the cores that route an FFN"
+            f"moe_experts={model.moe_experts} requires a core of {ROUTED_FFN_CORES} "
+            f"(got core={model.core!r}), the cores that route an FFN"
         )
+
+
+def resident_core(model: ModelConfig):
+    """The module of a core whose carry stays on the chip
+    (``ModelConfig.carry_stays_on_chip``), found by the core's name: it holds
+    ``Core`` and answers for the carry (``initial_state``, ``reset``,
+    ``chunk_start_view``, ``carry_bytes_per_lane``, ``require_episode_fits``),
+    so that nothing in this file compares such a core's name."""
+    import importlib
+
+    return importlib.import_module(f"dotaclient_tpu.models.{RESIDENT_CORES[model.core]}")

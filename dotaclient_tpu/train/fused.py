@@ -10,7 +10,7 @@ call (``FusedStep``). One dispatch per optimizer step, zero host
 round-trips, nothing staged through the trajectory buffer. Where the train
 state and the actor state together hold more than
 ``DONATE_ABOVE_BYTES`` on one device (a core whose carry is ring caches,
-``ModelConfig.carry_is_rings``: gigabytes beside the parameters) the call DONATES both: they
+``ModelConfig.carry_stays_on_chip``: gigabytes beside the parameters) the call DONATES both: they
 update in place in HBM, so states that do not fit the chip twice run through
 it, and what reads the state across a dispatch (the league's snapshot, a
 weights publish, a checkpoint) copies on the device BEFORE the next enqueue.

@@ -1502,7 +1502,9 @@ class Learner:
         """A logged step's counts from a core that keeps some, into the registry at the log cadence:
         a routed layer's pairs and load as gauges and the pairs its weights left out as a counter that
         never moves (``train/ppo._moe_counters``); a looped core's exit statistics as gauges and its
-        passes over the stack as a counter, R a logged forward pass (``train/ppo.exit_weighted_loss``)."""
+        passes over the stack as a counter, R a logged forward pass (``train/ppo.exit_weighted_loss``);
+        a delta-rule core's decay, write strength and state size as gauges and its reads of a void state
+        (a lane-layer at position 0 of an episode) as a counter (``train/ppo._kda_gauges``)."""
         tel = self.telemetry
         if "moe_local_assignments" in scalars:
             tel.gauge("moe/local_assignments").set(scalars["moe_local_assignments"])
@@ -1514,6 +1516,10 @@ class Learner:
             tel.gauge("looplm/exit_entropy").set(scalars["looplm_exit_entropy"])
             for r in range(self.config.model.loop_steps):
                 tel.gauge(f"looplm/exit_mass/{r}").set(scalars[f"looplm_exit_mass_{r}"])
+        if "kda_void_reads" in scalars:
+            tel.counter("kda/void_reads_total").inc(scalars["kda_void_reads"])
+            for key in ("kda/decay_mean", "kda/beta_mean", "kda/state_rms"):
+                tel.gauge(key).set(scalars[key.replace("/", "_")])
 
     def _publish_pipeline_gauges(self) -> None:
         """Refresh the cross-stage gauges at a log boundary: actor weight

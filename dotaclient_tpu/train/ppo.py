@@ -215,7 +215,7 @@ def ppo_loss(
         mutable=["losses"],
     )
     moe_aux = _moe_aux_loss(mutated.get("losses", {}), valid)
-    moe_counters = _moe_counters(mutated.get("losses", {}))
+    moe_counters = {**_moe_counters(mutated.get("losses", {})), **_kda_gauges(mutated.get("losses", {}), valid)}
     bias_errors = _select_bias_errors(mutated.get("losses", {}))
     # Trailing slot is the bootstrap step: value used, policy outputs unused.
     logits_t = {k: v[:, :T] for k, v in logits.items()}
@@ -858,3 +858,26 @@ def exit_weighted_loss(
         metrics["looplm_exit_entropy"] = exit_ent
         metrics["looplm_loop_passes"] = sown["loop_passes"][0]
     return loss, metrics
+
+
+def _kda_gauges(losses_col: Any, valid: jnp.ndarray) -> Dict[str, jnp.ndarray]:
+    """What a core with delta-rule linear-attention layers sowed in this pass
+    (``models/kimilinear.py``), over its KDA layers: the mean decay (what a
+    step keeps of a state's channel), the mean write strength, the end
+    states' root mean square, and the lane-layer reads of a void state (a
+    step at position 0 of its episode, the bootstrap step left to the next
+    chunk). Empty for every other core."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(losses_col)
+
+    def leaves(name):
+        return [l for p, l in flat if getattr(p[-2], "key", None) == name]
+
+    if not leaves("kda_decay"):
+        return {}
+    T = valid.shape[1]
+    return {
+        "kda_decay_mean": jnp.stack(leaves("kda_decay")).mean(),
+        "kda_beta_mean": jnp.stack(leaves("kda_beta")).mean(),
+        "kda_state_rms": jnp.sqrt(jnp.stack(leaves("kda_state_sq")).mean()),
+        "kda_void_reads": (leaves("kda_void_reads")[0][:, :T] * valid).sum(),
+    }

@@ -294,8 +294,8 @@ def test_episode_must_fit_the_ring_and_the_refusals_name_the_core():
     with pytest.raises(ValueError, match="core 'looplm' carries 36,872 bytes"):
         require_carry_stays(model, "actor mode 'device'")
     require_carry_stays(default_config().model, "actor mode 'device'")       # the LSTM's rows travel
-    assert model.carry_is_rings and tiny_model(core="afmoe").carry_is_rings
-    assert not default_config().model.carry_is_rings
+    assert model.carry_stays_on_chip and tiny_model(core="afmoe").carry_stays_on_chip
+    assert not default_config().model.carry_stays_on_chip
 
 
 @pytest.mark.parametrize("over", [
