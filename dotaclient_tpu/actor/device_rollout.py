@@ -36,6 +36,7 @@ from dotaclient_tpu.features.jax_featurizer import (
 )
 from dotaclient_tpu.features.reward import fold_terms
 from dotaclient_tpu.models import distributions as D
+from dotaclient_tpu.models.lanes import LaneBlocks
 from dotaclient_tpu.models.policy import Policy, require_episode_fits
 from dotaclient_tpu.outcome import ingraph as outcome_ingraph
 from dotaclient_tpu.outcome import records as outcome_records
@@ -352,6 +353,15 @@ class DeviceActor:
         self._tel = registry if registry is not None else telemetry.get_registry()
         outcome_records.ensure_actor_metrics(self._tel)
 
+    @property
+    def one_pass_when_live(self) -> bool:
+        """Whether a rollout step whose two teams play the same parameters
+        (``_rollout_impl`` with ``opp_params`` None) runs the policy ONCE over
+        both teams' rows: there are opponent lanes, and the lanes are on one
+        shard (joining two lane-sharded row sets would be a reshard, and the
+        rollout holds no collective: tests/test_fused_multichip.py)."""
+        return self._opp_feat is not None and self.lane_shards == 1
+
     def donate_state(self) -> None:
         """Whoever builds a program that DONATES ``self.state`` says so here
         (``train/fused.py``, where the states are most of the chip): from
@@ -411,6 +421,14 @@ class DeviceActor:
         state: DeviceActorState,
         opp_params: Any,
     ):
+        """``opp_params`` None says that the opponent lanes play ``params``
+        themselves (a caller inside a ``jit`` cannot show it by identity: two
+        arguments are two tracers). A step is then ONE pass of the policy over
+        both teams' rows, every weight read once (``one_pass_when_live``);
+        otherwise a pass a team, as a frozen opponent needs."""
+        one_pass = opp_params is None and self.one_pass_when_live
+        if opp_params is None:
+            opp_params = params
         cfg = self.config
         spec = self.spec
         T = cfg.ppo.rollout_len
@@ -421,6 +439,23 @@ class DeviceActor:
             if self.learner_players[0] < spec.team_size
             else sim_mod.TEAM_DIRE
         )
+
+        def policy_pass(p, *teams):
+            """One pass of the policy over the teams' rows together: an
+            ``(obs, carry)`` a team -> a ``(logits, carry)`` a team."""
+            if len(teams) == 1:
+                logits, _, carry = self.policy.apply(p, *teams[0], method="step")
+                return ((logits, carry),)
+            obs, carries = zip(*teams)
+            logits, _, carries = self.policy.apply(
+                p, jax.tree.map(lambda *rows: jnp.concatenate(rows), *obs),
+                LaneBlocks(carries), method="step",
+            )
+            sizes = [o["hero_id"].shape[0] for o in obs]
+            return tuple(
+                (jax.tree.map(lambda x: x[end - n:end], logits), carry)
+                for n, end, carry in zip(sizes, np.cumsum(sizes), carries)
+            )
 
         def body(c, _):
             sim, lstm, opp_lstm, key, ep_ret, ep_steps = c
@@ -436,9 +471,13 @@ class DeviceActor:
             # profiler trace times the stages by name.
             with jax.named_scope("rollout_featurize"):
                 obs = feat.featurize(sim)
-            logits, _, lstm2 = self.policy.apply(
-                params, obs, lstm, method="step"
-            )
+                oobs = self._opp_feat.featurize(sim) if one_pass else None
+            if one_pass:
+                (logits, lstm2), (ologits, opp_lstm2) = policy_pass(
+                    params, (obs, lstm), (oobs, opp_lstm)
+                )
+            else:
+                ((logits, lstm2),) = policy_pass(params, (obs, lstm))
             with jax.named_scope("rollout_sample"):
                 acts, logp = sample_per_game(k_act, logits, obs, spec.n_games)
                 packed = jnp.stack(
@@ -447,11 +486,12 @@ class DeviceActor:
                 sim_acts = feat.actions_to_sim(packed)
 
             if self._opp_feat is not None:
-                with jax.named_scope("rollout_featurize"):
-                    oobs = self._opp_feat.featurize(sim)
-                ologits, _, opp_lstm2 = self.policy.apply(
-                    opp_params, oobs, opp_lstm, method="step"
-                )
+                if not one_pass:
+                    with jax.named_scope("rollout_featurize"):
+                        oobs = self._opp_feat.featurize(sim)
+                    ((ologits, opp_lstm2),) = policy_pass(
+                        opp_params, (oobs, opp_lstm)
+                    )
                 with jax.named_scope("rollout_sample"):
                     oacts, _ = sample_per_game(
                         k_opp, ologits, oobs, spec.n_games

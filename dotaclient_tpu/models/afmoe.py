@@ -59,6 +59,13 @@ further back than a window layer's first query can see, and in a full layer
 further back than the episode is long (``require_episode_fits``), so
 ``chunk_start_view`` is the START's two counters beside the END's rings.
 
+**Several lane sets in one step.** A rollout whose two teams play the same
+parameters steps both in ONE call (``models/lanes.py``): the counters and
+the activations are concatenated, every product against a weight sees all
+rows, and each set's rings stay the set's own arrays: the products against a
+ring, its masks and its write run a block at a time on the block's rows
+(``lanes.by_lane_block``), so no cache is copied or joined.
+
 **Experts held here.** ``held_experts`` and ``expert_offset`` say which of
 the ``moe_experts`` routed experts this chip holds (guide: one chip's share
 of a layer divided over several). The router keeps its whole width and its
@@ -91,6 +98,7 @@ import jax
 import jax.numpy as jnp
 
 from dotaclient_tpu.config import ModelConfig
+from dotaclient_tpu.models.lanes import by_lane_block
 
 _NEG = -1e30
 
@@ -315,8 +323,6 @@ class Attention(nn.Module):
         B, T, _ = a.shape
         nh, kv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         G, W = nh // kv, cfg.context_window
-        R = ring[0].shape[1]
-        ring_k, ring_v = (r.reshape(B, R, kv, D) for r in ring)    # stored [B, R, kv D]
         with jax.named_scope("core_attn_full" if self.full else "core_attn_window"):
             q = _dense(cfg, nh * D, "wq")(a).reshape(B, T, kv, G, D)
             k = _dense(cfg, kv * D, "wk")(a).reshape(B, T, kv, D)
@@ -328,19 +334,25 @@ class Attention(nn.Module):
                 q, k = rope(q, p, cfg.rope_theta), rope(k, p, cfg.rope_theta)
             q = (q / math.sqrt(D)).astype(dtype)
             k = k.astype(dtype)
-
             t = jnp.arange(T, dtype=jnp.int32)
-            age, see_ring, see_chunk = ring_masks(pos0, cursor0, seg, R)
-            if not self.full:
-                see_ring &= (t[None, :, None] + 1 + age[:, None, :]) < W
-                see_chunk &= ((t[:, None] - t[None, :]) < W)[None]
 
-            out = _attend(q, k, v.astype(dtype), ring_k, ring_v, see_ring, see_chunk) if G > 1 or T >= _MXU_ROWS else _attend_few_rows(q, k, v.astype(dtype), ring_k, ring_v, see_ring, see_chunk)
+            def attend(ring, q, k, v, pos0, cursor0, seg):
+                R = ring[0].shape[1]
+                ring_k, ring_v = (r.reshape(-1, R, kv, D) for r in ring)   # stored [B, R, kv D]
+                age, see_ring, see_chunk = ring_masks(pos0, cursor0, seg, R)
+                if not self.full:
+                    see_ring &= (t[None, :, None] + 1 + age[:, None, :]) < W
+                    see_chunk &= ((t[:, None] - t[None, :]) < W)[None]
+                few_rows = G == 1 and T < _MXU_ROWS
+                return (_attend_few_rows if few_rows else _attend)(q, k, v, ring_k, ring_v, see_ring, see_chunk), None
+
+            out, _ = by_lane_block(attend, ring, q, k, v.astype(dtype), pos0, cursor0, seg)
             out = out.reshape(B, T, nh * D) * nn.sigmoid(gate.astype(jnp.float32)) if cfg.attn_out_gate else out.reshape(B, T, nh * D)
             attn = _dense(cfg, cfg.hidden_dim, "wo")(out.astype(dtype))
         with jax.named_scope("core_cache_write"):
-            ring = tuple(
-                write_rows(r, cursor0, new.reshape(B, T, kv * D)) for r, new in zip(ring, (k, v))
+            _, ring = by_lane_block(
+                lambda ring, k, v, cursor0: (None, tuple(write_rows(r, cursor0, new) for r, new in zip(ring, (k, v)))),
+                ring, k.reshape(B, T, kv * D), v.reshape(B, T, kv * D), cursor0,
             )
         return attn, ring
 

@@ -101,6 +101,7 @@ from dotaclient_tpu.models.afmoe import (
     RMSNorm, RoutedExperts, SwiGLU, _attend, _dense, _dtype, chunk_positions,
     layer_is_dense, layer_is_full, reset, ring_masks, write_rows,
 )
+from dotaclient_tpu.models.lanes import by_lane_block
 
 _NEG = -1e30
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -277,17 +278,21 @@ class KDA(nn.Module):
                 "conv", nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=0, out_axis=1),
                 (K, 3 * W), pdtype,
             ).astype(jnp.float32)
-            rows = jnp.concatenate([jnp.where(carried[:, None, None], history, 0), x], axis=1)
-            row_seg = jnp.concatenate([jnp.zeros((B, K - 1), seg.dtype), seg], axis=1)
-            y = sum(
-                taps[j] * jnp.where(
-                    (row_seg[:, K - 1 - j:K - 1 - j + T] == seg)[..., None],
-                    rows[:, K - 1 - j:K - 1 - j + T].astype(jnp.float32), 0.0,
+
+            def convolve(history, x, carried, seg):
+                rows = jnp.concatenate([jnp.where(carried[:, None, None], history, 0), x], axis=1)
+                row_seg = jnp.concatenate([jnp.zeros((x.shape[0], K - 1), seg.dtype), seg], axis=1)
+                y = sum(
+                    taps[j] * jnp.where(
+                        (row_seg[:, K - 1 - j:K - 1 - j + T] == seg)[..., None],
+                        rows[:, K - 1 - j:K - 1 - j + T].astype(jnp.float32), 0.0,
+                    )
+                    for j in range(K)
                 )
-                for j in range(K)
-            )
-            # the rows a later step's taps may read: those of the chunk's last episode
-            history = jnp.where((row_seg[:, T:] == seg[:, -1:])[..., None], rows[:, T:], 0)
+                # the rows a later step's taps may read: those of the chunk's last episode
+                return y, jnp.where((row_seg[:, T:] == seg[:, -1:])[..., None], rows[:, T:], 0)
+
+            y, history = by_lane_block(convolve, history, x, carried, seg)
             q, k, v = (z.reshape(B, T, nh, D) for z in jnp.split(nn.silu(y), 3, axis=-1))
             q, k = _l2norm(q) / math.sqrt(D), _l2norm(k)
             f = _dense(cfg, W, "wf_up")(_dense(cfg, D, "wf_down")(a)).astype(jnp.float32)
@@ -297,12 +302,20 @@ class KDA(nn.Module):
             beta = nn.sigmoid(_dense(cfg, nh, "wb")(a).astype(jnp.float32))
             gate = _dense(cfg, W, "wg_up")(_dense(cfg, D, "wg_down")(a)).astype(jnp.float32)
             with jax.named_scope("core_kda_state"):
-                o, S = delta_rule_chunk(q, k, v, log_alpha, beta, S0, seg, carried)
+                o, S = by_lane_block(
+                    lambda S0, q, k, v, log_alpha, beta, seg, carried: delta_rule_chunk(
+                        q, k, v, log_alpha, beta, S0, seg, carried
+                    ),
+                    S0, q, k, v, log_alpha, beta, seg, carried,
+                )
             out = RMSNorm(cfg, name="o_norm")(o) * nn.sigmoid(gate.reshape(B, T, nh, D))
             mix = _dense(cfg, cfg.hidden_dim, "wo")(out.reshape(B, T, W).astype(dtype))
         self.sow("losses", "kda_decay", jnp.exp(log_alpha).mean())
         self.sow("losses", "kda_beta", beta.mean())
-        self.sow("losses", "kda_state_sq", jnp.square(S).mean())
+        # a lane's sum a block, outside `core_kda_state` (that scope times the recurrence alone); a per-lane
+        # `mean` here left 96 more instructions in the rollout's loop (tests/test_shared_pass_hlo.py)
+        state_sq, _ = by_lane_block(lambda S: (jnp.square(S).sum(axis=(1, 2, 3)), None), S)
+        self.sow("losses", "kda_state_sq", state_sq.sum() / (B * W * D))
         return mix, (S, history)
 
 
@@ -319,7 +332,7 @@ class LatentAttention(nn.Module):
         B, T, _ = a.shape
         nh, C = cfg.n_heads, cfg.kv_lora_rank
         dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        R, Wl = ring.shape[1], latent_width(cfg)
+        Wl = latent_width(cfg)
 
         def per_head(name, width):
             return self.param(
@@ -336,13 +349,17 @@ class LatentAttention(nn.Module):
             q = jnp.concatenate([q_latent, q[..., dn:]], axis=-1).astype(jnp.float32)
             q = (q / math.sqrt(dn + dr)).astype(dtype)[:, :, None]           # one KV head: [B, T, 1, nh, Wl]
             with jax.named_scope("core_latent_attend"):
-                _, see_ring, see_chunk = ring_masks(pos0, cursor0, seg, R)
-                own, held = row[:, :, None], ring[:, :, None]                 # [B, T | R, 1, Wl]
-                out = _attend(q, own, own, held, held, see_ring, see_chunk)[:, :, 0, :, :C]
+
+                def attend(ring, q, row, pos0, cursor0, seg):
+                    _, see_ring, see_chunk = ring_masks(pos0, cursor0, seg, ring.shape[1])
+                    own, held = row[:, :, None], ring[:, :, None]             # [B, T | R, 1, Wl]
+                    return _attend(q, own, own, held, held, see_ring, see_chunk)[:, :, 0, :, :C], None
+
+                out, _ = by_lane_block(attend, ring, q, row, pos0, cursor0, seg)
             v = jnp.einsum("bthc,hcv->bthv", out.astype(dtype), per_head("wuv", dv))
             attn = _dense(cfg, cfg.hidden_dim, "wo")(v.reshape(B, T, nh * dv))
         with jax.named_scope("core_cache_write"):
-            ring = write_rows(ring, cursor0, row)
+            _, ring = by_lane_block(lambda ring, *rows: (None, write_rows(ring, *rows)), ring, cursor0, row)
         return attn, ring
 
 

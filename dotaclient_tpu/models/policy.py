@@ -23,6 +23,9 @@ TPU-first design decisions (SURVEY.md §7 step 3):
   (attention caches: 22 MiB at Trinity-Mini's widths, 403 MB at Ouro's; matrix states and a
   latent ring: 12 MB at Kimi-Linear's), which a reset never touches (a position counter returns
   to 0) and a chunk start never widens: the core's own module answers (``resident_core``).
+* ``step`` takes one lane set's carry or several sets' (``LaneBlocks``: a rollout
+  whose two teams play the same parameters): one pass, every weight read once, each
+  set's caches and states touched where they lie (``models/lanes.py``).
 * The trunk and heads are written shape-polymorphically (Dense/einsum on the
   last axis) so the same code handles ``[B, ...]`` and ``[B, T, ...]``.
 * Compute dtype is configurable bfloat16 with float32 params; logits are cast
@@ -40,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from dotaclient_tpu.config import RESIDENT_CORES, ROUTED_FFN_CORES, ActionSpec, ModelConfig, ObsSpec
+from dotaclient_tpu.models.lanes import join_lanes, split_lanes
 
 # Recurrent carry: (h, c) for the LSTM core; (valid, KV caches) for the
 # transformer core; {"pos", "cursor", "kv"} for a ring-cache core. Always a
@@ -226,16 +230,20 @@ class Policy(nn.Module):
     def step(
         self, obs: Mapping[str, jnp.ndarray], carry: Carry
     ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, Carry]:
-        """Single batched step (actor path): obs arrays ``[B, ...]``."""
+        """Single batched step (actor path): obs arrays ``[B, ...]``; ``carry``
+        is B lanes' carry, or a ``LaneBlocks`` of the carries of lane sets that
+        make up the B rows in order (every weight is then read once for all)."""
         with jax.named_scope("policy_trunk"):
             x, unit_emb = self._trunk(obs)
         with jax.named_scope("policy_core"):
+            sets, carry = carry, join_lanes(carry, self.model.carry_stays_on_chip)
             if self.model.carry_stays_on_chip:
                 # the chunk function at T = 1 (of a looped core's [R, B, 1, H] the last loop step)
                 carry, y = self.core(carry, x[:, None])
                 y = y[:, 0] if y.ndim == 3 else y[-1, :, 0]
             else:
                 carry, y = self.core(carry, x)
+            carry = split_lanes(carry, sets)
         with jax.named_scope("policy_heads"):
             logits, value = self._heads(y, unit_emb)
         return logits, value, carry

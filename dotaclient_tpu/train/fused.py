@@ -129,7 +129,9 @@ def make_fused_step(
     is BORN data-sharded; the mid-program sharding constraints are no-op
     assertions, not reshards. ``opp_params`` is a frozen snapshot, or
     ``None`` (or the state's own ``params``) where the opponent is the live
-    policy: see ``FusedStep``.
+    policy: see ``FusedStep``. Inside, ``None`` is handed down to the rollout
+    as the fact that both teams play ``state.params`` (one iteration a
+    dispatch; several hold the opponent at the dispatch's first parameters).
     """
     if (config.ppo.anchor_kl_coef > 0) != (anchor_params is not None):
         raise ValueError(
@@ -242,10 +244,16 @@ def make_fused_step(
         # rollout+update iterations, so ONE host dispatch advances K
         # optimizer steps. The opponent is fixed for the dispatch (the
         # learner rejects league configs whose opponent_hold is shorter
-        # than the dispatch stride); per-chunk
-        # episode stats are additive scalars, summed over the scan so
-        # league attribution sees the dispatch's true totals.
+        # than the dispatch stride): a live one is the parameters the
+        # dispatch STARTED from, which from the second iteration on are no
+        # longer the learner's, so the rollout is handed them as an opponent
+        # of its own and keeps a pass a team (`live_shares_pass` is false).
+        # Per-chunk episode stats are additive scalars, summed over the
+        # scan so league attribution sees the dispatch's true totals.
         def fused(state, actor_state, opp_params):
+            if opp_params is None:
+                opp_params = state.params
+
             def it(c, _):
                 st, ast = c
                 st, ast, metrics, stats = one_iter(st, ast, opp_params)
@@ -273,6 +281,7 @@ def make_fused_step(
         out_shardings=(st_sh, st_act_sh, repl, st_act_sh.stats),
         donate=donate,
         both_kinds=actor.opponent_players != [],
+        live_shares_pass=donate and n_iters == 1 and actor.one_pass_when_live,
     )
 
 
@@ -312,9 +321,20 @@ class FusedStep:
     their Adam moments) both update in place in HBM, and a dispatch in
     flight holds no second copy of either. A donated buffer cannot also be read as another
     argument, so the live opponent is a program of its own with two
-    arguments. Both call one traced function (``jax.jit`` caches its
-    jaxpr), so the second costs a lowering and a compile, not a second
-    trace. With opponent lanes (``both_kinds``) either draw can come at any
+    arguments, which hands the rollout ``None`` for the opponent's
+    parameters. The rollout then KNOWS that both teams play ``state.params``
+    and, where ``live_shares_pass`` (``DeviceActor.one_pass_when_live``),
+    runs the policy once a step over both teams' rows: every weight read
+    once where the frozen program reads it twice. So the two programs are two
+    traces (the live rollout's body is another jaxpr, a pass shorter), not
+    one trace lowered twice as they were until PR 33. ``live_shares_pass``
+    is fixed when the program is made and says whether a live dispatch
+    (``opp_params`` None) runs such a rollout (the learner's
+    ``league/shared_pass_dispatches_total``): false without donation, and
+    false where a dispatch scans several iterations
+    (``steps_per_dispatch`` > 1), whose live opponent is the parameters the
+    dispatch started from in either program. With opponent lanes
+    (``both_kinds``) either draw can come at any
     dispatch, so both programs are built at the first call, from its
     arguments' shapes: nothing is left to compile in the middle of a run.
     Whoever reads the state across a dispatch copies on the device before
@@ -325,22 +345,21 @@ class FusedStep:
     """
 
     def __init__(self, fused, state_sharding, actor_sharding, out_shardings,
-                 donate: bool, both_kinds: bool) -> None:
+                 donate: bool, both_kinds: bool, live_shares_pass: bool) -> None:
         self.donate = donate
+        self.live_shares_pass = live_shares_pass
         three = (state_sharding, actor_sharding, state_sharding.params)
         if not donate:
             self._jits = {"frozen": jax.jit(
                 fused, in_shardings=three, out_shardings=out_shardings,
             )}
             return
-        # traced once, its jaxpr laid into both programs (no call boundary)
-        inner = jax.jit(fused, inline=True)
 
         def frozen_opponent(state, actor_state, opp_params):
-            return inner(state, actor_state, opp_params)
+            return fused(state, actor_state, opp_params)
 
         def live_opponent(state, actor_state):
-            return inner(state, actor_state, state.params)
+            return fused(state, actor_state, None)
 
         self._jits = {
             "frozen": jax.jit(

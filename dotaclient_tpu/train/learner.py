@@ -534,13 +534,14 @@ class Learner:
             if mode == "fused":
                 from dotaclient_tpu.train.fused import make_fused_step
 
-                self.fused_step = tracing.instrument_jit(
-                    make_fused_step(
-                        self.policy, config, self.mesh, self.device_actor,
-                        anchor_params=self.anchor_params,
-                    ),
-                    "fused_step",
+                program = make_fused_step(
+                    self.policy, config, self.mesh, self.device_actor,
+                    anchor_params=self.anchor_params,
                 )
+                # fixed with the program, whatever later stands in for
+                # `fused_step`: a live dispatch's rollout is one pass
+                self._live_shares_pass = program.live_shares_pass
+                self.fused_step = tracing.instrument_jit(program, "fused_step")
         elif mode == "vec":
             self.pool = VecActorPool(
                 config,
@@ -1797,6 +1798,7 @@ class Learner:
                 tel = self.telemetry
                 dispatches = tel.counter("learner/dispatches_total")
                 frozen = tel.counter("league/frozen_dispatches_total")
+                shared = tel.counter("league/shared_pass_dispatches_total")
                 while steps_done < num_steps and not self._stop_requested:
                     with tel.span("learner/iteration", step=self._host_step):
                         with tel.span("learner/league_draw"):
@@ -1814,6 +1816,8 @@ class Learner:
                         dispatches.inc()
                         if opp_idx != league_pool.LIVE:
                             frozen.inc()
+                        elif self._live_shares_pass:
+                            shared.inc()
                         with tel.span("learner/league_report"):
                             self._report_league(opp_idx, chunk_stats)
                         # the program ran `stride` optimizer steps over K chunks —

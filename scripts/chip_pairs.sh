@@ -1,0 +1,46 @@
+#!/bin/bash
+# Compare two trees of this repository on ONE chip in ONE chiprun call (PR 33; the verify skill says when).
+# Before the call: unpack the parent into runs/parent and the change into runs/change (git archive; runs/ is
+# git-ignored and travels with the copy). Then:
+#   chiprun --timeout 3600 -- bash scripts/chip_pairs.sh <cell> <limit_s> <need_s> P1 C1 C2 P2 T4 P3 C3
+# step = P<k> (parent) | C<k> (change) | T<k> (change, --trace 1); k picks the seed, so P<k> and C<k> share one.
+# A step is skipped when fewer than <need_s> seconds of <limit_s> are left. Both trees run from ONE path
+# (runs/cur: the compile cache's key holds source paths); the compile cache is this call's own (runs/jc, inside
+# this checkout: two checkouts never meet in it) and unbounded, so each tree compiles cold once. Result lines and
+# the program's last JSONL line land under chiprun_out/pr_pairs/.
+cell=$1; limit=$2; need=$3; shift 3
+root=$(cd "$(dirname "$0")/.." && pwd)
+out=$root/chiprun_out/pr_pairs/$cell; mkdir -p "$out"
+export JAX_COMPILATION_CACHE_DIR=$root/runs/jc JAX_COMPILATION_CACHE_MAX_SIZE=42949672960
+t0=$(date +%s)
+cd "$root/runs"
+for step in "$@"; do
+  now=$(( $(date +%s) - t0 ))
+  if [ $(( now + need )) -gt $limit ]; then echo "SKIP $step at ${now}s"; continue; fi
+  side=${step:0:1}; k=${step:1}; seed=$(( 2147400000 + 7919 * k )); trace=0; tree=parent
+  [ $side != P ] && tree=change
+  [ $side = T ] && trace=1 && seed=$(( seed + 13 ))
+  mv $tree cur
+  ( cd cur && timeout 1700 python3 benchmark/run.py --workload $cell --seed $seed --seconds 20 --trace $trace > $out/$step.out 2> $out/$step.err; echo "rc=$?" >> $out/$step.out )
+  m=cur/benchmark_out/$cell/metrics.jsonl
+  [ -f $m ] && tail -1 $m > $out/$step.metrics
+  mv cur $tree
+  python3 - $out/$step.out $out/$step.metrics $step $(( $(date +%s) - t0 )) <<'PY'
+import json, sys
+out, met, step, t = sys.argv[1:5]
+lines = open(out).read().splitlines()
+res = next((json.loads(l) for l in reversed(lines) if l.startswith("{")), None)
+det = next((json.loads(l.split("detail ", 1)[1]) for l in lines if "benchmark: detail " in l), {})
+try:
+    sc = json.loads(open(met).read()).get("scalars", {})
+except Exception:
+    sc = {}
+print(step, "t=%ss" % t, lines[-1] if lines else "", 
+      "correct", res and res.get("correct"), "failed", res and res.get("failed"),
+      {k: v for k, v in (res or {}).get("metrics", {}).items()},
+      "compile_s", det.get("setup", {}).get("compile_s"), "hits", det.get("setup", {}).get("cache_hits"), "misses", det.get("setup", {}).get("cache_misses"),
+      "dispatches_total", sc.get("learner/dispatches_total"), "shared", sc.get("league/shared_pass_dispatches_total"), "frozen", sc.get("league/frozen_dispatches_total"),
+      flush=True)
+PY
+done
+du -sh "$JAX_COMPILATION_CACHE_DIR" 2>/dev/null

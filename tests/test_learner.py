@@ -356,6 +356,47 @@ class TestFusedLoopSpans:
         assert gained["league/frozen_dispatches_total"] == frozen
         # their outcomes were fetched, at a boundary or as the call ended
         assert gained["league/report_fetches_total"] >= 1
+        # the LSTM's states are kilobytes: ONE undonated program, whose
+        # opponent is an argument, so every rollout step is two passes
+        assert gained.get("league/shared_pass_dispatches_total", 0.0) == 0
+
+    def test_shared_pass_dispatches_are_the_donated_program_s_live_ones(self, monkeypatch):
+        """ISSUE 33: where the states are donated the live opponent is a
+        program of its own whose rollout KNOWS that both teams play one set
+        of parameters and steps them in one pass: the counter moves by the
+        live dispatches, not by the frozen ones."""
+        from dotaclient_tpu.league import pool as league_pool
+        from dotaclient_tpu.train import fused
+        from dotaclient_tpu.utils import telemetry
+
+        monkeypatch.setattr(fused, "DONATE_ABOVE_BYTES", 0)   # toy states as "most of the chip"
+        cfg = tiny_config()
+        cfg = dataclasses.replace(
+            cfg,
+            env=dataclasses.replace(cfg.env, opponent="league"),
+            ppo=dataclasses.replace(cfg.ppo, rollout_len=4),
+            league=dataclasses.replace(
+                cfg.league, enabled=True, snapshot_every=1, pool_size=2, selfplay_prob=0.5, opponent_hold=1,
+            ),
+        )
+        learner = Learner(cfg, actor="fused", seed=2)
+        assert learner.fused_step.donate and learner.fused_step.live_shares_pass
+        draws, draw = [], learner._league_opponent
+
+        def recorded_draw():
+            params, uid = draw()
+            draws.append(uid)
+            return params, uid
+
+        learner._league_opponent = recorded_draw
+        reg = telemetry.get_registry()
+        before = reg.snapshot()
+        out = learner.train(8)
+        gained = {k: v - before.get(k, 0.0) for k, v in reg.snapshot().items()}
+        live = sum(uid == league_pool.LIVE for uid in draws)
+        assert out["optimizer_steps"] == 8.0 and 0 < live < len(draws) == 8
+        assert gained["league/shared_pass_dispatches_total"] == live
+        assert gained["league/frozen_dispatches_total"] == 8 - live
 
     def test_a_child_span_lies_inside_its_parent(self, run):
         gained, _, _ = run
