@@ -72,6 +72,7 @@ from dotaclient_tpu.train.ppo import (
     train_state_shape,
     train_state_sharding,
 )
+from dotaclient_tpu.utils import telemetry
 
 
 def lane_minibatches(chunk, step, seed: int, n_lanes: int, n_shards: int,
@@ -391,7 +392,15 @@ class FusedStep:
         )
         if kind == "frozen":
             args += (args[0].params,)
-        self._programs[kind] = self._jits[kind].lower(*args).compile()
+        # once a kind, behind __call__'s `k not in self._programs`: the
+        # steady loop never comes here. `compile` is an XLA compile or the
+        # persistent cache's load of the program's code.
+        tel = telemetry.get_registry()
+        with tel.span("fused/build", kind=kind):
+            with tel.span("fused/build/lower"):
+                lowered = self._jits[kind].lower(*args)
+            with tel.span("fused/build/compile"):
+                self._programs[kind] = lowered.compile()
 
     def __call__(self, state, actor_state, opp_params=None):
         kind, args = self._args(state, actor_state, opp_params)

@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import os
 import threading
@@ -90,9 +91,23 @@ from dotaclient_tpu.utils.checkpoint import CheckpointManager, shape_mismatches
 from dotaclient_tpu.utils.metrics import MetricsLogger
 
 
+def _startup_span(init):
+    """``Learner.__init__`` under the span ``startup/learner_init``: a
+    decorator, so that the 600 lines stay the constructor's own text, which
+    the ``host-sync`` and ``thread-ownership`` lints read by its name."""
+
+    @functools.wraps(init)
+    def spanned(self, *args, **kwargs) -> None:
+        with telemetry.get_registry().span("startup/learner_init"):
+            init(self, *args, **kwargs)
+
+    return spanned
+
+
 class Learner:
     """Owns the full training stack for single-host runs."""
 
+    @_startup_span
     def __init__(
         self,
         config: RunConfig,
@@ -107,6 +122,13 @@ class Learner:
         debug_checkify: bool = False,
         metrics_jsonl: Optional[str] = None,
     ) -> None:
+        # the start told by the program itself: what the process spent
+        # before this line (interpreter, imports, accelerator runtime start),
+        # then `startup/learner_init` (the decorator) with one child a stage
+        # below; what no child names is the constructor's self time
+        telemetry.get_registry().gauge("startup/process_age_at_init_s").set(
+            telemetry.process_age_s()
+        )
         # actor mode: "device" (on-device rollout scan feeding the buffered
         # learner), "fused" (rollout + PPO update in ONE XLA program — the
         # fastest synchronous path; train batch = lane set, strictly
@@ -227,9 +249,11 @@ class Learner:
                     f"batch shard count {shards} (minibatches are "
                     f"data-sharded batches); got minibatch size {mb}"
                 )
-        self.policy = make_policy(config.model, config.obs, config.actions)
-        params = init_params(self.policy, jax.random.PRNGKey(config.seed))
-        self.state = init_train_state(params, config.ppo)
+        with reg.span("startup/learner_init/params"):
+            self.policy = make_policy(config.model, config.obs, config.actions)
+            params = init_params(self.policy, jax.random.PRNGKey(config.seed))
+        with reg.span("startup/learner_init/train_state"):
+            self.state = init_train_state(params, config.ppo)
         # The TrainState's sharding tree (params + Adam mirrors replicated
         # under pure DP, TP-partitioned under model_parallel > 1; counters
         # replicated) — the SAME tree make_train_step/make_epoch_step pin
@@ -284,7 +308,8 @@ class Learner:
                 # across optimizer configs — a plain-Adam source seeding a
                 # KL-adaptive run has a different opt_state layout, and the
                 # moments are discarded here anyway.
-                seeded_params, seeded_step = src.restore_weights()
+                with reg.span("startup/learner_init/init_from"):
+                    seeded_params, seeded_step = src.restore_weights()
             except (KeyError, ValueError, TypeError) as e:
                 raise ValueError(
                     f"init_from checkpoint at {init_from!r} does not match "
@@ -310,7 +335,8 @@ class Learner:
             self.ckpt = CheckpointManager(checkpoint_dir)
             if restore and self.ckpt.latest_step() is not None:
                 try:
-                    self.state, _ = self.ckpt.restore(config, self.state)
+                    with reg.span("startup/learner_init/restore"):
+                        self.state, _ = self.ckpt.restore(config, self.state)
                 except ValueError as e:
                     # The only layout-changing PPO knob today is kl_target
                     # (inject_hyperparams adds an lr leaf to opt_state) —
@@ -360,19 +386,20 @@ class Learner:
         # step donates correctly-sharded buffers instead of paying a
         # layout change mid-program. A 1-device mesh is the degenerate
         # case of the same call.
-        self.state = jax.device_put(self.state, self.state_shardings)
-        # Anchor-KL regularizer (PPOConfig.anchor_kl_coef): the anchor is
-        # the policy AS CONSTRUCTED — after --init-from/--restore — i.e.
-        # the transferred policy in a curriculum fine-tune. Copied: the
-        # train step donates/updates the live params.
-        self.anchor_params = (
-            jax.tree.map(jnp.copy, self.state.params)
-            if config.ppo.anchor_kl_coef > 0
-            else None
-        )
+        with reg.span("startup/learner_init/state_commit"):
+            self.state = jax.device_put(self.state, self.state_shardings)
+            # Anchor-KL regularizer (PPOConfig.anchor_kl_coef): the anchor is
+            # the policy AS CONSTRUCTED — after --init-from/--restore — i.e.
+            # the transferred policy in a curriculum fine-tune. Copied: the
+            # train step donates/updates the live params.
+            self.anchor_params = (
+                jax.tree.map(jnp.copy, self.state.params)
+                if config.ppo.anchor_kl_coef > 0
+                else None
+            )
         # instrument_jit (ISSUE 12): per-program compile/retrace counters
-        # + cost analysis once per compile; transparent to dispatch and
-        # to the donation lint (lint/donation.py unwraps the call)
+        # + (traced runs) cost analysis once per compile; transparent to
+        # dispatch and to the donation lint (lint/donation.py unwraps it)
         self.train_step = tracing.instrument_jit(
             make_train_step(
                 self.policy, config, self.mesh,
@@ -481,12 +508,13 @@ class Learner:
         if config.learner.async_snapshots:
             from dotaclient_tpu.train.snapshot import SnapshotEngine
 
-            self._snap_engine = SnapshotEngine(
-                transport=self.transport,
-                wire_dtype=config.transport.wire_dtype,
-                ckpt=self.ckpt,
-                health=self._health,
-            )
+            with reg.span("startup/learner_init/snapshot_engine"):
+                self._snap_engine = SnapshotEngine(
+                    transport=self.transport,
+                    wire_dtype=config.transport.wire_dtype,
+                    ckpt=self.ckpt,
+                    health=self._health,
+                )
             self._snap_copy = tracing.instrument_jit(
                 jax.jit(lambda t: jax.tree.map(jnp.copy, t)), "snap_copy"
             )
@@ -519,10 +547,11 @@ class Learner:
             # the actor state is committed lane-sharded over the learner's
             # mesh (ISSUE 18): games partition over the (dcn×)data axes, so
             # the fused program's pinned shardings are satisfied by layout
-            self.device_actor = DeviceActor(
-                config, self.policy, seed=seed,
-                mesh=self.mesh, mesh_config=config.mesh,
-            )
+            with reg.span("startup/learner_init/device_actor"):
+                self.device_actor = DeviceActor(
+                    config, self.policy, seed=seed,
+                    mesh=self.mesh, mesh_config=config.mesh,
+                )
             self.pool: Any = self.device_actor  # shared stats() surface
             reg = telemetry.get_registry()
             reg.gauge("mesh/lane_shards").set(
@@ -534,10 +563,11 @@ class Learner:
             if mode == "fused":
                 from dotaclient_tpu.train.fused import make_fused_step
 
-                program = make_fused_step(
-                    self.policy, config, self.mesh, self.device_actor,
-                    anchor_params=self.anchor_params,
-                )
+                with reg.span("startup/learner_init/fused_program"):
+                    program = make_fused_step(
+                        self.policy, config, self.mesh, self.device_actor,
+                        anchor_params=self.anchor_params,
+                    )
                 # fixed with the program, whatever later stands in for
                 # `fused_step`: a live dispatch's rollout is one pass
                 self._live_shares_pass = program.live_shares_pass
@@ -575,10 +605,11 @@ class Learner:
                 )
             from dotaclient_tpu.league import OpponentPool
 
-            self.league = OpponentPool(config.league, seed=seed)
-            self.league.maybe_snapshot(
-                self.state.params, int(self.state.version), 0
-            )
+            with reg.span("startup/learner_init/league"):
+                self.league = OpponentPool(config.league, seed=seed)
+                self.league.maybe_snapshot(
+                    self.state.params, int(self.state.version), 0
+                )
             if mode == "vec":
                 # live-params draws must be copies: the train step donates
                 # the learner state, killing any buffer the pool holds
@@ -688,7 +719,8 @@ class Learner:
             and self.ckpt is not None
             and self.ckpt.latest_step() is not None
         ):
-            self._restore_pipeline()
+            with reg.span("startup/learner_init/pipeline_restore"):
+                self._restore_pipeline()
 
     # -- loop --------------------------------------------------------------
 
@@ -1596,29 +1628,40 @@ class Learner:
         in its own thread feeding the transport while this thread trains —
         the staleness filter and version tags do real work here.
         """
-        cfg = self.config
-        epochs = self._steps_per_batch
-        # host-visible counter stride per loop iteration: fused dispatch
-        # batching advances K×epochs steps per call, so the log/checkpoint
-        # boundary windows must widen with it or boundaries get stepped over
-        stride = epochs * (
-            cfg.steps_per_dispatch if self.fused_step is not None else 1
-        )
-        actor_steps = actor_steps_per_iter or cfg.ppo.rollout_len
-        t_start = time.time()
-        frames_trained = 0
-        steps_done = 0
-        self._stall_s = 0.0   # per-call: stall_fraction is per train() call
-        # Mid-run weights publish for the device/fused loops (ISSUE 5):
-        # they never refresh an in-process pool, so consumers on a real
-        # transport (same-host eval actors on the shm lane, socket
-        # listeners) would only ever see the end-of-run weights. In-proc
-        # transports skip it — nobody is listening.
-        publish_midrun = self.device_actor is not None and not isinstance(
-            self.transport, InProcTransport
-        )
+        # One span a call, with `prepare` before the mode loop and `finish`
+        # after it; the loop's own `learner/iteration` spans lie between the
+        # two, as they were. Opened and closed by hand: a decorator or a
+        # second method puts one more Python frame between the entry point
+        # and the fused program's trace, every operation's location grows by
+        # it, and the wide cell's lowering by 0.25-0.5 s a start (PERF.md,
+        # PR 35); a `with` would re-indent the method. An exception leaves
+        # the span unrecorded: it ends the run.
+        call = self.telemetry.span("learner/train")
+        call.__enter__()
+        with self.telemetry.span("learner/train/prepare"):
+            cfg = self.config
+            epochs = self._steps_per_batch
+            # host-visible counter stride per loop iteration: fused dispatch
+            # batching advances K×epochs steps per call, so the log/checkpoint
+            # boundary windows must widen with it or boundaries get stepped over
+            stride = epochs * (
+                cfg.steps_per_dispatch if self.fused_step is not None else 1
+            )
+            actor_steps = actor_steps_per_iter or cfg.ppo.rollout_len
+            t_start = time.time()
+            frames_trained = 0
+            steps_done = 0
+            self._stall_s = 0.0   # per-call: stall_fraction is per train() call
+            # Mid-run weights publish for the device/fused loops (ISSUE 5):
+            # they never refresh an in-process pool, so consumers on a real
+            # transport (same-host eval actors on the shm lane, socket
+            # listeners) would only ever see the end-of-run weights. In-proc
+            # transports skip it — nobody is listening.
+            publish_midrun = self.device_actor is not None and not isinstance(
+                self.transport, InProcTransport
+            )
 
-        boundaries = self.telemetry.counter("learner/boundaries_total")
+            boundaries = self.telemetry.counter("learner/boundaries_total")
 
         def after_step(m, frames: Optional[int] = None) -> int:
             """Boundary side effects for one loop iteration. Returns the
@@ -1934,65 +1977,67 @@ class Learner:
                         if steps_done >= num_steps or self._stop_requested:
                             break
         _run_mode_loop()
-        while True:
-            # End-of-call prefetch flush: a batch staged behind the final
-            # dispatch was never trained on — return it to the ring so the
-            # final checkpoint (and the next train() call) see it.
-            if self.buffer is not None:
-                self._flush_prefetch()
-            self._dispatch_inflight = False
-            # Async boundary jobs still in flight must land before the tail
-            # reads/mutates the shared stats below (and any deferred
-            # best-model save applies); the snapshot thread is idle
-            # afterwards. Pending health verdicts flush first so the
-            # tail's publish/save gates see the final steps' verdicts.
-            self._flush_health()
+        # drains, the closing publish of all weights, the forced save
+        with self.telemetry.span("learner/train/finish"):
+            while True:
+                # End-of-call prefetch flush: a batch staged behind the final
+                # dispatch was never trained on — return it to the ring so the
+                # final checkpoint (and the next train() call) see it.
+                if self.buffer is not None:
+                    self._flush_prefetch()
+                self._dispatch_inflight = False
+                # Async boundary jobs still in flight must land before the tail
+                # reads/mutates the shared stats below (and any deferred
+                # best-model save applies); the snapshot thread is idle
+                # afterwards. Pending health verdicts flush first so the
+                # tail's publish/save gates see the final steps' verdicts.
+                self._flush_health()
+                self._drain_snapshots()
+                # Tail rollback check (ISSUE 6): on a fast run the engine can
+                # fold the poisoned verdict only AFTER the loop hit its step
+                # target — containment already held (the gates were latched
+                # before anything left the learner), but the run must not be
+                # SEALED on poisoned params: roll back and re-enter the loop
+                # so it still completes to the exact target step. Bounded by
+                # health.max_rollbacks like every rollback.
+                rewound = self._maybe_rollback()
+                if not rewound or self._stop_requested:
+                    break
+                steps_done -= rewound
+                _run_mode_loop()
+            if self.device_actor is not None:
+                # End-of-call drain: the windowed stats cover this train() call
+                # (the demo's block cadence) — the second best-model hook, so
+                # peak capture works even when log_every never fires mid-call.
+                self._maybe_save_best(self.device_actor.drain_stats())
+            elif self.pool is not None:
+                self._maybe_save_best(self.pool.drain_stats())
+            if self.league is not None:
+                self._flush_league_reports()
+            # Publish final weights for out-of-process actors (cluster parity);
+            # drain so they reach the wire before the caller closes transports.
+            self._publish_weights()
             self._drain_snapshots()
-            # Tail rollback check (ISSUE 6): on a fast run the engine can
-            # fold the poisoned verdict only AFTER the loop hit its step
-            # target — containment already held (the gates were latched
-            # before anything left the learner), but the run must not be
-            # SEALED on poisoned params: roll back and re-enter the loop
-            # so it still completes to the exact target step. Bounded by
-            # health.max_rollbacks like every rollback.
-            rewound = self._maybe_rollback()
-            if not rewound or self._stop_requested:
-                break
-            steps_done -= rewound
-            _run_mode_loop()
-        if self.device_actor is not None:
-            # End-of-call drain: the windowed stats cover this train() call
-            # (the demo's block cadence) — the second best-model hook, so
-            # peak capture works even when log_every never fires mid-call.
-            self._maybe_save_best(self.device_actor.drain_stats())
-        elif self.pool is not None:
-            self._maybe_save_best(self.pool.drain_stats())
-        if self.league is not None:
-            self._flush_league_reports()
-        # Publish final weights for out-of-process actors (cluster parity);
-        # drain so they reach the wire before the caller closes transports.
-        self._publish_weights()
-        self._drain_snapshots()
-        if self.ckpt:
-            # The forced end-of-run/drain save stays SYNC (the snapshot
-            # thread is drained and idle): it lands at the EXACT stop step
-            # and an I/O failure here raises loudly (ISSUE 4 policy). It is
-            # NEVER health-blocked — exact-step resume outranks hygiene —
-            # but only a verdict-clean state earns the last_good mark (a
-            # divergence detected in the final steps restores through the
-            # guardian on the next --restore instead). Sync mode folds the
-            # final batch's verdict first — its last log boundary may
-            # predate the final steps.
-            self._sync_fold_latest()
-            self.ckpt.save(
-                self.state, cfg, force=True,
-                pipeline=self._pipeline_state(),
-                mark_good=(
-                    self._health is not None
-                    and self._health.unhealthy is None
-                ),
-            )
-            self.ckpt.wait()
+            if self.ckpt:
+                # The forced end-of-run/drain save stays SYNC (the snapshot
+                # thread is drained and idle): it lands at the EXACT stop step
+                # and an I/O failure here raises loudly (ISSUE 4 policy). It is
+                # NEVER health-blocked — exact-step resume outranks hygiene —
+                # but only a verdict-clean state earns the last_good mark (a
+                # divergence detected in the final steps restores through the
+                # guardian on the next --restore instead). Sync mode folds the
+                # final batch's verdict first — its last log boundary may
+                # predate the final steps.
+                self._sync_fold_latest()
+                self.ckpt.save(
+                    self.state, cfg, force=True,
+                    pipeline=self._pipeline_state(),
+                    mark_good=(
+                        self._health is not None
+                        and self._health.unhealthy is None
+                    ),
+                )
+                self.ckpt.wait()
         elapsed = time.time() - t_start
         actor_stats = self.pool.stats() if self.pool is not None else {}
         out = {
@@ -2006,8 +2051,10 @@ class Learner:
         }
         self._publish_pipeline_gauges()
         # Close the machine-readable record with a final full snapshot (the
-        # end-of-run publish/checkpoint spans land here); console is spared.
+        # end-of-run publish/checkpoint spans and `learner/train/finish` land
+        # here; `learner/train` itself closes after it); console is spared.
         self.metrics.log_files_only(self._host_step, out)
+        call.__exit__(None, None, None)
         return out
 
 

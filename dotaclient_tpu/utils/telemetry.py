@@ -104,6 +104,7 @@ __all__ = [
     "TensorBoardSink",
     "get_registry",
     "load_jsonl",
+    "process_age_s",
     "trace_sample_n",
 ]
 
@@ -352,6 +353,23 @@ _GLOBAL = Registry()
 
 def get_registry() -> Registry:
     return _GLOBAL
+
+
+def process_age_s() -> float:
+    """Seconds since the kernel started this process (``/proc/self/stat``
+    start time against the boot clock, as the benchmark's
+    ``harness/device.py`` reads it for ``setup_s``): the interpreter's start,
+    every import and the accelerator runtime's start are in it. The learner
+    sets ``startup/process_age_at_init_s`` from it at its constructor's
+    first line; 0 where the kernel keeps no such record."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command name (field 2) may hold spaces: split after its ")"
+            fields = f.read().rsplit(")", 1)[1].split()
+        started = int(fields[19]) / os.sysconf("SC_CLK_TCK")   # field 22
+        return time.clock_gettime(time.CLOCK_BOOTTIME) - started
+    except (OSError, ValueError, IndexError, AttributeError):
+        return 0.0
 
 
 # -- sinks -------------------------------------------------------------------

@@ -45,9 +45,15 @@ attribution — PAPERS.md). This module is that instrument:
 * **Device observability hooks.** :func:`instrument_jit` wraps the jit
   entry points the learner/buffer/serve own: per-program compile and
   retrace counters (``compile/<program>/...`` + the process-wide
-  ``compile/{compiles,retraces}_total``), elapsed compile time, and
-  XLA cost analysis (flops / bytes accessed) logged ONCE per compile —
-  never per step. :func:`update_memory_gauges` reads
+  ``compile/{compiles,retraces}_total``), elapsed compile time, and,
+  with a tracer configured, XLA cost analysis (flops / bytes accessed)
+  logged ONCE per compile — never per step. What those wrappers cannot
+  see (eager one-operation programs, a compile told from a cache load)
+  comes from JAX's own events: :func:`ensure_metrics` installs ONE
+  ``jax.monitoring`` listener pair a process behind
+  ``compile/{trace_s,lower_s,backend_s,cache_load_s}_total``,
+  ``compile/programs_total`` and ``compile/cache_{hits,misses}_total``.
+  :func:`update_memory_gauges` reads
   ``jax.local_devices()`` memory stats into ``mem/hbm_peak_bytes``,
   degrading to 0 on backends (CPU) that report none.
 """
@@ -56,10 +62,11 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from dotaclient_tpu.utils import telemetry
 
@@ -94,10 +101,59 @@ def now() -> float:
 # ONE traced layout per rollout structure instead of one per blob length.
 TRACE_WIRE_LEN = 192
 
+# JAX's own compile events (``jax.monitoring``) feed process-wide counters.
+# A duration event adds its seconds; the backend event also counts the
+# program (an XLA compile or, on a persistent-cache hit, the load), so after
+# a warm-up ``compile/programs_total`` moving means "a step recompiled",
+# whichever program it was: eager one-operation programs and uninstrumented
+# jits included, which ``InstrumentedJit`` cannot see.
+_listener_lock = threading.Lock()
+_listener_installed = False
+
+
+def _install_compile_listener() -> None:
+    """One listener pair a process, however many learners are built, feeding
+    the process-wide registry. Never what imports JAX: a jax-free tool that
+    configures tracing compiles nothing to count."""
+    global _listener_installed
+    monitoring = getattr(sys.modules.get("jax"), "monitoring", None)
+    if monitoring is None:
+        return
+    with _listener_lock:
+        if _listener_installed:
+            return
+        _listener_installed = True
+    # counters are resolved by name at each event: compiles are rare, and a
+    # registry cleared for test isolation must not orphan them
+    reg = telemetry.get_registry()
+
+    def on_seconds(event: str, duration: float, **_: Any) -> None:
+        if event == "/jax/core/compile/jaxpr_trace_duration":
+            reg.counter("compile/trace_s_total").inc(duration)
+        elif event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            reg.counter("compile/lower_s_total").inc(duration)
+        elif event == "/jax/core/compile/backend_compile_duration":
+            reg.counter("compile/backend_s_total").inc(duration)
+            reg.counter("compile/programs_total").inc()
+        elif event == "/jax/compilation_cache/cache_retrieval_time_sec":
+            reg.counter("compile/cache_load_s_total").inc(duration)
+
+    def on_event(event: str, **_: Any) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            reg.counter("compile/cache_hits_total").inc()
+        elif event == "/jax/compilation_cache/cache_misses":
+            reg.counter("compile/cache_misses_total").inc()
+
+    monitoring.register_event_duration_secs_listener(on_seconds)
+    monitoring.register_event_listener(on_event)
+
+
 def ensure_metrics(registry: Optional[telemetry.Registry] = None) -> None:
     """Eager-create the trace/compile/mem keys so
     `check_telemetry_schema.py --require-trace` validates any learner
-    JSONL deterministically (zeros when nothing fired)."""
+    JSONL deterministically (zeros when nothing fired), and install the
+    process's one ``jax.monitoring`` listener pair behind the seven
+    whole-process compile counters."""
     reg = registry if registry is not None else telemetry.get_registry()
     for key in (
         "trace/emitted_total",
@@ -105,9 +161,17 @@ def ensure_metrics(registry: Optional[telemetry.Registry] = None) -> None:
         "compile/compiles_total",
         "compile/retraces_total",
         "compile/compile_time_s_total",
+        "compile/trace_s_total",
+        "compile/lower_s_total",
+        "compile/backend_s_total",
+        "compile/programs_total",
+        "compile/cache_load_s_total",
+        "compile/cache_hits_total",
+        "compile/cache_misses_total",
     ):
         reg.counter(key)
     reg.gauge("mem/hbm_peak_bytes")
+    _install_compile_listener()
 
 
 # -- trace records -----------------------------------------------------------
@@ -410,9 +474,11 @@ class InstrumentedJit:
     On a compile (cache grew — or, when the backend exposes no cache
     probe, the wrapper's first call) the per-program and process-wide
     counters advance, elapsed time (trace + compile + first execution;
-    compile dominates) is recorded, and XLA cost analysis runs ONCE —
-    never per step. ``retraces`` = compiles beyond this wrapper's first
-    (the "a shape bump recompiled the program" signal).
+    compile dominates) is recorded, and, where a tracer is configured to
+    read it, XLA cost analysis runs ONCE (span ``compile/cost_analysis``) —
+    never per step, and never with tracing off. ``retraces`` = compiles
+    beyond this wrapper's first (the "a shape bump recompiled the program"
+    signal).
 
     Attribute access (``.lower``, ``._cache_size``) delegates to the
     wrapped function, so call sites that introspect the jit keep
@@ -428,6 +494,7 @@ class InstrumentedJit:
         reg = registry if registry is not None else telemetry.get_registry()
         self._fn = fn
         self._name = name
+        self._reg = reg
         self._seen = 0
         self._compiles = reg.counter("compile/compiles_total")
         self._retraces = reg.counter("compile/retraces_total")
@@ -466,6 +533,25 @@ class InstrumentedJit:
             self._p_retraces.inc()
         self._time.inc(elapsed)
         self._p_last.set(elapsed)
+        tracer = get()
+        if tracer is not None:
+            # a second trace and lowering of the whole program: it runs only
+            # where its result has a reader, and under a span, so whoever
+            # turns tracing on sees what it costs
+            with self._reg.span("compile/cost_analysis"):
+                flops, bytes_accessed = self._cost(args, kwargs)
+            tracer.emit(
+                "compile",
+                program=self._name,
+                n=self._seen,
+                elapsed_s=round(elapsed, 6),
+                flops=flops,
+                bytes_accessed=bytes_accessed,
+            )
+
+    def _cost(self, args: tuple, kwargs: dict) -> Tuple[float, float]:
+        """(flops, bytes accessed) by XLA's cost analysis, zeros where it
+        cannot tell."""
         flops = bytes_accessed = 0.0
         try:
             # abstract re-trace only (no second backend compile); on a
@@ -482,16 +568,7 @@ class InstrumentedJit:
                 )
         except Exception:  # noqa: BLE001 - analysis is best-effort
             pass
-        tracer = get()
-        if tracer is not None:
-            tracer.emit(
-                "compile",
-                program=self._name,
-                n=self._seen,
-                elapsed_s=round(elapsed, 6),
-                flops=flops,
-                bytes_accessed=bytes_accessed,
-            )
+        return flops, bytes_accessed
 
     def __getattr__(self, item: str) -> Any:
         return getattr(object.__getattribute__(self, "_fn"), item)

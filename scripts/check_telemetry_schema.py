@@ -227,17 +227,25 @@ ROUTER_KEYS = (
 
 # Pipeline tracing + device observability (ISSUE 12). Validated with
 # --require-trace against ANY learner run's JSONL: the Learner
-# eager-creates all six at construction (tracing.ensure_metrics) — the
-# trace emit/drop counters stay 0 with tracing off, the compile counters
-# track the instrumented jit entry points regardless of tracing, and
-# mem/hbm_peak_bytes degrades to 0 on backends without allocator stats
-# (CPU).
+# eager-creates all of them at construction (tracing.ensure_metrics) —
+# the trace emit/drop counters stay 0 with tracing off, the compile
+# counters track the instrumented jit entry points and (ISSUE 35, from
+# JAX's own events) every program of the process regardless of tracing,
+# and mem/hbm_peak_bytes degrades to 0 on backends without allocator
+# stats (CPU).
 TRACE_KEYS = (
     "trace/emitted_total",          # trace events written to --trace-jsonl
     "trace/dropped_total",          # events dropped (writer behind / queue full)
     "compile/compiles_total",       # XLA compiles across instrumented programs
     "compile/retraces_total",       # compiles beyond each program's first
     "compile/compile_time_s_total", # cumulative seconds spent compiling
+    "compile/trace_s_total",        # every program: seconds tracing to a jaxpr
+    "compile/lower_s_total",        # every program: seconds lowering to MLIR
+    "compile/backend_s_total",      # every program: XLA compile or cache load
+    "compile/programs_total",       # programs compiled or loaded
+    "compile/cache_load_s_total",   # seconds reading the persistent cache
+    "compile/cache_hits_total",     # persistent-cache hits
+    "compile/cache_misses_total",   # persistent-cache misses (entries written)
     "mem/hbm_peak_bytes",           # device allocator peak (max over devices)
 )
 
