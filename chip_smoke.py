@@ -22,6 +22,11 @@ failure, and prints its compile seconds and steady seconds apart:
   d. transformer core   fused, ``--core transformer``, two dispatches
   e. the Pallas kernel  ``lstm_sequence_pallas(interpret=False)`` against
                         ``lstm_sequence_reference`` at H=128 and H=512
+  f. the KDA step kernel ``kda_step_pallas(interpret=False)`` through
+                        ``kimilinear.delta_rule_step`` against
+                        ``kimilinear.delta_rule_chunk`` at one step, at the
+                        Kimi-Linear cell's shapes (40 lanes, 32 heads of
+                        128 x 128), the state donated
 
 What it claims: the programs compile for the device, values are finite,
 counters add up, every request is answered with an action legal under its
@@ -64,13 +69,13 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 FULL = dict(
     n_envs=128, ppo=None, buffer="capacity_rollouts=512,min_fill=128",
     rollout_len=16, batch_rollouts=32, games=8, ticks=20,
-    kernel_b=32, kernel_t=16,
+    kernel_b=32, kernel_t=16, kda_lanes=40, kda_heads=32,
 )
 REHEARSAL = dict(
     n_envs=12, ppo="rollout_len=4,batch_rollouts=4",
     buffer="capacity_rollouts=32,min_fill=8",
     rollout_len=4, batch_rollouts=4, games=4, ticks=5,
-    kernel_b=8, kernel_t=4,
+    kernel_b=8, kernel_t=4, kda_lanes=3, kda_heads=2,
 )
 # One log boundary (log_every=10), so the record holds a loss. Both sizes
 # collect n_envs rollouts at a time and n_envs / batch_rollouts does not
@@ -487,6 +492,57 @@ def phase_kernel(size: Dict[str, Any], interpret: bool) -> Dict[str, Any]:
     return got
 
 
+# -- phase f: the KDA step kernel ----------------------------------------------
+
+
+def phase_kda_step(size: Dict[str, Any], interpret: bool) -> Dict[str, Any]:
+    """One step of the delta rule through the kernel against the closed form
+    at ``Precision.HIGHEST`` (whose few-rows branch multiplies and reduces in
+    float32 whatever the precision: the kernel has no product to round), a
+    quarter of the lanes void with a poisoned state; the donated state's
+    buffer is the new state's."""
+    from dotaclient_tpu.models.kimilinear import delta_rule_chunk, delta_rule_step
+
+    B, h, d = size["kda_lanes"], size["kda_heads"], 128
+    rng = np.random.default_rng(36)
+
+    def f(*shape: int) -> np.ndarray:
+        return rng.standard_normal(shape).astype(np.float32)
+
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+    carried = rng.random(B) < 0.75
+    S0 = np.where(carried[:, None, None, None], f(B, h, d, d) * 0.5, np.float32(1e30))
+    rows = tuple(jnp.asarray(x) for x in (
+        unit(f(B, 1, h, d)) / math.sqrt(d), unit(f(B, 1, h, d)), f(B, 1, h, d),
+        -np.abs(f(B, 1, h, d)) * 0.3, 1.0 / (1.0 + np.exp(-f(B, 1, h))),
+    ))
+    seg, carried = jnp.zeros((B, 1), jnp.int32), jnp.asarray(carried)
+    with jax.default_matmul_precision("highest"):
+        o_r, S_r = jax.block_until_ready(jax.jit(delta_rule_chunk)(*rows, jnp.asarray(S0), seg, carried))
+    step = jax.jit(
+        lambda S, *r: delta_rule_step(interpret, *r, S, seg, carried), donate_argnums=(0,)
+    ).lower(jnp.asarray(S0), *rows).compile()
+    got = []
+    for _ in range(2):
+        state = jnp.asarray(S0)
+        held = state.unsafe_buffer_pointer()
+        o, S = jax.block_until_ready(step(state, *rows))
+        check(state.is_deleted(), "the donated state was not taken")
+        got.append((np.asarray(o), np.asarray(S), S.unsafe_buffer_pointer() == held))
+    (o, S, in_place), (o2, S2, _) = got
+    check(bool(np.isfinite(o).all() and np.isfinite(S).all()), "kda step: not finite")
+    check(bool((o == o2).all() and (S == S2).all()), "kda step: not deterministic")
+    err_o, err_S = float(np.abs(o - np.asarray(o_r)).max()), float(np.abs(S - np.asarray(S_r)).max())
+    check(err_o <= 1e-5 and err_S <= 1e-5, f"kda step: max |kernel - closed form| = {err_o:.3e} (o), {err_S:.3e} (S) > 1e-5")
+    # compiled for a TPU the program names its alias; the interpreter's rehearsal has no custom call to name
+    aliased = "output_to_operand_aliasing" in step.as_text()
+    check(interpret or (aliased and in_place), f"kda step: state not updated in place (aliased={aliased}, same buffer={in_place})")
+    return {
+        "lanes": B, "heads": h, "o_max_abs_err": float(f"{err_o:.3e}"), "S_max_abs_err": float(f"{err_S:.3e}"),
+        "output_aliases_input": aliased, "same_buffer": bool(in_place),
+    }
+
+
 # -- driver ------------------------------------------------------------------
 
 
@@ -545,6 +601,9 @@ def main(argv: Any = None) -> int:
             ("--core", "transformer", "--steps-per-dispatch", "5"),
         )),
         "e": ("pallas lstm kernel", lambda: phase_kernel(
+            size, interpret=args.rehearse_cpu
+        )),
+        "f": ("pallas kda step kernel", lambda: phase_kda_step(
             size, interpret=args.rehearse_cpu
         )),
     }

@@ -43,7 +43,33 @@ later steps and every pair (i, j) of different episodes drop out by
 ``afmoe.chunk_positions``' segment, and the convolution's taps do not cross
 it. ``G_i / G_j`` is ``exp(g_i - g_j)`` a pair and channel, never a quotient of
 two exponentials (``G_j`` underflows inside a chunk where a channel forgets
-fast). The rollout's step is this function at T = 1.
+fast).
+
+**One step** (T = 1) is the same formula with no pair left in it. With
+``keep`` = the lane carries a state (``pos0 > 0`` and the step starts no
+episode) and ``G = exp(log alpha)``, a head of a lane, float32 throughout:
+
+  ks = keep * S_0^T (G * k)                      [d_v]
+  u  = beta * (v - ks)                           [d_v]
+  S_1 = keep * Diag(G) S_0 + k u^T
+  o  = keep * S_0^T (G * q) + (q . k) u          ( = S_1^T q )
+
+**Where each path runs** (``_recurrence``; two needs, two paths that share
+this formula and no code): a chunk wants the closed form and its gradient,
+so the learner's pass, and every T > 1, is ``delta_rule_chunk``; a rollout
+step wants each state touched once, so where T = 1, the heads are square
+and fill whole lanes (``d_k`` a multiple of 128: the published 128, not the
+toy widths of ``tests/`` and of ``serve/engine.py``'s tests) and the
+program is lowered for a TPU, it is ``delta_rule_step``: the Pallas kernel
+of ``ops/pallas/kda_step.py``, which reads a head's 64 KiB into VMEM, does
+all four lines there and writes ``S_1`` where ``S_0`` lay (XLA's form reads
+every state twice and writes it once: the update needs the whole of ``ks``
+first). ``step_takes_kernel`` is that choice as a predicate, made from what
+the code can see (the widths, the platform of the lowering through
+``jax.lax.platform_dependent``: ONE traced program serves a CPU rehearsal
+and the chip) and from no option; everywhere else a step is
+``delta_rule_chunk`` at T = 1. The learner counts the kernel's layer-steps
+by the same predicate (``kda/kernel_steps_total``).
 
 **MLA layer** (no query compression, no rotation: ``q_lora_rank`` null,
 ``mla_use_nope``):
@@ -76,9 +102,10 @@ counters, states and convolution rows (the start's own buffers, float32
 already) beside the END's latent rings.
 
 Scopes inside ``policy_core``: ``core_kda`` (projections, convolution,
-gates, output) with ``core_kda_state`` (the recurrence and its readout)
-inside it; ``core_attn_latent`` with ``core_latent_attend`` (the products
-against the ring) inside it; ``core_cache_write``; the FFNs' ``core_router``,
+gates, output) with ``core_kda_state`` (the recurrence and its readout; the
+kernel's call carries it in its name) inside it; ``core_attn_latent`` with
+``core_latent_attend`` (the products against the ring) inside it;
+``core_cache_write``; the FFNs' ``core_router``,
 ``core_experts_routed``, ``core_expert_shared``, ``core_dense_ffn``. Sown
 into ``losses`` for the learner's gauges (``train/ppo._kda_gauges``):
 ``kda_decay``, ``kda_beta``, ``kda_state_sq`` a KDA layer, and
@@ -88,6 +115,7 @@ step.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, List
 
@@ -102,6 +130,7 @@ from dotaclient_tpu.models.afmoe import (
     layer_is_dense, layer_is_full, reset, ring_masks, write_rows,
 )
 from dotaclient_tpu.models.lanes import by_lane_block
+from dotaclient_tpu.ops.pallas import kda_step
 
 _NEG = -1e30
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -239,6 +268,71 @@ def delta_rule_chunk(q, k, v, log_alpha, beta, S0, seg, carried):
     return o, S
 
 
+# -- the delta rule over one step: the kernel's path ---------------------------------
+
+
+def step_takes_kernel(cfg: ModelConfig, platform: str) -> bool:
+    """Whether a single step (T = 1) of this configuration's KDA layers is the
+    Pallas kernel in a program lowered for ``platform``: the ONE predicate, the
+    model's (``_recurrence``) and the learner's for ``kda/kernel_steps_total``.
+    Mosaic compiles for the TPU alone, the kernel takes square heads of whole
+    lanes, and it stands where the closed form takes its few-rows branch (a
+    step's two rows a head are no product there)."""
+    return platform == "tpu" and kda_step.takes(cfg.kda_head_dim, cfg.kda_head_dim) and 2 < afmoe._MXU_ROWS
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def delta_rule_step(interpret, q, k, v, log_alpha, beta, S0, seg, carried):
+    """``delta_rule_chunk`` at T = 1 through ``ops/pallas/kda_step.py``
+    (``interpret`` only where there is no TPU to compile for): the same
+    arguments and results, the state read once and written once where it
+    lies. The program never differentiates a step; where a test does, the
+    backward is the closed form's."""
+    keep = carried & (seg[:, 0] == 0)                    # the step sees S0: a carried state, no episode start
+    o, S = kda_step.kda_step_pallas(
+        q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0], S0, keep, interpret=interpret
+    )
+    return o[:, None], S
+
+
+def _step_fwd(interpret, *args):
+    return delta_rule_step(interpret, *args), args
+
+
+def _step_bwd(interpret, residuals, cotangents):
+    *floats, seg, carried = residuals
+    _, vjp = jax.vjp(lambda *f: delta_rule_chunk(*f, seg, carried), *floats)
+    return (*vjp(cotangents), None, None)
+
+
+delta_rule_step.defvjp(_step_fwd, _step_bwd)
+
+
+# ``platform_dependent`` traces BOTH paths at every call (a step has one a KDA layer and lane set, and a
+# start traces the rollout several times): under ``jit`` a path is traced once a shape and lowered once a program
+_step_traced_once = jax.jit(delta_rule_step, static_argnums=0)
+_chunk_traced_once = jax.jit(delta_rule_chunk)
+
+
+def _recurrence(cfg: ModelConfig, T: int):
+    """``(S0, q, k, v, log_alpha, beta, seg, carried) -> (o, S)`` over one lane
+    block: the closed form; for a single step the kernel where the program is
+    lowered for a platform on which ``step_takes_kernel`` (interpreted
+    anywhere but on a TPU: a test's case). One traced program serves the CPU
+    and the chip."""
+
+    def recur(S0, *rows):
+        args = (*rows[:5], S0, *rows[5:])
+        kernel = {
+            p: functools.partial(_step_traced_once, p != "tpu") for p in ("tpu", "cpu") if T == 1 and step_takes_kernel(cfg, p)
+        }
+        if not kernel:
+            return delta_rule_chunk(*args)
+        return jax.lax.platform_dependent(*args, default=_chunk_traced_once, **kernel)
+
+    return recur
+
+
 def _l2norm(x: jnp.ndarray) -> jnp.ndarray:
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
 
@@ -302,12 +396,7 @@ class KDA(nn.Module):
             beta = nn.sigmoid(_dense(cfg, nh, "wb")(a).astype(jnp.float32))
             gate = _dense(cfg, W, "wg_up")(_dense(cfg, D, "wg_down")(a)).astype(jnp.float32)
             with jax.named_scope("core_kda_state"):
-                o, S = by_lane_block(
-                    lambda S0, q, k, v, log_alpha, beta, seg, carried: delta_rule_chunk(
-                        q, k, v, log_alpha, beta, S0, seg, carried
-                    ),
-                    S0, q, k, v, log_alpha, beta, seg, carried,
-                )
+                o, S = by_lane_block(_recurrence(cfg, T), S0, q, k, v, log_alpha, beta, seg, carried)
             out = RMSNorm(cfg, name="o_norm")(o) * nn.sigmoid(gate.reshape(B, T, nh, D))
             mix = _dense(cfg, cfg.hidden_dim, "wo")(out.reshape(B, T, W).astype(dtype))
         self.sow("losses", "kda_decay", jnp.exp(log_alpha).mean())

@@ -1,11 +1,13 @@
-"""Pallas fused LSTM-sequence kernel (the one custom-kernel candidate,
-SURVEY.md §2.2 row 1 / §7 hard-part 1).
+"""Pallas fused LSTM-sequence kernel (the first custom-kernel candidate,
+SURVEY.md §2.2 row 1 / §7 hard-part 1; the second, `kda_step.py`, is on the
+Kimi-Linear core's rollout path since PR 36).
 
-The DEFAULT core stays `nn.scan` (BASELINE.md "Pallas decision"); no path in
-the package calls this kernel. It exists as the alternative for *wider*
-cores, where keeping the weights pinned in VMEM across all T steps should
-pay: one `pallas_call` runs the whole sequence, double-reading nothing from
-HBM. Its speed against the scan: not measured on chip in this round.
+The DEFAULT core stays `nn.scan` (BASELINE.md "Pallas decision"); this
+kernel is still called by no path in the package. It exists as the
+alternative for *wider* cores, where keeping the weights pinned in VMEM
+across all T steps should pay: one `pallas_call` runs the whole sequence,
+double-reading nothing from HBM. Its speed against the scan: not measured
+on chip in this round.
 
 On the chip (TPU v5e, jax 0.9.0, libtpu 0.0.34; chip_smoke.py phase e, PR
 21): Mosaic accepts the kernel as written — no grid, no `BlockSpec`, the

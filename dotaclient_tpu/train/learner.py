@@ -1842,6 +1842,7 @@ class Learner:
                 dispatches = tel.counter("learner/dispatches_total")
                 frozen = tel.counter("league/frozen_dispatches_total")
                 shared = tel.counter("league/shared_pass_dispatches_total")
+                kda_ran, kda_steps = tel.counter("kda/kernel_steps_total"), _kda_kernel_steps(cfg, self.mesh)
                 while steps_done < num_steps and not self._stop_requested:
                     with tel.span("learner/iteration", step=self._host_step):
                         with tel.span("learner/league_draw"):
@@ -1857,6 +1858,7 @@ class Learner:
                             "dispatch_inflight", time.perf_counter() - t0
                         )
                         dispatches.inc()
+                        kda_ran.inc(kda_steps)
                         if opp_idx != league_pool.LIVE:
                             frozen.inc()
                         elif self._live_shares_pass:
@@ -2550,6 +2552,23 @@ def main(argv=None) -> Dict[str, float]:
         flush=True,
     )
     return stats
+
+
+def _kda_kernel_steps(config: RunConfig, mesh) -> int:
+    """Delta-rule (KDA) layer-steps that ONE fused dispatch's rollout runs as
+    the Pallas kernel (``ops/pallas/kda_step.py``): layers x rollout steps
+    where the core's own predicate, the one its model traces by, takes the
+    kernel on the platform the program is lowered for; 0 where the closed
+    form runs (a CPU, toy widths) and for a core without such layers.
+    ``kda/kernel_steps_total`` over ``learner/dispatches_total`` x layers x
+    steps is the share of dispatches in which the kernel was engaged."""
+    from dotaclient_tpu.models.policy import resident_core
+
+    model = config.model
+    core = resident_core(model) if model.carry_stays_on_chip else None
+    if not hasattr(core, "step_takes_kernel") or not core.step_takes_kernel(model, mesh.devices.flat[0].platform):
+        return 0
+    return len(core.kda_layers(model)) * config.ppo.rollout_len * config.steps_per_dispatch
 
 
 if __name__ == "__main__":

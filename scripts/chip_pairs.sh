@@ -10,7 +10,9 @@
 # this checkout: two checkouts never meet in it) and unbounded, so each tree compiles cold once; with
 # PAIRS_CACHE=default in the environment it is left where the program and the machine put it, as the driver's
 # runs have it (PR 35, Step 0). Result lines and the program's last JSONL line land under chiprun_out/<PAIRS_OUT or
-# pr_pairs>/; a run that exits non-zero has its failures and the last 30 lines of its stderr printed.
+# pr_pairs>/; a run that exits non-zero has its failures and the last 30 lines of its stderr printed. With
+# PAIRS_TRACE_TOOL=<script> a traced step keeps its trace and `python3 <script> <tree> <cell>` reads it there (PR 36:
+# a scope's time split by phase, by hand; benchmark_out/ does not travel back, what the tool prints does).
 cell=$1; limit=$2; need=$3; shift 3
 root=$(cd "$(dirname "$0")/.." && pwd)
 out=$root/chiprun_out/${PAIRS_OUT:-pr_pairs}/$cell; mkdir -p "$out"
@@ -25,7 +27,9 @@ for step in "$@"; do
   [ $side = C ] || [ $side = T ] && tree=change
   [ $side = T ] || [ $side = Q ] && trace=1 && seed=$(( seed + 13 ))
   mv $tree cur
-  ( cd cur && timeout 1700 python3 benchmark/run.py --workload $cell --seed $seed --seconds 20 --trace $trace > $out/$step.out 2> $out/$step.err; echo "rc=$?" >> $out/$step.out )
+  keep=; [ $trace = 1 ] && [ -n "$PAIRS_TRACE_TOOL" ] && keep=--keep-trace
+  ( cd cur && timeout 1700 python3 benchmark/run.py --workload $cell --seed $seed --seconds 20 --trace $trace $keep > $out/$step.out 2> $out/$step.err; echo "rc=$?" >> $out/$step.out )
+  [ -n "$keep" ] && { python3 "$PAIRS_TRACE_TOOL" cur $cell 2>&1 | tee $out/$step.trace_tool; rm -rf cur/benchmark_out/$cell/trace; }
   m=cur/benchmark_out/$cell/metrics.jsonl
   [ -f $m ] && tail -1 $m > $out/$step.metrics && cp $m $out/$step.jsonl
   mv cur $tree

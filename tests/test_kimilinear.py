@@ -571,6 +571,66 @@ def test_a_step_at_the_cells_widths_reads_states_and_ring_as_they_lie(one_chip, 
         assert found["rounded"] >= KDA_LAYERS
 
 
+def test_the_step_compiled_for_the_chip_is_one_kernel_a_kda_layer_under_the_state_s_scope(one_chip):
+    """ISSUE 36: at the cell's widths a step lowered for the v5e calls the
+    Pallas kernel once a KDA layer, each call named under ``core_kda_state``
+    (what ``kda_state_roofline``'s reader matches as a whole path segment:
+    an unscoped call would leave the roofline without its time), writes the
+    state where it read it, and leaves no multiply-and-reduce over a state;
+    the chunk the learner runs keeps the closed form."""
+    from benchmark.harness import cells, program
+    from dotaclient_tpu.models.policy import dummy_obs_batch, make_policy
+
+    cfg = program.build_run_config(cells.load_cell("kimi-linear-5v5-ep32.fused-selfplay-anycore"), seed=0, rehearsal=False)
+    assert kimilinear.step_takes_kernel(cfg.model, "tpu") and not kimilinear.step_takes_kernel(cfg.model, "cpu")
+    policy, lanes = make_policy(cfg.model, cfg.obs, cfg.actions), 5
+    shapes = jax.eval_shape(lambda: (
+        init_params(policy, jax.random.PRNGKey(0)), dummy_obs_batch(lanes, cfg.obs, cfg.actions),
+        policy.initial_state(lanes),
+    ))
+    args = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
+    text = jax.jit(
+        lambda p, o, c: policy.apply(p, o, c, method="step"), donate_argnums=(2,)
+    ).lower(*args).compile().as_text()
+    calls = [ln for ln in text.splitlines() if " custom-call(" in ln and "kda_step" in ln]
+    assert len(calls) == KDA_LAYERS
+    nh, d = cfg.model.n_heads, cfg.model.kda_head_dim
+    for ln in calls:
+        assert "/core_kda_state/" in re.search(r'op_name="([^"]*)"', ln).group(1)
+        assert "output_to_operand_aliasing={{1}: (6, {})}" in ln, ln[-400:]
+    assert not re.findall(rf"f32\[{lanes},{nh},{d},{d}\][^ ]* (?:reduce|fusion)\(", text)
+    assert " conditional(" not in text
+
+
+def test_the_kernel_steps_counter_moves_by_layers_x_steps_a_dispatch_where_the_kernel_ran(monkeypatch):
+    """``kda/kernel_steps_total`` is in the logged step's telemetry and moves
+    by KDA layers x rollout steps a dispatch where the model's predicate says
+    kernel (patched to leave the platform out: the kernel interpreted, one
+    head of 128); by nothing on this CPU, where the closed form runs, nor for
+    a core without such layers."""
+    from dotaclient_tpu.parallel import make_mesh
+    from dotaclient_tpu.train import learner as learner_mod
+    from dotaclient_tpu.utils import telemetry
+    from tests.test_fused_kimilinear import kimilinear_cfg
+    from tests.test_kda_step_kernel import _kernel_everywhere
+
+    cfg = kimilinear_cfg(selfplay_prob=1.0)
+    cfg = dataclasses.replace(
+        cfg, model=dataclasses.replace(cfg.model, kda_head_dim=128, n_heads=1), env=dataclasses.replace(cfg.env, n_envs=1)
+    )
+    mesh = make_mesh(cfg.mesh)
+    assert learner_mod._kda_kernel_steps(cfg, mesh) == 0                     # the real predicate, a CPU
+    assert learner_mod._kda_kernel_steps(default_config(), mesh) == 0        # the LSTM: no such layers
+    monkeypatch.setattr(kimilinear, "step_takes_kernel", _kernel_everywhere)
+    assert learner_mod._kda_kernel_steps(cfg, mesh) == KDA_LAYERS * cfg.ppo.rollout_len
+    before = telemetry.get_registry().snapshot()
+    out = learner_mod.Learner(cfg, actor="fused", seed=1).train(3)
+    assert np.isfinite(out["loss"]) and out["health_ok"] == 1.0
+    snap = telemetry.get_registry().snapshot()
+    assert snap["learner/dispatches_total"] - before.get("learner/dispatches_total", 0.0) == 3
+    assert snap["kda/kernel_steps_total"] - before.get("kda/kernel_steps_total", 0.0) == 3 * KDA_LAYERS * cfg.ppo.rollout_len
+
+
 # -- the counts, by hand -------------------------------------------------------------
 
 

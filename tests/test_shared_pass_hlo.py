@@ -24,7 +24,10 @@ CELLS = {
 # Instructions in the FROZEN opponent's loop body and everything it calls, at
 # the parent of PR 33 (commit 8915058) and since: its two passes are the same
 # lines as before. (jax 0.9.0, libtpu 0.0.34; a new compiler may move them.)
-FROZEN_BODY_INSTRUCTIONS = {"afmoe": 21809, "kimilinear": 21664, "looplm": 29017}
+# Kimi-Linear's count fell from 21,664 at PR 36: a KDA layer's step is one Pallas call
+# a lane set there (``ops/pallas/kda_step.py``), where the closed form's T = 1 case was
+# some 184 instructions; the other two cores run no line that PR touched.
+FROZEN_BODY_INSTRUCTIONS = {"afmoe": 21809, "kimilinear": 20192, "looplm": 29017}
 
 
 def computations(text):
