@@ -52,7 +52,7 @@ class ModelConfig:
     hidden_dim: int = 128        # LSTM hidden size — parity with reference
     n_hero_ids: int = 32         # hero-embedding vocabulary (multi-hero pools)
     hero_embed_dim: int = 16
-    core: str = "lstm"           # "lstm" | "transformer" | RESIDENT_CORES ("afmoe", "looplm", "kimilinear")
+    core: str = "lstm"           # "lstm" | "transformer" | RESIDENT_CORES ("afmoe", "looplm", "kimilinear", "lfm2moe")
     # Transformer-core options (scale-out path, SURVEY.md §7 step 8).
     n_layers: int = 2
     n_heads: int = 4
@@ -110,6 +110,20 @@ class ModelConfig:
     v_head_dim: int = 128        # MLA: a head's value width
     kda_head_dim: int = 128      # KDA: d_k = d_v of a head's state
     kda_conv_kernel: int = 4     # KDA: taps of the causal depthwise convolution
+    # "lfm2moe" core (models/lfm2moe.py): gated short-convolution layers (a
+    # carry of shortconv_taps - 1 rows of hidden_dim a lane and layer) beside
+    # grouped-query attention layers over rings of full_context rows (the
+    # afmoe core's Attention with attn_qk_norm and rope_full_layers on,
+    # attn_out_gate off); "full" = attention, the rest as the kimilinear
+    # core reads the fields above; n_shared_experts 0. The one width no
+    # field above states:
+    shortconv_taps: int = 3      # taps of the mixer's causal depthwise convolution (conv_L_cache)
+    # A routed layer's buffer of tokens x experts_per_token rows multiplied
+    # WHOLE (afmoe.RoutedExperts, whichever core builds it): the rows of pairs
+    # whose expert is not held, zeros, padded evenly into the held experts'
+    # groups, so that a step's time does not follow the router's draw. Same
+    # outputs; more work wherever fewer pairs land here than the buffer holds.
+    pad_expert_groups: bool = False
 
     @property
     def carry_stays_on_chip(self) -> bool:
@@ -131,11 +145,13 @@ class ModelConfig:
 # Cores whose carry stays on the chip (ModelConfig.carry_stays_on_chip) and
 # the module under dotaclient_tpu/models/ that holds each one's ``Core``,
 # ``initial_state``, ``reset``, ``chunk_start_view``, ``carry_bytes_per_lane``
-# and ``require_episode_fits``.
-RESIDENT_CORES = {"afmoe": "afmoe", "looplm": "looplm", "kimilinear": "kimilinear"}
+# and ``require_episode_fits``: ring caches (afmoe, looplm), matrix states
+# beside a latent ring (kimilinear), convolution histories of kilobytes
+# beside one ring (lfm2moe).
+RESIDENT_CORES = {"afmoe": "afmoe", "looplm": "looplm", "kimilinear": "kimilinear", "lfm2moe": "lfm2moe"}
 
 # Cores whose FFN slot can be a routed mixture (``moe_experts`` > 0).
-ROUTED_FFN_CORES = ("transformer", "afmoe", "kimilinear")
+ROUTED_FFN_CORES = ("transformer", "afmoe", "kimilinear", "lfm2moe")
 
 
 # Valid PPOConfig.adv_norm values — the single source of truth for the

@@ -142,7 +142,7 @@ KEY_PREFIXES = (
     "actor/", "advantage/", "alerts/", "buffer/", "checkpoint/",
     "compile/", "faults/", "fleet/", "fused/", "health/", "league/",
     "kda/", "learner/", "looplm/", "mem/", "mesh/", "moe/", "outcome/", "router/", "serve/",
-    "shm/", "snapshot/", "span/", "startup/", "trace/", "transport/", "util/",
+    "shm/", "shortconv/", "snapshot/", "span/", "startup/", "trace/", "transport/", "util/",
 )
 # single-line inline code only: multi-line matches would mispair across
 # ``` fence lines (odd backtick count flips pairing for the whole doc)

@@ -28,7 +28,7 @@ expert in the rest. One layer, on the stream ``h``, the query at position
 The stream enters scaled by sqrt(hidden_dim) (``mup_enabled``). A core that is
 a stack of these layers with less in each (``models/looplm.py``) says so in its
 configuration: ``attn_qk_norm``, ``attn_out_gate``, ``rope_full_layers`` (RoPE on
-full layers too), ``moe_experts`` 0 (every FFN dense), ``loop_steps`` rings a layer.
+full layers too), ``moe_experts`` 0 (every FFN dense), ``n_shared_experts`` 0 (no shared expert), ``loop_steps`` rings a layer.
 
 **One function for a step and for a chunk.** ``AfmoeCore(carry, x, resets)``
 takes ``x [B, T, H]``: the learner's pass is ONE pass over the chunk (T
@@ -343,8 +343,8 @@ class Attention(nn.Module):
                 if not self.full:
                     see_ring &= (t[None, :, None] + 1 + age[:, None, :]) < W
                     see_chunk &= ((t[:, None] - t[None, :]) < W)[None]
-                few_rows = G == 1 and T < _MXU_ROWS
-                return (_attend_few_rows if few_rows else _attend)(q, k, v, ring_k, ring_v, see_ring, see_chunk), None
+                few = G * T < _MXU_ROWS      # too few rows a KV head for a product against the ring as [R, kv, D]
+                return (_attend_few_rows if few and G == 1 else _attend_few_rows_grouped if few else _attend)(q, k, v, ring_k, ring_v, see_ring, see_chunk), None
 
             out, _ = by_lane_block(attend, ring, q, k, v.astype(dtype), pos0, cursor0, seg)
             out = out.reshape(B, T, nh * D) * nn.sigmoid(gate.astype(jnp.float32)) if cfg.attn_out_gate else out.reshape(B, T, nh * D)
@@ -409,12 +409,12 @@ class RoutedExperts(nn.Module):
             local = top - offset
             here = (local >= 0) & (local < held)                            # [N, k]
             # pairs sorted by held expert, pairs of absent experts last
-            key = jnp.where(here, local, held).reshape(N * k)
+            key = jnp.where(here, local, _pad_groups(here, held) if cfg.pad_expert_groups else held).reshape(N * k)
             order = jnp.argsort(key, stable=True)
             back = jnp.argsort(order)
             load = (key[:, None] == jnp.arange(held)[None, :]).sum(axis=0).astype(jnp.int32)
             n_here = load.sum()
-            row_here = jnp.arange(N * k) < n_here
+            row_here = here.reshape(N * k)[order] if cfg.pad_expert_groups else jnp.arange(N * k) < n_here
 
         def experts(name, shape, fan_in_axis):
             return self.param(
@@ -437,7 +437,7 @@ class RoutedExperts(nn.Module):
                 "nkh,nk->nh", ys, w.astype(dtype), preferred_element_type=jnp.float32
             )
         with jax.named_scope("core_expert_shared"):
-            shared = SwiGLU(cfg, cfg.n_shared_experts * F, name="shared")(x.astype(dtype))
+            shared = SwiGLU(cfg, cfg.n_shared_experts * F, name="shared")(x.astype(dtype)) if cfg.n_shared_experts else None
 
         # per token, time-major like the scanned cores' sows (train/ppo.py
         # masks the bootstrap step out of the auxiliary loss by time index)
@@ -451,16 +451,16 @@ class RoutedExperts(nn.Module):
         self.sow("losses", "moe_probs", time_major(s / s.sum(axis=-1, keepdims=True)))
         self.sow("losses", "moe_frac", time_major(chosen / k))
         # a pair is multiplied iff its row of the buffer lies inside the groups' rows
-        covered = (back.reshape(N, k) < n_here) & here
+        covered = here if cfg.pad_expert_groups else (back.reshape(N, k) < n_here) & here
         self.sow("losses", "moe_local", here.sum().astype(jnp.float32))
         self.sow("losses", "moe_dropped", (here.sum() - covered.sum()).astype(jnp.float32))
-        self.sow("losses", "moe_load", load.astype(jnp.float32))
+        self.sow("losses", "moe_load", (_pairs_held(here, local, held) if cfg.pad_expert_groups else load).astype(jnp.float32))
         # what the balancing update of the selection bias reads (train/ppo.py
         # _balance_select_bias): each expert's tokens minus the mean, over
         # the whole router, whatever share of it is held here
         tokens = jax.lax.stop_gradient(chosen.sum(axis=0))
         self.sow("losses", "select_bias_err", tokens - tokens.mean())
-        return (routed + shared.astype(jnp.float32)).reshape(B, T, H)
+        return (routed + shared.astype(jnp.float32)).reshape(B, T, H) if cfg.n_shared_experts else routed.reshape(B, T, H)
 
 
 class Block(nn.Module):
@@ -557,6 +557,69 @@ def _attend_few_rows(q, k, v, ring_k, ring_v, see_ring, see_chunk):
         "bktj,bjkd->btkd", e_own.astype(q.dtype), v, preferred_element_type=jnp.float32
     )
     return (out / jnp.moveaxis(total, 2, 1)[..., None])[:, :, :, None]
+
+
+@jax.checkpoint
+def _attend_few_rows_grouped(q, k, v, ring_k, ring_v, see_ring, see_chunk):
+    """``_attend_few_rows`` where a KV head serves G > 1 query heads and G T is
+    still under 8 rows a KV head: the rollout's T = 1 of LFM2's 32 query heads
+    over 8 KV heads of 64 (G = 4). Through ``_attend`` the TPU compiler copied
+    every ring into a position-minor layout, every step (four copies of 126 MB
+    a rollout step at 40 lanes a team: the fused program's optimised HLO, PR
+    37); with every query head a column of ONE block-diagonal ``[kv D, kv G]``
+    matrix the scores are one product with the ring as it lies and the values
+    one product ``[kv G, R] x [R, kv D]`` of which a head keeps its own KV
+    head's D columns. The G = 1 case of this is ``_attend_few_rows``, kept
+    word for word: the looped cell's loop lowers to another program through
+    this form (182 instructions fewer in its frozen loop, speed not measured:
+    ROADMAP D15 folds the two when a PR can measure that cell)."""
+    B, T, kv, G, D = q.shape
+    R = ring_k.shape[1]
+    heads = jnp.eye(kv, dtype=q.dtype)
+    # column (k', g) holds query head (k', g) in the D rows of its KV head k' and zeros in the others'
+    q_blocks = (jnp.swapaxes(q, 3, 4)[..., None, :] * heads[:, None, :, None]).reshape(B, T, kv * D, kv * G)
+    s_ring = jnp.einsum(
+        "brc,btcn->bntr", ring_k.reshape(B, R, kv * D), q_blocks, preferred_element_type=jnp.float32
+    )
+    s_own = jnp.einsum("btkgd,bjkd->bkgtj", q, k, preferred_element_type=jnp.float32).reshape(B, kv * G, T, T)
+    s_ring = jnp.where(see_ring[:, None], s_ring, _NEG)
+    s_own = jnp.where(see_chunk[:, None], s_own, _NEG)
+    top = jnp.maximum(s_ring.max(-1), s_own.max(-1))[..., None]
+    e_ring, e_own = jnp.exp(s_ring - top), jnp.exp(s_own - top)
+    total = (e_ring.sum(-1) + e_own.sum(-1)).reshape(B, kv, G, T)
+    every = jnp.matmul(
+        e_ring.astype(q.dtype).reshape(B, kv * G * T, R), ring_v.reshape(B, R, kv * D),
+        preferred_element_type=jnp.float32,
+    ).reshape(B, kv, G, T, kv, D)
+    out = jnp.einsum("bkgtkd->btkgd", every) + jnp.einsum(
+        "bkgtj,bjkd->btkgd", e_own.astype(q.dtype).reshape(B, kv, G, T, T), v, preferred_element_type=jnp.float32
+    )
+    return out / jnp.moveaxis(total, 3, 1)[..., None]
+
+
+# -- the routed buffer multiplied whole (``ModelConfig.pad_expert_groups``) ---------
+
+
+def _pad_groups(here: jnp.ndarray, held: int) -> jnp.ndarray:
+    """``[N, k]``: the held expert whose group the ZERO row of an absent pair
+    pads. ``RoutedExperts``' buffer has N k rows whatever the router chose, and
+    the grouped products take time by the rows inside the groups and by the
+    groups that are not empty: where lanes choose alike a layer's pairs land
+    on held experts all or none, and a step's time follows the router's draw
+    (the LFM2 cell: 3% between seeds, PERF.md section 6). Padded, every group
+    holds its pairs and an even share of the zero rows (the absent pairs dealt
+    round the held experts in order), the N k rows are all multiplied, every
+    held expert's weights are read in every pass, and the time is the
+    buffer's: a fixed shape at a fixed cost, as a ring is read whole. Outputs,
+    gradients and the sown counts are the unpadded layer's (a zero row gives a
+    zero row and is masked besides)."""
+    absent = ~here.reshape(-1)
+    return ((jnp.cumsum(absent) - 1) % held).reshape(here.shape)
+
+
+def _pairs_held(here: jnp.ndarray, local: jnp.ndarray, held: int) -> jnp.ndarray:
+    """``[held]``: the pairs each held expert was chosen for (without padding)."""
+    return ((local[..., None] == jnp.arange(held)) & here[..., None]).sum(axis=(0, 1)).astype(jnp.int32)
 
 
 Core = AfmoeCore      # what ``models/policy.py resident_core`` constructs

@@ -130,6 +130,7 @@ from dotaclient_tpu.models.afmoe import (
     layer_is_dense, layer_is_full, reset, ring_masks, write_rows,
 )
 from dotaclient_tpu.models.lanes import by_lane_block
+from dotaclient_tpu.models.shortconv import causal_conv
 from dotaclient_tpu.ops.pallas import kda_step
 
 _NEG = -1e30
@@ -372,21 +373,8 @@ class KDA(nn.Module):
                 "conv", nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=0, out_axis=1),
                 (K, 3 * W), pdtype,
             ).astype(jnp.float32)
-
-            def convolve(history, x, carried, seg):
-                rows = jnp.concatenate([jnp.where(carried[:, None, None], history, 0), x], axis=1)
-                row_seg = jnp.concatenate([jnp.zeros((x.shape[0], K - 1), seg.dtype), seg], axis=1)
-                y = sum(
-                    taps[j] * jnp.where(
-                        (row_seg[:, K - 1 - j:K - 1 - j + T] == seg)[..., None],
-                        rows[:, K - 1 - j:K - 1 - j + T].astype(jnp.float32), 0.0,
-                    )
-                    for j in range(K)
-                )
-                # the rows a later step's taps may read: those of the chunk's last episode
-                return y, jnp.where((row_seg[:, T:] == seg[:, -1:])[..., None], rows[:, T:], 0)
-
-            y, history = by_lane_block(convolve, history, x, carried, seg)
+            # the convolution over the carried rows is the one `models/lfm2moe.py`'s mixer runs (`shortconv.causal_conv`)
+            y, history = by_lane_block(functools.partial(causal_conv, taps), history, x, carried, seg)
             q, k, v = (z.reshape(B, T, nh, D) for z in jnp.split(nn.silu(y), 3, axis=-1))
             q, k = _l2norm(q) / math.sqrt(D), _l2norm(k)
             f = _dense(cfg, W, "wf_up")(_dense(cfg, D, "wf_down")(a)).astype(jnp.float32)

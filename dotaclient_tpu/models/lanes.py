@@ -9,7 +9,7 @@ block at a time (``by_lane_block``). This module is all that knows the format:
 the rollout builds a ``LaneBlocks`` (``actor/device_rollout.py``), the policy
 joins and splits it around its core (``models/policy.py``), and a core calls
 ``by_lane_block`` where it touches a carry leaf (``models/afmoe.py``,
-``models/kimilinear.py``). A plain carry is one block everywhere.
+``models/kimilinear.py``, ``models/lfm2moe.py``). A plain carry is one block everywhere.
 """
 
 from __future__ import annotations

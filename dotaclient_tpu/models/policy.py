@@ -14,14 +14,14 @@ TPU-first design decisions (SURVEY.md §7 step 3):
   parameters — sequence mode runs the LSTM through ``models/lstm.py`` (one
   ``lax.scan``, the weight gradient one product AFTER the backward loop) and
   the windowed transformer under ``nn.scan``, and hands a core whose carry stays on the chip
-  (``models/afmoe.py``, ``looplm.py``, ``kimilinear.py``) the chunk in ONE pass (its step: T = 1).
+  (``models/afmoe.py``, ``looplm.py``, ``kimilinear.py``, ``lfm2moe.py``) the chunk in ONE pass (its step: T = 1).
 * The carry, its reset and the chunk-start carry a learner is handed are
   the core's own: ``initial_state``, ``reset_carry`` and
   ``chunk_start_carry``. The LSTM's ``(h, c)`` and the transformer's window
   are rows that a reset zeroes (``mask_carry``) and a chunk start copies in
   float32; where ``ModelConfig.carry_stays_on_chip`` the carry is megabytes a lane
   (attention caches: 22 MiB at Trinity-Mini's widths, 403 MB at Ouro's; matrix states and a
-  latent ring: 12 MB at Kimi-Linear's), which a reset never touches (a position counter returns
+  latent ring: 12 MB at Kimi-Linear's; one ring and rows: 6 MiB at LFM2's), which a reset never touches (a position counter returns
   to 0) and a chunk start never widens: the core's own module answers (``resident_core``).
 * ``step`` takes one lane set's carry or several sets' (``LaneBlocks``: a rollout
   whose two teams play the same parameters): one pass, every weight read once, each

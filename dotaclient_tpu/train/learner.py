@@ -1532,12 +1532,8 @@ class Learner:
         return _finish_metrics
 
     def _fold_core_counters(self, scalars: Dict[str, float]) -> None:
-        """A logged step's counts from a core that keeps some, into the registry at the log cadence:
-        a routed layer's pairs and load as gauges and the pairs its weights left out as a counter that
-        never moves (``train/ppo._moe_counters``); a looped core's exit statistics as gauges and its
-        passes over the stack as a counter, R a logged forward pass (``train/ppo.exit_weighted_loss``);
-        a delta-rule core's decay, write strength and state size as gauges and its reads of a void state
-        (a lane-layer at position 0 of an episode) as a counter (``train/ppo._kda_gauges``)."""
+        """A logged step's counts from a core that keeps some, into the registry at the log cadence (what each says: docs/ARCHITECTURE.md): ``moe/*`` from ``train/ppo._moe_counters``, ``looplm/*``
+        from ``exit_weighted_loss``, ``kda/*`` from ``_kda_gauges``, ``shortconv/*`` from ``_shortconv_gauges``; a void read is a lane-layer at position 0 of an episode."""
         tel = self.telemetry
         if "moe_local_assignments" in scalars:
             tel.gauge("moe/local_assignments").set(scalars["moe_local_assignments"])
@@ -1552,6 +1548,10 @@ class Learner:
         if "kda_void_reads" in scalars:
             tel.counter("kda/void_reads_total").inc(scalars["kda_void_reads"])
             for key in ("kda/decay_mean", "kda/beta_mean", "kda/state_rms"):
+                tel.gauge(key).set(scalars[key.replace("/", "_")])
+        if "shortconv_void_reads" in scalars:
+            tel.counter("shortconv/void_reads_total").inc(scalars["shortconv_void_reads"])
+            for key in ("shortconv/history_rms", "shortconv/gate_mean"):
                 tel.gauge(key).set(scalars[key.replace("/", "_")])
 
     def _publish_pipeline_gauges(self) -> None:

@@ -215,7 +215,7 @@ def ppo_loss(
         mutable=["losses"],
     )
     moe_aux = _moe_aux_loss(mutated.get("losses", {}), valid)
-    moe_counters = {**_moe_counters(mutated.get("losses", {})), **_kda_gauges(mutated.get("losses", {}), valid)}
+    moe_counters = {**_moe_counters(mutated.get("losses", {})), **_kda_gauges(mutated.get("losses", {}), valid), **_shortconv_gauges(mutated.get("losses", {}), valid)}
     bias_errors = _select_bias_errors(mutated.get("losses", {}))
     # Trailing slot is the bootstrap step: value used, policy outputs unused.
     logits_t = {k: v[:, :T] for k, v in logits.items()}
@@ -867,11 +867,7 @@ def _kda_gauges(losses_col: Any, valid: jnp.ndarray) -> Dict[str, jnp.ndarray]:
     states' root mean square, and the lane-layer reads of a void state (a
     step at position 0 of its episode, the bootstrap step left to the next
     chunk). Empty for every other core."""
-    flat, _ = jax.tree_util.tree_flatten_with_path(losses_col)
-
-    def leaves(name):
-        return [l for p, l in flat if getattr(p[-2], "key", None) == name]
-
+    leaves = _sown(losses_col)
     if not leaves("kda_decay"):
         return {}
     T = valid.shape[1]
@@ -881,3 +877,27 @@ def _kda_gauges(losses_col: Any, valid: jnp.ndarray) -> Dict[str, jnp.ndarray]:
         "kda_state_rms": jnp.sqrt(jnp.stack(leaves("kda_state_sq")).mean()),
         "kda_void_reads": (leaves("kda_void_reads")[0][:, :T] * valid).sum(),
     }
+
+
+def _shortconv_gauges(losses_col: Any, valid: jnp.ndarray) -> Dict[str, jnp.ndarray]:
+    """What a core with gated short-convolution layers sowed in this pass
+    (``models/lfm2moe.py``), over its convolution layers: the root mean
+    square of the rows the histories carry, the mean magnitude of the output
+    gate, and the lane-layer reads of a void history (a step at position 0
+    of its episode, the bootstrap step left to the next chunk). Empty for
+    every other core."""
+    leaves = _sown(losses_col)
+    if not leaves("shortconv_gate"):
+        return {}
+    T = valid.shape[1]
+    return {
+        "shortconv_history_rms": jnp.sqrt(jnp.stack(leaves("shortconv_history_sq")).mean()),
+        "shortconv_gate_mean": jnp.stack(leaves("shortconv_gate")).mean(),
+        "shortconv_void_reads": (leaves("shortconv_void_reads")[0][:, :T] * valid).sum(),
+    }
+
+
+def _sown(losses_col: Any):
+    """``name -> the leaves sown under it`` in a ``losses`` collection, in layer order."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(losses_col)
+    return lambda name: [leaf for path, leaf in flat if getattr(path[-2], "key", None) == name]

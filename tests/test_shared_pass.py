@@ -18,6 +18,7 @@ from dotaclient_tpu.models.policy import dummy_obs_batch, init_params, make_poli
 from tests.test_fused import tiny_cfg
 from tests.test_fused_afmoe import afmoe_cfg
 from tests.test_fused_kimilinear import kimilinear_cfg
+from tests.test_fused_lfm2moe import lfm2moe_cfg
 from tests.test_fused_looplm import looplm_cfg
 
 
@@ -25,7 +26,7 @@ def lstm_cfg():
     return tiny_cfg(opponent="league")
 
 
-CORES = {"afmoe": afmoe_cfg, "looplm": looplm_cfg, "kimilinear": kimilinear_cfg, "lstm": lstm_cfg}
+CORES = {"afmoe": afmoe_cfg, "looplm": looplm_cfg, "kimilinear": kimilinear_cfg, "lfm2moe": lfm2moe_cfg, "lstm": lstm_cfg}
 
 
 def short_episodes(cfg):
