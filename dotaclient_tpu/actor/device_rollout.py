@@ -144,9 +144,9 @@ def sample_per_game(
     """``D.sample`` vmapped over the game axis: lanes are game-major, so
     each game's block of lanes draws from that game's own key ``[N, 2]``.
     Random-bit generation therefore partitions WITH the games when they
-    shard over a mesh — a single batch-wide key would make every device
-    generate the full lane set's bits — and the sampled actions are
-    bitwise independent of the shard count."""
+    shard over a mesh, and the sampled actions are bitwise independent of
+    the shard count. A core that decodes over passes drew between them."""
+    if "act_stage" in logits: return logits["actions"], logits["logp"]   # noqa: E701
     def split_g(t):
         return t.reshape((n_games, t.shape[0] // n_games) + t.shape[1:])
 
@@ -439,7 +439,7 @@ class DeviceActor:
             if self.learner_players[0] < spec.team_size
             else sim_mod.TEAM_DIRE
         )
-
+        staged = self.config.model.diffusion_steps > 0     # the core decodes an action over passes
         def policy_pass(p, *teams):
             """One pass of the policy over the teams' rows together: an
             ``(obs, carry)`` a team -> a ``(logits, carry)`` a team."""
@@ -465,7 +465,7 @@ class DeviceActor:
             with jax.named_scope("rollout_sample"):
                 ks = jax.vmap(lambda k: jax.random.split(k, 3))(key)
                 key2, k_act, k_opp = ks[:, 0], ks[:, 1], ks[:, 2]
-
+            decode_pass = self._decode_pass(iter((k_act, k_opp))) if staged else policy_pass
             # Each stage of a rollout step carries a scope (metadata only;
             # the policy's two forward passes keep the policy's own), so a
             # profiler trace times the stages by name.
@@ -473,11 +473,11 @@ class DeviceActor:
                 obs = feat.featurize(sim)
                 oobs = self._opp_feat.featurize(sim) if one_pass else None
             if one_pass:
-                (logits, lstm2), (ologits, opp_lstm2) = policy_pass(
+                (logits, lstm2), (ologits, opp_lstm2) = decode_pass(
                     params, (obs, lstm), (oobs, opp_lstm)
                 )
             else:
-                ((logits, lstm2),) = policy_pass(params, (obs, lstm))
+                ((logits, lstm2),) = decode_pass(params, (obs, lstm))
             with jax.named_scope("rollout_sample"):
                 acts, logp = sample_per_game(k_act, logits, obs, spec.n_games)
                 packed = jnp.stack(
@@ -489,7 +489,7 @@ class DeviceActor:
                 if not one_pass:
                     with jax.named_scope("rollout_featurize"):
                         oobs = self._opp_feat.featurize(sim)
-                    ((ologits, opp_lstm2),) = policy_pass(
+                    ((ologits, opp_lstm2),) = decode_pass(
                         opp_params, (oobs, opp_lstm)
                     )
                 with jax.named_scope("rollout_sample"):
@@ -567,7 +567,7 @@ class DeviceActor:
                     # per-term rewards kept PER-LANE [L]: the post-scan sums
                     # reduce only the step axis, so the accumulators stay
                     # shard-local partials under the lane sharding
-                    "rew_terms": r_terms,
+                    "rew_terms": r_terms, **({"act_stage": logits["act_stage"]} if staged else {}),
                 }
                 ep_ret = jnp.where(done_lane, 0.0, ep_ret)
             return (sim3, lstm3, opp_lstm3, key2, ep_ret, ep_steps3), out
@@ -602,9 +602,9 @@ class DeviceActor:
                 "rewards": jnp.moveaxis(outs["reward"], 0, 1),
                 "dones": jnp.moveaxis(outs["done_lane"], 0, 1),
                 "valid": jnp.ones((self.n_lanes, T), jnp.float32),
-                # the chunk-start carry as the core hands it to a learner:
-                # float32 rows for the LSTM, and for a core with caches the
-                # start's counters beside the rings as the chunk left them
+                # the chunk-start carry as the core hands it to a learner: float32 rows for
+                # the LSTM, for a core with caches the start's counters beside the END's rings
+                **({"act_stage": jnp.moveaxis(outs["act_stage"], 0, 1)} if staged else {}),
                 "carry0": self.policy.chunk_start_carry(state.carry, lstm_f),
             }
             lg = self._league_game_mask[None, :]     # [1, N] non-anchor games
@@ -758,3 +758,34 @@ class DeviceActor:
                 recent.get("ep_return_sum", 0.0) / r_eps if r_eps else 0.0
             ),
         }
+
+    def _decode_pass(self, keys):
+        """``_rollout_impl``'s policy pass where the core decodes an action as
+        a block over several passes (``models/sdar.py decode``), drawing
+        between them: each team's ``(obs, carry)`` -> its ``(decoded, carry)``,
+        ``decoded`` holding the actions, their log-probability and the pass
+        that committed each slot (``act_stage``, recorded in the chunk). The
+        teams' per-game keys come from ``keys`` in the order the teams are
+        passed, the learner's before the opponent's, as ``sample_per_game``
+        would have drawn from them. Several teams in one call are one pass
+        over their rows, as ``policy_pass``."""
+        from dotaclient_tpu.models.sdar import decode
+
+        def decode_pass(p, *teams):
+            obs, carries = zip(*teams)
+            team_keys = tuple(next(keys) for _ in teams)
+            if len(teams) == 1:
+                out, carry = self.policy.apply(p, obs[0], carries[0], team_keys[0], method=decode)
+                return ((out, carry),)
+            out, carries = self.policy.apply(
+                p, jax.tree.map(lambda *rows: jnp.concatenate(rows), *obs), LaneBlocks(carries), team_keys,
+                method=decode,
+            )
+            sizes = [o["hero_id"].shape[0] for o in obs]
+            lanes = {k: v for k, v in out.items() if k != "logits"}
+            return tuple(
+                (jax.tree.map(lambda x: x[end - n:end], lanes), carry)
+                for n, end, carry in zip(sizes, np.cumsum(sizes), carries)
+            )
+
+        return decode_pass

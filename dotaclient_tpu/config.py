@@ -52,7 +52,7 @@ class ModelConfig:
     hidden_dim: int = 128        # LSTM hidden size — parity with reference
     n_hero_ids: int = 32         # hero-embedding vocabulary (multi-hero pools)
     hero_embed_dim: int = 16
-    core: str = "lstm"           # "lstm" | "transformer" | RESIDENT_CORES ("afmoe", "looplm", "kimilinear", "lfm2moe")
+    core: str = "lstm"           # "lstm" | "transformer" | RESIDENT_CORES ("afmoe", "looplm", "kimilinear", "lfm2moe", "sdar")
     # Transformer-core options (scale-out path, SURVEY.md §7 step 8).
     n_layers: int = 2
     n_heads: int = 4
@@ -124,6 +124,16 @@ class ModelConfig:
     # groups, so that a step's time does not follow the router's draw. Same
     # outputs; more work wherever fewer pairs land here than the buffer holds.
     pad_expert_groups: bool = False
+    # What a routed layer's router scores its experts by (afmoe.RoutedExperts):
+    # "sigmoid" each expert on its own (Trinity, Kimi-Linear, LFM2), "softmax"
+    # over all of them (the Qwen3-MoE layer of the "sdar" core)
+    route_score: str = "sigmoid"
+    # "sdar" core (models/sdar.py): an action is a block of five tokens (the
+    # heads in distributions.HEADS order) decoded by this many denoising
+    # passes over the block and a pass that commits it to the ring; a game
+    # step is six positions, the observation and its block. 0: every other
+    # core, whose step yields its action from one pass
+    diffusion_steps: int = 0
 
     @property
     def carry_stays_on_chip(self) -> bool:
@@ -147,11 +157,11 @@ class ModelConfig:
 # ``initial_state``, ``reset``, ``chunk_start_view``, ``carry_bytes_per_lane``
 # and ``require_episode_fits``: ring caches (afmoe, looplm), matrix states
 # beside a latent ring (kimilinear), convolution histories of kilobytes
-# beside one ring (lfm2moe).
-RESIDENT_CORES = {"afmoe": "afmoe", "looplm": "looplm", "kimilinear": "kimilinear", "lfm2moe": "lfm2moe"}
+# beside one ring (lfm2moe), a ring of six positions a step (sdar).
+RESIDENT_CORES = {"afmoe": "afmoe", "looplm": "looplm", "kimilinear": "kimilinear", "lfm2moe": "lfm2moe", "sdar": "sdar"}
 
 # Cores whose FFN slot can be a routed mixture (``moe_experts`` > 0).
-ROUTED_FFN_CORES = ("transformer", "afmoe", "kimilinear", "lfm2moe")
+ROUTED_FFN_CORES = ("transformer", "afmoe", "kimilinear", "lfm2moe", "sdar")
 
 
 # Valid PPOConfig.adv_norm values — the single source of truth for the

@@ -100,6 +100,10 @@ DYNAMIC_KEY_EXPANSIONS: Dict[Tuple[str, str], Tuple[str, ...]] = {
     # (model.loop_steps is a runtime value: representative members;
     # documented as the `looplm/exit_mass/<r>` wildcard row)
     ("looplm/exit_mass/", ""): ("0", "1"),
+    # train/learner.py: a block-decoding core's entropy per pass
+    # (model.diffusion_steps is a runtime value: representative members;
+    # documented as the `diffusion/stage_entropy/<s>` wildcard row)
+    ("diffusion/stage_entropy/", ""): ("1", "2", "3"),
     # Outcome attribution plane (ISSUE 15; dotaclient_tpu/outcome/).
     # Keep the value tuples in sync with outcome.records BUCKETS / SIDES
     # / REWARD_TERMS / N_LEN_BUCKETS and the OUTCOME_KEYS schema tier.
@@ -140,7 +144,7 @@ _DOC_KEY_RE = re.compile(
 # namespace must be added here when its first key is minted.
 KEY_PREFIXES = (
     "actor/", "advantage/", "alerts/", "buffer/", "checkpoint/",
-    "compile/", "faults/", "fleet/", "fused/", "health/", "league/",
+    "compile/", "diffusion/", "faults/", "fleet/", "fused/", "health/", "league/",
     "kda/", "learner/", "looplm/", "mem/", "mesh/", "moe/", "outcome/", "router/", "serve/",
     "shm/", "shortconv/", "snapshot/", "span/", "startup/", "trace/", "transport/", "util/",
 )

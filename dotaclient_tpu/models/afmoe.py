@@ -400,7 +400,7 @@ class RoutedExperts(nn.Module):
             s = nn.sigmoid(jnp.dot(
                 x.astype(jnp.float32), wr.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST,
-            ))                                                              # [N, E]
+            )) if cfg.route_score == "sigmoid" else _softmax_scores(x, wr)  # [N, E]
             _, top = jax.lax.top_k(s + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
             w = jnp.take_along_axis(s, top, axis=1)                         # [N, k]
             if cfg.route_norm:
@@ -620,6 +620,19 @@ def _pad_groups(here: jnp.ndarray, held: int) -> jnp.ndarray:
 def _pairs_held(here: jnp.ndarray, local: jnp.ndarray, held: int) -> jnp.ndarray:
     """``[held]``: the pairs each held expert was chosen for (without padding)."""
     return ((local[..., None] == jnp.arange(held)) & here[..., None]).sum(axis=(0, 1)).astype(jnp.int32)
+
+
+# -- a router that scores by softmax (``ModelConfig.route_score``) -------------------
+
+
+def _softmax_scores(x: jnp.ndarray, wr: jnp.ndarray) -> jnp.ndarray:
+    """``RoutedExperts``' scores where ``route_score`` is "softmax" (the
+    Qwen3-MoE router): ``softmax(x W_r)`` over the experts, in float32 at the
+    product's highest precision as the sigmoid router's. The experts per token
+    are then the largest scores and their weights the scores renormalised over
+    the chosen (``route_norm``): ``norm_topk_prob``."""
+    logits = jnp.dot(x.astype(jnp.float32), wr.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+    return jax.nn.softmax(logits, axis=-1)
 
 
 Core = AfmoeCore      # what ``models/policy.py resident_core`` constructs

@@ -219,3 +219,125 @@ def entropy(
         + p_attack * H(logps["target_attack"])
         + p_cast * (H(logps["target_cast"]) + H(logps["ability"]))
     )
+
+
+# -- an action decoded as a block over several passes (``models/sdar.py``) ----------
+#
+# The heads are the five slots of a block, in ``HEADS`` order. Pass 1 decodes
+# the action type; the arguments the type makes relevant (``relevant``) are
+# committed over passes 2..S, in an order drawn from the rollout's key alone
+# (``commit_stages``); a slot the type leaves out is NONE. Logits come a pass
+# at a time (``stage_logits``: each head ``[S, ..., K]``, pass s at index
+# s - 1), and a slot's head is read from the pass that committed it
+# (``act_stage [..., 5]``: 1 for the type, 2..S for an argument, 0 for NONE).
+# The order is exogenous, so the log-probability of the action given the
+# state and the order is the conditional factorization above with each head
+# taken from its own pass, and the PPO ratio is exact given the order.
+
+
+def relevant(a_type: jnp.ndarray) -> jnp.ndarray:
+    """``[..., 5]`` bool: the slots an action of type ``a_type`` fills, as
+    ``_joint_logp`` counts them (NOOP the type alone, MOVE x and y, ATTACK the
+    target, CAST the target and the ability)."""
+    move = a_type == A_MOVE
+    target = (a_type == A_ATTACK) | (a_type == A_CAST)
+    return jnp.stack([jnp.ones_like(move), move, move, target, a_type == A_CAST], axis=-1)
+
+
+def commit_stages(key: jax.Array, a_type: jnp.ndarray, steps: int) -> jnp.ndarray:
+    """``int8 [..., 5]``: the pass that commits each slot. The type is pass
+    1; the n relevant arguments go over passes 2..``steps`` by LLaDA's static
+    schedule (``n // (steps - 1)`` a pass, the remainder one more each in the
+    first passes: two arguments over two passes are [1, 1], one is [1, 0]),
+    in the order of priorities drawn from ``key`` alone (LLaDA's "random"
+    remasking: which slot goes first never depends on the parameters); a
+    slot the type leaves out is 0 (NONE)."""
+    rel = relevant(a_type)
+    u = jax.random.uniform(key, rel.shape)
+    args = rel[..., 1:]
+    # rank of each relevant argument among the relevant ones, by priority
+    rank = (args[..., None, :] & (u[..., None, 1:] < u[..., 1:, None])).sum(-1)
+    n, passes = args.sum(-1), steps - 1
+    # how many arguments the passes 2..j + 2 commit together: rank r goes in
+    # the first pass whose count exceeds it
+    per, extra = n // passes, n % passes
+    ends = jnp.stack([(j + 1) * per + jnp.minimum(j + 1, extra) for j in range(passes)], axis=-1)
+    stage = 2 + (rank[..., None] >= ends[..., None, :]).sum(-1)
+    stage = jnp.where(args, stage, 0)
+    return jnp.concatenate([jnp.ones_like(stage[..., :1]), stage], axis=-1).astype(jnp.int8)
+
+
+def pick_stage_logits(stage_logits: Mapping[str, jnp.ndarray], act_stage: jnp.ndarray) -> Dict[str, jnp.ndarray]:
+    """Each head's logits from the pass that committed its slot (a select
+    over the passes, never a gather; zeros for a NONE slot, which no term
+    reads)."""
+    out = {}
+    for j, h in enumerate(HEADS):
+        lg, st = stage_logits[h], act_stage[..., j].astype(jnp.int32)
+        out[h] = sum(jnp.where((st == s + 1)[..., None], lg[s], 0.0) for s in range(lg.shape[0]))
+    return out
+
+
+def stage_sample(
+    key: jax.Array, logits: Mapping[str, jnp.ndarray], obs: Mapping[str, jnp.ndarray], a_type: jnp.ndarray
+) -> Dict[str, jnp.ndarray]:
+    """One pass's draw of every argument head (the target under the mask the
+    type implies); the caller keeps the slots this pass commits."""
+    logps = _head_logps(logits, obs)
+    k_mx, k_my, k_tgt, k_ab = jax.random.split(key, 4)
+    return {
+        "move_x": jax.random.categorical(k_mx, logps["move_x"], axis=-1),
+        "move_y": jax.random.categorical(k_my, logps["move_y"], axis=-1),
+        "target_unit": jax.random.categorical(k_tgt, _select_target_logps(logps, a_type), axis=-1),
+        "ability": jax.random.categorical(k_ab, logps["ability"], axis=-1),
+    }
+
+
+def type_sample(key: jax.Array, logits: jnp.ndarray, obs: Mapping[str, jnp.ndarray]) -> jnp.ndarray:
+    """Pass 1's draw of the action type under its mask."""
+    return jax.random.categorical(key, masked_log_softmax(logits, obs["mask_action_type"]), axis=-1)
+
+
+def staged_log_prob(
+    stage_logits: Mapping[str, jnp.ndarray], obs: Mapping[str, jnp.ndarray],
+    actions: Mapping[str, jnp.ndarray], act_stage: jnp.ndarray,
+) -> jnp.ndarray:
+    """``log p^1(type) + sum over relevant k of log p^{stage(k)}(a_k)``:
+    ``log_prob`` of the heads as their passes committed them."""
+    return log_prob(pick_stage_logits(stage_logits, act_stage), obs, actions)
+
+
+def _path_terms(lp: Mapping[str, jnp.ndarray], fn, a_type: jnp.ndarray, lq=None) -> jnp.ndarray:
+    """``fn`` of the type's head plus ``fn`` of the heads the sampled type made
+    relevant, each from the pass that committed it: the path the rollout took
+    (passes 2..S exist for the sampled type only, so the other types' heads
+    are not there to weigh by their probability as ``entropy`` does)."""
+    q = lq if lq is not None else lp
+    rel = relevant(a_type).astype(jnp.float32)
+    cast = (a_type == A_CAST)[..., None]
+    target = fn(jnp.where(cast, lp["target_cast"], lp["target_attack"]), jnp.where(cast, q["target_cast"], q["target_attack"]))
+    return (
+        fn(lp["action_type"], q["action_type"])
+        + rel[..., 1] * fn(lp["move_x"], q["move_x"]) + rel[..., 2] * fn(lp["move_y"], q["move_y"])
+        + rel[..., 3] * target + rel[..., 4] * fn(lp["ability"], q["ability"])
+    )
+
+
+def staged_entropy(
+    stage_logits: Mapping[str, jnp.ndarray], obs: Mapping[str, jnp.ndarray],
+    actions: Mapping[str, jnp.ndarray], act_stage: jnp.ndarray,
+) -> jnp.ndarray:
+    """Entropy in the path-wise conditional form: the type's, plus each
+    relevant argument head's at the pass that committed it."""
+    lp = _head_logps(pick_stage_logits(stage_logits, act_stage), obs)
+    return _path_terms(lp, lambda a, _: -jnp.sum(jnp.exp(a) * a, axis=-1), actions["action_type"])
+
+
+def staged_kl(
+    logits_p: Mapping[str, jnp.ndarray], logits_q: Mapping[str, jnp.ndarray], obs: Mapping[str, jnp.ndarray],
+    actions: Mapping[str, jnp.ndarray], act_stage: jnp.ndarray,
+) -> jnp.ndarray:
+    """KL(P || Q) in the same path-wise form, both read at the same passes."""
+    lp = _head_logps(pick_stage_logits(logits_p, act_stage), obs)
+    lq = _head_logps(pick_stage_logits(logits_q, act_stage), obs)
+    return _path_terms(lp, lambda a, b: jnp.sum(jnp.exp(a) * (a - b), axis=-1), actions["action_type"], lq)

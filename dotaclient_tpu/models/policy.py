@@ -406,3 +406,14 @@ def resident_core(model: ModelConfig):
     import importlib
 
     return importlib.import_module(f"dotaclient_tpu.models.{RESIDENT_CORES[model.core]}")
+
+
+def require_one_pass_decode(model: ModelConfig, where: str) -> None:
+    """Raise where ``where`` steps the policy ONE pass an action and the core
+    decodes an action as a block over several (``ModelConfig.diffusion_steps``:
+    ``models/sdar.py decode``, which only the fused trainer's rollout runs)."""
+    if model.diffusion_steps:
+        raise ValueError(
+            f"core {model.core!r} decodes an action as a block over {model.diffusion_steps} denoising "
+            f"passes and a commit: it trains in actor mode 'fused', and {where} steps one pass an action"
+        )

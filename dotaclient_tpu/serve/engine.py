@@ -59,6 +59,7 @@ from dotaclient_tpu.models.policy import (
     Policy,
     dummy_obs_batch,
     require_carry_stays,
+    require_one_pass_decode,
 )
 from dotaclient_tpu.utils import telemetry, utilization
 
@@ -150,6 +151,7 @@ class ServeEngine:
         # bit-exact on a fresh backend. Inbound rows park here (slot →
         # host row tree) and the batcher installs them BETWEEN dispatches
         # — the same marshalling discipline as slot zeroes.
+        require_one_pass_decode(policy.model, "the serve engine")
         self._carry_shadow = bool(scfg.carry_shadow)
         if self._carry_shadow:
             require_carry_stays(policy.model, "serve.carry_shadow")

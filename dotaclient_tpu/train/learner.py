@@ -1532,9 +1532,9 @@ class Learner:
         return _finish_metrics
 
     def _fold_core_counters(self, scalars: Dict[str, float]) -> None:
-        """A logged step's counts from a core that keeps some, into the registry at the log cadence (what each says: docs/ARCHITECTURE.md): ``moe/*`` from ``train/ppo._moe_counters``, ``looplm/*``
-        from ``exit_weighted_loss``, ``kda/*`` from ``_kda_gauges``, ``shortconv/*`` from ``_shortconv_gauges``; a void read is a lane-layer at position 0 of an episode."""
+        """A logged step's counts from a core that keeps some, into the registry at the log cadence (docs/ARCHITECTURE.md): ``moe/*`` from ``train/ppo._moe_counters``, ``looplm/*`` from ``exit_weighted_loss``, ``kda/*`` from ``_kda_gauges``, ``shortconv/*`` from ``_shortconv_gauges``, ``diffusion/*`` from ``_diffusion_gauges``; a void read is a lane-layer at position 0 of an episode."""
         tel = self.telemetry
+        _fold_diffusion(tel, scalars, self.config.model.diffusion_steps)
         if "moe_local_assignments" in scalars:
             tel.gauge("moe/local_assignments").set(scalars["moe_local_assignments"])
             tel.gauge("moe/max_over_mean_expert_load").set(scalars["moe_max_over_mean_load"])
@@ -1835,10 +1835,10 @@ class Learner:
                 da = self.device_actor
                 k_iters = cfg.steps_per_dispatch
                 frames_per = da.n_lanes * cfg.ppo.rollout_len * k_iters
-                # The loop's host stretches, named for the profiler
-                # (telemetry.Registry.span): one `learner/iteration` per
-                # dispatch, its self time the loop work no child names.
+                # The loop's host stretches, named for the profiler (telemetry.Registry.span):
+                # one `learner/iteration` per dispatch, its self time the loop work no child names.
                 tel = self.telemetry
+                passes_ran, passes = tel.counter("diffusion/passes_total"), _diffusion_passes(cfg)
                 dispatches = tel.counter("learner/dispatches_total")
                 frozen = tel.counter("league/frozen_dispatches_total")
                 shared = tel.counter("league/shared_pass_dispatches_total")
@@ -1854,9 +1854,9 @@ class Learner:
                             self.state, da.state, m, chunk_stats = self.fused_step(
                                 self.state, da.state, opp_params
                             )
-                        self._util.phase(
-                            "dispatch_inflight", time.perf_counter() - t0
-                        )
+                        self._util.phase("dispatch_inflight", time.perf_counter() - t0)
+                        # the core's passes a rollout step, the commit counted (0 for a one-pass core)
+                        passes_ran.inc(passes)
                         dispatches.inc()
                         kda_ran.inc(kda_steps)
                         if opp_idx != league_pool.LIVE:
@@ -2569,6 +2569,32 @@ def _kda_kernel_steps(config: RunConfig, mesh) -> int:
     if not hasattr(core, "step_takes_kernel") or not core.step_takes_kernel(model, mesh.devices.flat[0].platform):
         return 0
     return len(core.kda_layers(model)) * config.ppo.rollout_len * config.steps_per_dispatch
+
+
+def _diffusion_passes(config: RunConfig) -> int:
+    """Core passes ONE fused dispatch's rollout runs where the core decodes an
+    action over several (``models/sdar.py``): S denoising passes and the
+    commit a rollout step (a pass over both teams' rows counts once), times
+    steps and iterations; 0 for every other core. ``diffusion/passes_total``
+    over ``learner/dispatches_total`` x steps is the passes a step."""
+    from dotaclient_tpu.models.policy import resident_core
+
+    model = config.model
+    if not model.diffusion_steps:
+        return 0
+    return resident_core(model).rollout_passes(model) * config.ppo.rollout_len * config.steps_per_dispatch
+
+
+def _fold_diffusion(tel, scalars: Dict[str, float], steps: int) -> None:
+    """A logged step's block counts (``train/ppo._diffusion_gauges``): the
+    chunk's committed tokens and NONE slots, and each pass's mean entropy of
+    the heads it committed."""
+    if "diffusion_none_slots" not in scalars:
+        return
+    tel.counter("diffusion/tokens_committed_total").inc(scalars["diffusion_tokens_committed"])
+    tel.counter("diffusion/none_slots_total").inc(scalars["diffusion_none_slots"])
+    for s in range(1, steps + 1):
+        tel.gauge(f"diffusion/stage_entropy/{s}").set(scalars[f"diffusion_stage_entropy_{s}"])
 
 
 if __name__ == "__main__":

@@ -196,10 +196,10 @@ def ppo_loss(
     regularizer: one extra frozen-policy forward over the batch, exact
     conditional KL(π_θ ‖ π_anchor) per frame (PPOConfig.anchor_kl_coef).
 
-    A batch carrying precomputed ``advantages``/``returns`` leaves (the one-pass advantage plane,
-    ``train/advantage.py``) skips the in-step estimator and shortens the forward to the T transition
-    steps: the bootstrap slot only seeded the estimator. A looped core's loss is its own (below).
+    Precomputed ``advantages``/``returns`` (``train/advantage.py``) skip the estimator and the bootstrap slot. A looped or a staged core's loss is its own (below).
     """
+    if policy.model.diffusion_steps:
+        return staged_loss(policy, params, batch, cfg, step, anchor_params)
     if policy.model.loop_steps > 1:
         return exit_weighted_loss(policy, params, batch, cfg, step, anchor_params)
     obs = batch["obs"]
@@ -719,7 +719,7 @@ def example_batch(config: RunConfig, batch: int, as_struct: bool = False) -> Bat
         "rewards": jnp.zeros((batch, T), jnp.float32),
         "dones": jnp.zeros((batch, T), jnp.float32),
         "valid": jnp.ones((batch, T), jnp.float32),
-        "carry0": carry0,
+        "carry0": carry0, **_staged_batch(config, batch),
     }
     if as_struct:
         return jax.tree.map(
@@ -901,3 +901,127 @@ def _sown(losses_col: Any):
     """``name -> the leaves sown under it`` in a ``losses`` collection, in layer order."""
     flat, _ = jax.tree_util.tree_flatten_with_path(losses_col)
     return lambda name: [leaf for path, leaf in flat if getattr(path[-2], "key", None) == name]
+
+
+# -- the loss of a core that decodes an action over several passes (models/sdar.py) ---
+
+
+def _staged_batch(config: RunConfig, batch: int) -> Batch:
+    """What a chunk of a staged core carries besides: the pass that
+    committed each slot (``act_stage [B, T, 5]`` int8). Empty otherwise."""
+    if not config.model.diffusion_steps:
+        return {}
+    return {"act_stage": jnp.ones((batch, config.ppo.rollout_len, len(D.HEADS)), jnp.int8)}
+
+
+def staged_loss(
+    policy: Policy,
+    params: Any,
+    batch: Batch,
+    cfg: PPOConfig,
+    step: Any = None,
+    anchor_params: Any = None,
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """``ppo_loss`` for a core that decodes an action as a block over S
+    passes (``models/sdar.py``): the learner's pass is the core's own
+    ``sequence`` (SDAR's layout: clean rows and S noisy copies of every
+    step's block), which hands back each head's logits from every copy; the
+    log-probability, the entropy bonus and the ratio read each head from the
+    copy of the pass that committed it (``batch["act_stage"]``:
+    ``distributions.staged_log_prob``, ``staged_entropy``), so at the
+    rollout's parameters the ratio is 1. Every other term is ``ppo_loss``'s.
+    The auxiliary load-balancing loss is over every row of the pass. The
+    anchor KL, the KL-adaptive learning rate and precomputed advantages are
+    refused: the core trains in fused mode only, which uses none."""
+    from dotaclient_tpu.models.policy import resident_core
+
+    if cfg.anchor_kl_coef > 0 or cfg.kl_target > 0 or "advantages" in batch:
+        raise ValueError(
+            f"core {policy.model.core!r} decodes an action over {policy.model.diffusion_steps} passes: "
+            "ppo.anchor_kl_coef, ppo.kl_target and precomputed advantages (the one-pass advantage "
+            "plane) are not written for that"
+        )
+    obs, actions, act_stage = batch["obs"], batch["actions"], batch["act_stage"]
+    T = batch["rewards"].shape[1]
+    valid = batch["valid"].astype(jnp.float32)
+    n_valid = jnp.maximum(valid.sum(), 1.0)
+    (stage_logits, values), mutated = policy.apply(
+        params, obs, batch["carry0"], batch["dones"], actions, act_stage,
+        method=resident_core(policy.model).sequence, mutable=["losses"],
+    )
+    losses = mutated.get("losses", {})
+    probs = _sown(losses)("moe_probs")          # [rows, B, E] a routed layer: every row of the pass weighs alike
+    moe_aux = _moe_aux_loss(losses, jnp.ones((valid.shape[0], probs[0].shape[0] if probs else 1), jnp.float32))
+    bias_errors = _select_bias_errors(losses)
+    obs_t = {k: v[:, :T] for k, v in obs.items()}
+    values_t = values[:, :T]
+    logp = D.staged_log_prob(stage_logits, obs_t, actions, act_stage)
+    with jax.named_scope("update_gae"):
+        if cfg.advantage == "gae":
+            adv, returns = gae(
+                batch["rewards"], jax.lax.stop_gradient(values), batch["dones"], cfg.gamma, cfg.gae_lambda,
+            )
+        elif cfg.advantage == "vtrace":
+            adv, returns = vtrace(
+                batch["rewards"], jax.lax.stop_gradient(values), batch["dones"], batch["behavior_logp"],
+                jax.lax.stop_gradient(logp), cfg.gamma, cfg.vtrace_rho_clip, cfg.vtrace_c_clip,
+            )
+        else:
+            raise ValueError(f"unknown advantage {cfg.advantage!r} (one of {ADVANTAGE_MODES})")
+    adv = adv - (adv * valid).sum() / n_valid
+    if cfg.adv_norm == "batch":
+        adv_std = jnp.sqrt((jnp.square(adv) * valid).sum() / n_valid + 1e-8)
+        adv = adv / jnp.maximum(adv_std, cfg.adv_norm_floor)
+    elif cfg.adv_norm not in ADV_NORM_MODES:
+        raise ValueError(f"unknown adv_norm {cfg.adv_norm!r} (one of {ADV_NORM_MODES})")
+    ratio = jnp.exp(logp - batch["behavior_logp"])
+    clipped = jnp.clip(ratio, 1.0 - cfg.clip_eps, 1.0 + cfg.clip_eps)
+    policy_loss = -(jnp.minimum(ratio * adv, clipped * adv) * valid).sum() / n_valid
+    value_loss = 0.5 * (jnp.square(values_t - returns) * valid).sum() / n_valid
+    ent = (D.staged_entropy(stage_logits, obs_t, actions, act_stage) * valid).sum() / n_valid
+    if cfg.value_warmup_steps and step is not None:
+        policy_on = (step >= cfg.value_warmup_steps).astype(jnp.float32)
+    else:
+        policy_on = 1.0
+    loss = (
+        policy_on * (policy_loss - cfg.entropy_coef * ent + cfg.moe_aux_coef * moe_aux)
+        + cfg.value_coef * value_loss
+    )
+    metrics = {
+        "loss": loss,
+        "moe_aux": moe_aux,
+        **_moe_counters(losses),
+        **_diffusion_gauges(stage_logits, obs_t, actions, act_stage, valid),
+        **({"_select_bias_err": bias_errors} if bias_errors else {}),
+        "policy_loss": policy_loss,
+        "value_loss": value_loss,
+        "entropy": ent,
+        "approx_kl": ((batch["behavior_logp"] - logp) * valid).sum() / n_valid,
+        "clip_frac": (
+            (jnp.abs(ratio - 1.0) > cfg.clip_eps).astype(jnp.float32) * valid
+        ).sum() / n_valid,
+        "value_mean": (values_t * valid).sum() / n_valid,
+        "reward_mean": (batch["rewards"] * valid).sum() / n_valid,
+    }
+    return loss, metrics
+
+
+def _diffusion_gauges(stage_logits, obs_t, actions, act_stage, valid) -> Dict[str, jnp.ndarray]:
+    """What the learner folds into ``diffusion/*`` at the log cadence: the
+    chunk's tokens committed (slots a pass filled) and NONE slots over valid
+    steps, and for each pass s the mean entropy of the heads it committed
+    (each from its own copy's logits, the target under its type's mask)."""
+    stage = act_stage.astype(jnp.int32)
+    w = valid[..., None]
+    lp = D._head_logps(D.pick_stage_logits(stage_logits, act_stage), obs_t)
+    is_cast = (actions["action_type"] == D.A_CAST)[..., None]
+    lp["target_unit"] = jnp.where(is_cast, lp["target_cast"], lp["target_attack"])
+    entropy = jnp.stack([-jnp.sum(jnp.exp(lp[h]) * lp[h], axis=-1) for h in D.HEADS], axis=-1)   # [B, T, 5]
+    out = {
+        "diffusion_tokens_committed": ((stage > 0) * w).sum(),
+        "diffusion_none_slots": ((stage == 0) * w).sum(),
+    }
+    for s in range(1, stage_logits[D.HEADS[0]].shape[0] + 1):
+        here = (stage == s) * w
+        out[f"diffusion_stage_entropy_{s}"] = (here * entropy).sum() / jnp.maximum(here.sum(), 1.0)
+    return out
