@@ -71,14 +71,16 @@ the ``moe_experts`` routed experts this chip holds (guide: one chip's share
 of a layer divided over several). The router keeps its whole width and its
 experts per token; the layer adds the terms of the experts it holds and the
 shared expert. Every token-expert pair is sorted by held expert (pairs of
-absent experts last) and multiplied in groups by ``jax.lax.ragged_dot``; the
+absent experts last) and multiplied in groups (``_grouped_swiglu``: on a TPU
+at widths of whole lanes the Pallas kernels of ``ops/pallas/grouped_matmul.py``,
+chosen by ``grouped_takes_kernel``; ``jax.lax.ragged_dot`` elsewhere); the
 buffer has a row for every pair, so none can be dropped, and the layer
 counts that from the buffer (``moe_dropped``). A step therefore costs what
-the router chose: a grouped product takes as long as the held experts it
-touches (1.0 / 4.7 / 8.4 / 15.5 microseconds a decode product for 0 / 1 / 2 /
-3 experts, 4.2 MB of weights each: my chip runs, PR 26), and early in
-training lanes that watch like game states choose like experts, so a chip's
-share of the pairs is a draw of the weights (PERF.md section 6). With
+the router chose: the kernels visit a tile of rows once for each held
+expert whose group meets it and read that expert's weights each visit, an
+empty group never and a tile past the groups never, and early in training
+lanes that watch like game states choose like experts, so a chip's share of
+the pairs is a draw of the weights (PERF.md section 6). With
 ``held_experts`` 0 or ``moe_experts`` the layer is the whole layer.
 
 **The selection bias** is the one parameter no gradient reaches: the layer
@@ -90,6 +92,7 @@ step moves the bias against it (``train/ppo._balance_select_bias``). Scopes insi
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -99,6 +102,7 @@ import jax.numpy as jnp
 
 from dotaclient_tpu.config import ModelConfig
 from dotaclient_tpu.models.lanes import by_lane_block
+from dotaclient_tpu.ops.pallas import grouped_matmul
 
 _NEG = -1e30
 
@@ -429,8 +433,7 @@ class RoutedExperts(nn.Module):
             # row j of the buffer is pair order[j], of token order[j] // k
             xs = _take_rows(x.astype(dtype), order // k, back)              # [N k, H]
             xs = jnp.where(row_here[:, None], xs, 0)
-            mid = nn.silu(jax.lax.ragged_dot(xs, wg, load)) * jax.lax.ragged_dot(xs, wu, load)
-            ys = jax.lax.ragged_dot(mid, wd, load)                          # [N k, H]
+            ys = _grouped_swiglu(cfg, xs, wg, wu, wd, load)                 # [N k, H]
             ys = _take_rows(ys, back, order).reshape(N, k, H)
             ys = jnp.where(here[:, :, None], ys, 0)
             routed = jnp.einsum(
@@ -455,6 +458,8 @@ class RoutedExperts(nn.Module):
         self.sow("losses", "moe_local", here.sum().astype(jnp.float32))
         self.sow("losses", "moe_dropped", (here.sum() - covered.sum()).astype(jnp.float32))
         self.sow("losses", "moe_load", (_pairs_held(here, local, held) if cfg.pad_expert_groups else load).astype(jnp.float32))
+        if grouped_takes_kernel(cfg, "tpu"):
+            self.sow("losses", "moe_kernel_rows_share", grouped_matmul.visited_rows(load, N * k) / (N * k))
         # what the balancing update of the selection bias reads (train/ppo.py
         # _balance_select_bias): each expert's tokens minus the mean, over
         # the whole router, whatever share of it is held here
@@ -603,16 +608,19 @@ def _attend_few_rows_grouped(q, k, v, ring_k, ring_v, see_ring, see_chunk):
 def _pad_groups(here: jnp.ndarray, held: int) -> jnp.ndarray:
     """``[N, k]``: the held expert whose group the ZERO row of an absent pair
     pads. ``RoutedExperts``' buffer has N k rows whatever the router chose, and
-    the grouped products take time by the rows inside the groups and by the
-    groups that are not empty: where lanes choose alike a layer's pairs land
-    on held experts all or none, and a step's time follows the router's draw
-    (the LFM2 cell: 3% between seeds, PERF.md section 6). Padded, every group
-    holds its pairs and an even share of the zero rows (the absent pairs dealt
-    round the held experts in order), the N k rows are all multiplied, every
-    held expert's weights are read in every pass, and the time is the
-    buffer's: a fixed shape at a fixed cost, as a ring is read whole. Outputs,
-    gradients and the sown counts are the unpadded layer's (a zero row gives a
-    zero row and is masked besides)."""
+    the grouped products take time by the row tiles inside the groups and by
+    the groups that are not empty: where lanes choose alike a layer's pairs
+    land on held experts all or none, and a step's time follows the router's
+    draw (the LFM2 cell: 3% between seeds, PERF.md section 6). Padded, every
+    group holds its pairs and an even share of the zero rows (the absent pairs
+    dealt round the held experts in order), the N k rows are all multiplied
+    (``moe_kernel_rows_share`` 1), every held expert's weights are read in
+    every pass, and the time is the buffer's: a fixed shape at a fixed cost,
+    as a ring is read whole. The zero rows stand for the tokens of the chips
+    the deployment's other experts lie on: they are work the configuration
+    states, not rows a faster layer may skip. Outputs, gradients and the sown
+    counts are the unpadded layer's (a zero row gives a zero row and is masked
+    besides)."""
     absent = ~here.reshape(-1)
     return ((jnp.cumsum(absent) - 1) % held).reshape(here.shape)
 
@@ -620,6 +628,48 @@ def _pad_groups(here: jnp.ndarray, held: int) -> jnp.ndarray:
 def _pairs_held(here: jnp.ndarray, local: jnp.ndarray, held: int) -> jnp.ndarray:
     """``[held]``: the pairs each held expert was chosen for (without padding)."""
     return ((local[..., None] == jnp.arange(held)) & here[..., None]).sum(axis=(0, 1)).astype(jnp.int32)
+
+
+# -- the grouped products: a kernel on the TPU -------------------------------------
+
+
+def grouped_takes_kernel(cfg: ModelConfig, platform: str) -> bool:
+    """Whether ``RoutedExperts``' grouped products are the Pallas kernels of
+    ``ops/pallas/grouped_matmul.py`` in a program lowered for ``platform``: the
+    ONE predicate, the model's and the learner's for
+    ``moe/grouped_kernel_calls_total``. Mosaic compiles for the TPU alone, and
+    the kernels take widths of whole lanes in the configuration's compute type
+    (the published widths, not the toy widths of ``tests/``)."""
+    return platform == "tpu" and grouped_matmul.takes(cfg.hidden_dim, cfg.expert_ffn_dim, _dtype(cfg.dtype))
+
+
+def _swiglu_ragged(xs, wg, wu, wd, load):
+    mid = nn.silu(jax.lax.ragged_dot(xs, wg, load)) * jax.lax.ragged_dot(xs, wu, load)
+    return jax.lax.ragged_dot(mid, wd, load)
+
+
+# ``platform_dependent`` traces BOTH paths at every call (a layer has one, and a start traces the core
+# many times): under ``jit`` a path is traced once a shape and lowered once a program (``kimilinear._recurrence``'s)
+def _swiglu_kernel(interpret, xs, wg, wu, wd, load):
+    return grouped_matmul.grouped_swiglu(xs, wg, wu, wd, load, interpret=interpret)
+
+
+_ragged_traced_once = jax.jit(_swiglu_ragged)
+# a branch per ``interpret``, made once: a branch made anew at each call is traced anew
+_kernel_traced_once = {i: functools.partial(jax.jit(_swiglu_kernel, static_argnums=0), i) for i in (False, True)}
+
+
+def _grouped_swiglu(cfg: ModelConfig, xs, wg, wu, wd, load):
+    """``silu(xs Wg) * (xs Wu) Wd`` over the sorted buffer, a group of
+    ``load`` rows a held expert from row 0: the Pallas kernels where the
+    program is lowered for a platform on which ``grouped_takes_kernel``
+    (interpreted anywhere but on a TPU: a test's case), ``ragged_dot``
+    elsewhere. One traced program serves the CPU and the chip. Rows past the
+    groups are undefined on the kernel's path: ``RoutedExperts`` selects them away."""
+    kernel = {p: _kernel_traced_once[p != "tpu"] for p in ("tpu", "cpu") if grouped_takes_kernel(cfg, p)}
+    if not kernel:
+        return _swiglu_ragged(xs, wg, wu, wd, load)
+    return jax.lax.platform_dependent(xs, wg, wu, wd, load, default=_ragged_traced_once, **kernel)
 
 
 # -- a router that scores by softmax (``ModelConfig.route_score``) -------------------

@@ -2,7 +2,9 @@
 
 ``kda_step``: one step of a delta-rule linear-attention layer, a head's
 state read once and written once; called by ``models/kimilinear.py`` for a
-rollout step on a TPU. ``lstm``: the LSTM over a whole sequence in one call;
+rollout step on a TPU. ``grouped_matmul``: a routed layer's grouped
+products and their gradients, a grid that follows the group sizes; called by
+``models/afmoe.py RoutedExperts`` on a TPU. ``lstm``: the LSTM over a whole sequence in one call;
 called by nothing in the package (``models/lstm.py`` is the LSTM's path),
 kept with its tests and ``chip_smoke.py`` phase e as the alternative for
 wider cores. Nothing here looks at the backend: every entry point takes

@@ -1539,6 +1539,8 @@ class Learner:
             tel.gauge("moe/local_assignments").set(scalars["moe_local_assignments"])
             tel.gauge("moe/max_over_mean_expert_load").set(scalars["moe_max_over_mean_load"])
             tel.counter("moe/dropped_assignments").inc(scalars["moe_dropped_assignments"])
+        if "moe_kernel_rows_share" in scalars:
+            tel.gauge("moe/kernel_rows_share").set(scalars["moe_kernel_rows_share"])
         if "looplm_loop_passes" in scalars:
             tel.counter("looplm/loop_passes_total").inc(scalars["looplm_loop_passes"])
             tel.gauge("looplm/expected_exit_step").set(scalars["looplm_expected_exit_step"])
@@ -1843,6 +1845,7 @@ class Learner:
                 frozen = tel.counter("league/frozen_dispatches_total")
                 shared = tel.counter("league/shared_pass_dispatches_total")
                 kda_ran, kda_steps = tel.counter("kda/kernel_steps_total"), _kda_kernel_steps(cfg, self.mesh)
+                grouped_ran, grouped_calls = tel.counter("moe/grouped_kernel_calls_total"), _grouped_kernel_calls(cfg, self.mesh)
                 while steps_done < num_steps and not self._stop_requested:
                     with tel.span("learner/iteration", step=self._host_step):
                         with tel.span("learner/league_draw"):
@@ -1859,6 +1862,7 @@ class Learner:
                         passes_ran.inc(passes)
                         dispatches.inc()
                         kda_ran.inc(kda_steps)
+                        grouped_ran.inc(grouped_calls)
                         if opp_idx != league_pool.LIVE:
                             frozen.inc()
                         elif self._live_shares_pass:
@@ -2569,6 +2573,25 @@ def _kda_kernel_steps(config: RunConfig, mesh) -> int:
     if not hasattr(core, "step_takes_kernel") or not core.step_takes_kernel(model, mesh.devices.flat[0].platform):
         return 0
     return len(core.kda_layers(model)) * config.ppo.rollout_len * config.steps_per_dispatch
+
+
+def _grouped_kernel_calls(config: RunConfig, mesh) -> int:
+    """Routed-layer passes ONE fused dispatch makes through the grouped-matmul
+    kernels (``ops/pallas/grouped_matmul.py``): routed layers x (the core's
+    passes a rollout step x rollout steps + the update's optimizer steps),
+    times iterations, where ``afmoe.grouped_takes_kernel`` holds on the
+    platform the program is lowered for; 0 where ``ragged_dot`` runs (a CPU,
+    toy widths) and for a core without routed layers. A pass over both teams'
+    rows counts once, as in ``diffusion/passes_total``."""
+    from dotaclient_tpu.models import afmoe
+    from dotaclient_tpu.models.policy import resident_core
+
+    model = config.model
+    if not (model.carry_stays_on_chip and model.moe_experts) or not afmoe.grouped_takes_kernel(model, mesh.devices.flat[0].platform):
+        return 0
+    routed = sum(not afmoe.layer_is_dense(model, layer) for layer in range(model.n_layers))
+    passes = resident_core(model).rollout_passes(model) if model.diffusion_steps else 1
+    return routed * (passes * config.ppo.rollout_len + config.ppo.steps_per_batch) * config.steps_per_dispatch
 
 
 def _diffusion_passes(config: RunConfig) -> int:

@@ -124,8 +124,11 @@ def _moe_aux_loss(losses_col: Any, valid: jnp.ndarray) -> jnp.ndarray:
 def _moe_counters(losses_col: Any) -> Dict[str, jnp.ndarray]:
     """What a core that holds part of a routed layer counted in this pass
     (``models/afmoe.py``), summed over its expert layers: token-expert pairs
-    computed here, pairs its weights left out (always 0), and the busiest
-    held expert's load over the mean. Empty for every other core."""
+    computed here, pairs its weights left out (always 0), the busiest held
+    expert's load over the mean, and where the layer's grouped products are
+    the kernel's (``afmoe.grouped_takes_kernel``) the share of the buffers'
+    rows inside the row tiles it visits, the layers' mean. Empty for every
+    other core."""
     flat, _ = jax.tree_util.tree_flatten_with_path(losses_col)
 
     def leaves(name):
@@ -141,6 +144,7 @@ def _moe_counters(losses_col: Any) -> Dict[str, jnp.ndarray]:
         "moe_max_over_mean_load": (
             load.max(axis=1) / jnp.maximum(load.mean(axis=1), 1e-9)
         ).max(),
+        **({"moe_kernel_rows_share": sum(shares) / len(shares)} if (shares := leaves("moe_kernel_rows_share")) else {}),
     }
 
 

@@ -342,7 +342,9 @@ def test_the_padded_buffer_is_the_unpadded_layer_at_a_fixed_amount_of_work(monke
         def out(p, x):
             y, sown = layer.apply({"params": p}, x, mutable=["losses"])
             return jnp.sum(y * jnp.cos(jnp.arange(y.size, dtype=y.dtype).reshape(y.shape))), (y, sown["losses"])
-        return jax.value_and_grad(out, argnums=(0, 1), has_aux=True)(params, m)
+        # eagerly, through the jitted products too: each ``ragged_dot`` call is seen with its groups
+        with jax.disable_jit():
+            return jax.value_and_grad(out, argnums=(0, 1), has_aux=True)(params, m)
 
     ((_, (y0, sown0)), grads0), groups0 = run(plain), [np.asarray(g) for g in sizes]
     del sizes[:]

@@ -27,7 +27,12 @@ CELLS = {
 # Kimi-Linear's count fell from 21,664 at PR 36: a KDA layer's step is one Pallas call
 # a lane set there (``ops/pallas/kda_step.py``), where the closed form's T = 1 case was
 # some 184 instructions; the other two cores run no line that PR touched.
-FROZEN_BODY_INSTRUCTIONS = {"afmoe": 21809, "kimilinear": 20192, "looplm": 29017}
+# Since a routed layer's three grouped products became the grouped-matmul kernels
+# (``ops/pallas/grouped_matmul.py``: one call on the scalar unit for the layer's tables,
+# one a product), where XLA's ``ragged_dot`` lowering was its own metadata call and three
+# products: Trinity's count fell from 21,809 and Kimi-Linear's rose from 20,192. The
+# looped core holds no routed layer.
+FROZEN_BODY_INSTRUCTIONS = {"afmoe": 21512, "kimilinear": 20200, "looplm": 29017}
 
 
 def computations(text):
@@ -64,11 +69,13 @@ def loop_body(text):
 
 def weight_products(lines):
     """{scoped name of a Dense or einsum against a parameter: how many
-    ``dot``/``convolution`` instructions carry it}; the products against rings
-    and states (inside ``jax.checkpoint``) apart."""
+    ``dot``/``convolution`` instructions carry it, and of the held experts'
+    grouped products: how many calls of the grouped-matmul kernel, one weight
+    operand each}; the products against rings and states (inside
+    ``jax.checkpoint``) apart."""
     weights, held = collections.Counter(), collections.Counter()
     for ln in lines:
-        if re.search(r" (?:dot|convolution)\(", ln):
+        if re.search(r" (?:dot|convolution)\(", ln) or re.search(r' custom-call\(.*op_name="[^"]*/grouped_matmul/', ln):
             name = re.search(r'op_name="([^"]*)"', ln).group(1)
             name = name[name.index("policy_"):] if "policy_" in name else name
             (held if "checkpoint" in name else weights)[name] += 1
