@@ -145,7 +145,7 @@ _DOC_KEY_RE = re.compile(
 KEY_PREFIXES = (
     "actor/", "advantage/", "alerts/", "buffer/", "checkpoint/",
     "compile/", "diffusion/", "faults/", "fleet/", "fused/", "health/", "league/",
-    "kda/", "learner/", "looplm/", "mem/", "mesh/", "moe/", "outcome/", "router/", "serve/",
+    "kda/", "learner/", "looplm/", "mem/", "mesh/", "moe/", "outcome/", "router/", "sdar/", "serve/",
     "shm/", "shortconv/", "snapshot/", "span/", "startup/", "trace/", "transport/", "util/",
 )
 # single-line inline code only: multi-line matches would mispair across

@@ -50,7 +50,12 @@ the rollout's pass s at the same parameters, and the learner's
 log-probability is the rollout's. The scores of a chunk's rows against a ring
 are too large for every lane at once: ``_attend_lanes`` runs them a few lanes
 at a time (a ``lax.map`` over lane groups), rematerialised in the backward
-pass.
+pass. A rollout pass's rows (at most ``ROWS`` a lane, all of the lane's
+current episode) see only the ring rows their lane's episode wrote: on a TPU
+at heads of whole lanes the pass attends through the ring kernel
+(``ops/pallas/ring_attend.py``, chosen by ``pass_takes_kernel`` under
+``jax.lax.platform_dependent``), whose grid visits only the ring blocks that
+hold them; the learner's pass, a CPU and toy widths keep ``_attend_lanes``.
 
 Scopes inside ``policy_core``: ``core_denoise`` around each of the S
 denoising passes, ``core_commit`` around the commit pass (the learner's pass
@@ -63,6 +68,7 @@ weight is read under it), ``core_cache_write``, ``core_router``,
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Mapping, Optional, Tuple
 
@@ -77,6 +83,7 @@ from dotaclient_tpu.models.afmoe import (
     RMSNorm, RoutedExperts, SwiGLU, _attend, _dense, _dtype, chunk_positions, layer_is_dense, rope, write_rows,
 )
 from dotaclient_tpu.models.lanes import LaneBlocks, by_lane_block, join_lanes, split_lanes
+from dotaclient_tpu.ops.pallas import ring_attend
 
 SLOTS = len(D.HEADS)     # the tokens of an action's block
 ROWS = 1 + SLOTS         # positions a game step: the observation and its block
@@ -228,6 +235,61 @@ def _attend_lanes(q, k, v, ring_k, ring_v, see_ring, see_own):
     return out.reshape(B // g, blocks, g, n, kv, G, -1).swapaxes(1, 2).reshape(q.shape[:-1] + (-1,))
 
 
+def _attend_heads(q, k, v, ring, see_ring, see_own, *counters):
+    """``_attend_lanes`` a KV head at a time: its G query heads against its
+    own ring ``ring[0][h]``, ``ring[1][h]`` (``counters`` are the kernel's)."""
+    return jnp.concatenate([
+        _attend_lanes(q[:, :, h:h + 1], k[:, :, h:h + 1], v[:, :, h:h + 1], ring[0][h][:, :, None],
+                      ring[1][h][:, :, None], see_ring, see_own)
+        for h in range(q.shape[2])
+    ], axis=2)
+
+
+# -- a rollout pass's attention as the ring kernel ----------------------------------
+
+
+def pass_takes_kernel(cfg: ModelConfig, rows: int, platform: str) -> bool:
+    """Whether a pass of ``rows`` rows a lane attends through the ring kernel
+    (``ops/pallas/ring_attend.py``) in a program lowered for ``platform``: the
+    ONE predicate, the model's and the learner's for
+    ``sdar/ring_kernel_calls_total``. A pass of at most ``ROWS`` rows is a
+    rollout pass (``decode``; the learner's is hundreds), whose rows are all
+    of their lane's current episode. Mosaic compiles for the TPU alone, and
+    the kernel takes heads of whole lanes in bfloat16 or float32 and a ring of
+    whole blocks (the published widths, not the toy widths of ``tests/``)."""
+    return platform == "tpu" and rows <= ROWS and ring_attend.takes(cfg.head_dim, _dtype(cfg.dtype), cfg.full_context)
+
+
+def _ring_pass(interpret, own, block_rows, q, k, v, ring, see_ring, see_own, pos0, cursor0):
+    """``_attend_heads`` of a rollout pass through the kernel: ``see_ring`` and
+    ``see_own`` are what the kernel reads from the counters and ``own``."""
+    return ring_attend.ring_attend(
+        q, k, v, ring[0], ring[1], pos0, cursor0, np.asarray(own), interpret=interpret, block_rows=block_rows
+    )
+
+
+# ``platform_dependent`` traces BOTH paths at every call (a pass has one a layer and lane set, and a start
+# traces the rollout several times): under ``jit`` a path is traced once a shape and lowered once a program
+_heads_traced_once = jax.jit(_attend_heads)
+_ring_traced_once = jax.jit(_ring_pass, static_argnums=(0, 1, 2))
+
+
+@functools.lru_cache(maxsize=None)
+def _ring_branch(interpret: bool, own: Tuple[Tuple[bool, ...], ...], block_rows: int):
+    """The kernel's branch for a pass whose rows see each other as ``own``
+    (interpreted anywhere but on a TPU: a test's case), made once: a branch
+    made anew at each call is traced anew."""
+    return functools.partial(_ring_traced_once, interpret, own, block_rows)
+
+
+def ring_rows_read_share(cfg: ModelConfig, pos, cursor) -> jnp.ndarray:
+    """The share of the lanes' rings that a rollout pass at these counters
+    reads through the kernel: rows of the blocks its grid visits over lanes x
+    ``full_context`` (``sdar/ring_rows_read_share``)."""
+    _, blocks = ring_attend.visited_blocks(pos, cursor, cfg.full_context)
+    return jnp.sum(blocks) * ring_attend.BLOCK_ROWS / (pos.shape[0] * cfg.full_context)
+
+
 class BlockAttention(nn.Module):
     """Grouped-query attention of a pass's rows ``a [B, N, H]`` over a ring
     and the pass's own rows: the afmoe core's parameters and products
@@ -263,12 +325,14 @@ class BlockAttention(nn.Module):
                 see_ring = (seg == 0)[:, :, None] & (age < pos0[:, None])[:, None, :]
                 see_own = own[None] & (seg[:, :, None] == seg[:, None, :])
                 with jax.named_scope("core_block_attend"):
-                    # a KV head at a time: its G query heads against its own ring
-                    return jnp.concatenate([
-                        _attend_lanes(q[:, :, h:h + 1], k[:, :, h:h + 1], v[:, :, h:h + 1], ring[0][h][:, :, None],
-                                      ring[1][h][:, :, None], see_ring, see_own)
-                        for h in range(kv)
-                    ], axis=2), None
+                    platforms = [p for p in ("tpu", "cpu") if pass_takes_kernel(cfg, N, p)]
+                    if not platforms:
+                        return _attend_heads(q, k, v, ring, see_ring, see_own), None
+                    own_key = tuple(map(tuple, np.asarray(sees, bool).tolist()))
+                    kernel = {p: _ring_branch(p != "tpu", own_key, ring_attend.BLOCK_ROWS) for p in platforms}
+                    return jax.lax.platform_dependent(
+                        q, k, v, ring, see_ring, see_own, pos0, cursor0, default=_heads_traced_once, **kernel
+                    ), None
 
             out, _ = by_lane_block(attend, ring, q, k, v, seg, pos0, cursor0)
             attn = _dense(cfg, cfg.hidden_dim, "wo")(out.reshape(B, N, nh * D_).astype(dtype))

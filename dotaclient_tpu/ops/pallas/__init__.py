@@ -4,7 +4,9 @@
 state read once and written once; called by ``models/kimilinear.py`` for a
 rollout step on a TPU. ``grouped_matmul``: a routed layer's grouped
 products and their gradients, a grid that follows the group sizes; called by
-``models/afmoe.py RoutedExperts`` on a TPU. ``lstm``: the LSTM over a whole sequence in one call;
+``models/afmoe.py RoutedExperts`` on a TPU. ``ring_attend``: a rollout pass's
+attention against its lanes' rings, a grid of the ring blocks each lane
+sees; called by ``models/sdar.py BlockAttention`` on a TPU. ``lstm``: the LSTM over a whole sequence in one call;
 called by nothing in the package (``models/lstm.py`` is the LSTM's path),
 kept with its tests and ``chip_smoke.py`` phase e as the alternative for
 wider cores. Nothing here looks at the backend: every entry point takes

@@ -1532,7 +1532,7 @@ class Learner:
         return _finish_metrics
 
     def _fold_core_counters(self, scalars: Dict[str, float]) -> None:
-        """A logged step's counts from a core that keeps some, into the registry at the log cadence (docs/ARCHITECTURE.md): ``moe/*`` from ``train/ppo._moe_counters``, ``looplm/*`` from ``exit_weighted_loss``, ``kda/*`` from ``_kda_gauges``, ``shortconv/*`` from ``_shortconv_gauges``, ``diffusion/*`` from ``_diffusion_gauges``; a void read is a lane-layer at position 0 of an episode."""
+        """A logged step's counts from a core that keeps some, into the registry at the log cadence (docs/ARCHITECTURE.md): ``moe/*`` from ``train/ppo._moe_counters``, ``looplm/*`` from ``exit_weighted_loss``, ``kda/*`` from ``_kda_gauges``, ``shortconv/*`` from ``_shortconv_gauges``, ``diffusion/*`` from ``_diffusion_gauges``, ``sdar/ring_rows_read_share`` from ``staged_loss``; a void read is a lane-layer at position 0 of an episode."""
         tel = self.telemetry
         _fold_diffusion(tel, scalars, self.config.model.diffusion_steps)
         if "moe_local_assignments" in scalars:
@@ -1541,6 +1541,8 @@ class Learner:
             tel.counter("moe/dropped_assignments").inc(scalars["moe_dropped_assignments"])
         if "moe_kernel_rows_share" in scalars:
             tel.gauge("moe/kernel_rows_share").set(scalars["moe_kernel_rows_share"])
+        if "sdar_ring_rows_read_share" in scalars:
+            tel.gauge("sdar/ring_rows_read_share").set(scalars["sdar_ring_rows_read_share"])
         if "looplm_loop_passes" in scalars:
             tel.counter("looplm/loop_passes_total").inc(scalars["looplm_loop_passes"])
             tel.gauge("looplm/expected_exit_step").set(scalars["looplm_expected_exit_step"])
@@ -1846,6 +1848,7 @@ class Learner:
                 shared = tel.counter("league/shared_pass_dispatches_total")
                 kda_ran, kda_steps = tel.counter("kda/kernel_steps_total"), _kda_kernel_steps(cfg, self.mesh)
                 grouped_ran, grouped_calls = tel.counter("moe/grouped_kernel_calls_total"), _grouped_kernel_calls(cfg, self.mesh)
+                ring_ran, ring_calls = tel.counter("sdar/ring_kernel_calls_total"), _ring_kernel_calls(cfg, self.mesh, da)
                 while steps_done < num_steps and not self._stop_requested:
                     with tel.span("learner/iteration", step=self._host_step):
                         with tel.span("learner/league_draw"):
@@ -1863,6 +1866,7 @@ class Learner:
                         dispatches.inc()
                         kda_ran.inc(kda_steps)
                         grouped_ran.inc(grouped_calls)
+                        ring_ran.inc(ring_calls)
                         if opp_idx != league_pool.LIVE:
                             frozen.inc()
                         elif self._live_shares_pass:
@@ -2592,6 +2596,27 @@ def _grouped_kernel_calls(config: RunConfig, mesh) -> int:
     routed = sum(not afmoe.layer_is_dense(model, layer) for layer in range(model.n_layers))
     passes = resident_core(model).rollout_passes(model) if model.diffusion_steps else 1
     return routed * (passes * config.ppo.rollout_len + config.ppo.steps_per_batch) * config.steps_per_dispatch
+
+
+def _ring_kernel_calls(config: RunConfig, mesh, actor) -> int:
+    """Calls of the ring kernel (``ops/pallas/ring_attend.py``) ONE fused
+    dispatch's rollout makes: a call a layer, rollout pass and lane set (the
+    learner's and, where the opponent is a policy, the opponent's; one pass
+    over both teams' rows is a call a set as two passes are), but for the
+    commit's last layer, whose output nothing reads, times steps and
+    iterations; 0 where ``sdar.pass_takes_kernel`` does not hold on the
+    platform the program is lowered for (a CPU, toy widths) and for every
+    other core. ``sdar/ring_kernel_calls_total`` over
+    ``learner/dispatches_total`` says whether the kernel was engaged."""
+    from dotaclient_tpu.models.policy import resident_core
+
+    model = config.model
+    core = resident_core(model) if model.carry_stays_on_chip else None
+    if not hasattr(core, "pass_takes_kernel") or not core.pass_takes_kernel(model, core.ROWS, mesh.devices.flat[0].platform):
+        return 0
+    lane_sets = 1 + bool(actor.opponent_players)
+    layer_passes = model.n_layers * core.rollout_passes(model) - 1
+    return layer_passes * lane_sets * config.ppo.rollout_len * config.steps_per_dispatch
 
 
 def _diffusion_passes(config: RunConfig) -> int:

@@ -996,6 +996,7 @@ def staged_loss(
         "moe_aux": moe_aux,
         **_moe_counters(losses),
         **_diffusion_gauges(stage_logits, obs_t, actions, act_stage, valid),
+        **_ring_read_share(policy.model, batch["carry0"]),
         **({"_select_bias_err": bias_errors} if bias_errors else {}),
         "policy_loss": policy_loss,
         "value_loss": value_loss,
@@ -1008,6 +1009,19 @@ def staged_loss(
         "reward_mean": (batch["rewards"] * valid).sum() / n_valid,
     }
     return loss, metrics
+
+
+def _ring_read_share(model, carry0) -> Dict[str, jnp.ndarray]:
+    """Where a rollout pass of this configuration attends through the ring
+    kernel on a TPU (``sdar.pass_takes_kernel``): the share of the chunk's
+    rings that the first pass at its start read, ring rows of the blocks the
+    kernel visits over lanes x ``full_context`` (``sdar/ring_rows_read_share``
+    at the log cadence). Empty where the widths leave the kernel out."""
+    from dotaclient_tpu.models import sdar
+
+    if not sdar.pass_takes_kernel(model, sdar.ROWS, "tpu"):
+        return {}
+    return {"sdar_ring_rows_read_share": sdar.ring_rows_read_share(model, carry0["pos"], carry0["cursor"])}
 
 
 def _diffusion_gauges(stage_logits, obs_t, actions, act_stage, valid) -> Dict[str, jnp.ndarray]:

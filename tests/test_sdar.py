@@ -449,13 +449,14 @@ def test_the_episode_has_to_fit_six_positions_a_step():
 
 def test_a_rollout_step_at_the_cells_widths_multiplies_every_ring_as_it_lies():
     """``decode`` at SDAR's published widths, one game (5 lanes), compiled
-    for a described v5e: the 48 and 40 query rows a KV head of pass 1 and of
-    the other passes (``_attend``, a KV head at a time against its own ring
-    ``[lanes, R, D]``) read each ring where it lies: no ring is copied,
-    transposed or widened to float32, and each of the four passes multiplies
-    every layer's KV heads' rings twice (scores, values) but for the commit's
-    last layer, whose output nothing reads: 120 products with a ring
-    operand, the form this test pins."""
+    for a described v5e: each pass's attention against the rings is the ring
+    kernel (``ops/pallas/ring_attend.py``, chosen by
+    ``sdar.pass_takes_kernel``), which reads each ring's blocks where they
+    lie: no product against a whole ring is left under ``core_block_attend``,
+    no ring is copied, transposed or widened to float32, and the four passes
+    make one kernel call a layer, all KV heads' keys and values in it, named
+    under ``core_block_attend``, but for the commit's last layer, whose
+    output nothing reads: 15 calls, the form this test pins."""
     import os
     import re
 
@@ -471,6 +472,7 @@ def test_a_rollout_step_at_the_cells_widths_multiplies_every_ring_as_it_lies():
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     cfg = program.build_run_config(cells.load_cell("sdar-30b-a3b-5v5-ep16.fused-selfplay-anycore"), seed=0, rehearsal=False)
+    assert sdar.pass_takes_kernel(cfg.model, sdar.ROWS, "tpu")
     policy, lanes = make_policy(cfg.model, cfg.obs, cfg.actions), 5
     shapes = jax.eval_shape(lambda: (
         init_params(policy, jax.random.PRNGKey(0)), dummy_obs_batch(lanes, cfg.obs, cfg.actions),
@@ -484,8 +486,10 @@ def test_a_rollout_step_at_the_cells_widths_multiplies_every_ring_as_it_lies():
     assert not re.findall(rf"= bf16{ring}{{[^}}]*}} (copy|transpose)\(", text)
     assert not re.findall(rf"= f32{ring}", text)
     products = [l for l in text.splitlines() if re.search(r"= [^ ]+ convolution\(", l) and "core_block_attend" in l]
-    against_ring = [l for l in products if "brkd" in l]          # the einsums whose second operand is a ring
-    L, kv = cfg.model.n_layers, cfg.model.n_kv_heads
-    # four passes, each layer's KV heads twice; the commit's last layer is keys and values alone (its output is dead)
-    assert len(against_ring) == 2 * kv * (4 * L - 1) == 120
-    assert len(products) == 2 * len(against_ring)                 # and as many against the pass's own rows
+    # the einsums of ``afmoe._attend`` whose second operand is a ring, or any product with a ring-long axis
+    assert not [l for l in products if "brkd" in l or f",{cfg.model.full_context}," in l]
+    calls = [l for l in text.splitlines() if "custom-call(" in l and "ring_attend" in l]
+    L = cfg.model.n_layers
+    # four passes, a call each layer; the commit's last layer is keys and values alone (its output is dead)
+    assert len(calls) == 4 * L - 1 == 15
+    assert all("core_block_attend" in l for l in calls)
